@@ -97,6 +97,10 @@ def main():
                              "JSONL (shared observability schema)")
     args = parser.parse_args()
 
+    from chainermn_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import optax

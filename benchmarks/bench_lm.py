@@ -111,7 +111,7 @@ def run(args) -> dict:
     t0 = time.perf_counter()
     for _ in range(steps):
         params, opt_state, loss = step(params, opt_state, batch_dev)
-    final_loss = float(loss)  # value read = execution fence (bench.py note)
+    final_loss = float(loss)  # value read: every timed step has run
     dt = time.perf_counter() - t0
     log(f"bench_lm: final loss {final_loss:.3f}")
 
@@ -126,31 +126,25 @@ def run(args) -> dict:
         "train_gflop_per_token": round(gflop_tok, 4),
     }
     if on_tpu:
-        from chainermn_tpu.utils.tpu_info import peak_tflops_info
+        from chainermn_tpu.utils.tpu_info import peak_tflops
+        from chainermn_tpu.utils.trace import device_time
 
         dev = jax.devices()[0]
-        peak, matched = peak_tflops_info(dev)
+        peak = peak_tflops(dev)
         out["mfu"] = round(tok_per_sec * gflop_tok / 1e3 / peak, 4)
-        out["device_kind"] = getattr(dev, "device_kind", "")
-        if matched is None:
-            out["peak_assumed"] = True
+        out["device_kind"] = dev.device_kind
         out["peak_tflops"] = peak
         out["step_ms"] = round(dt / steps * 1e3, 2)
-        try:
-            from chainermn_tpu.utils.trace import device_time
+        box = [(params, opt_state)]
 
-            box = [(params, opt_state)]
+        def one():
+            p, s = box[0]
+            p, s, l = step(p, s, batch_dev)
+            box[0] = (p, s)
+            return l
 
-            def one():
-                p, s = box[0]
-                p, s, l = step(p, s, batch_dev)
-                box[0] = (p, s)
-                return l
-
-            out["device_ms_per_step"] = round(
-                device_time(one, (), steps=3, warmup=1), 2)
-        except Exception as e:  # noqa: BLE001 — supplementary only
-            log(f"bench_lm: device-time capture skipped ({e})")
+        out["device_ms_per_step"] = round(
+            device_time(one, (), steps=3, warmup=1), 2)
         log(f"bench_lm: MFU {out['mfu']:.1%} (peak {peak} TFLOP/s bf16)")
     else:
         out["smoke"] = True
@@ -168,13 +162,12 @@ def main():
     parser.add_argument("--layers", type=int, default=8)
     parser.add_argument("--batch", type=int, default=1,
                         help="per-chip batch (TPU path)")
-    parser.add_argument("--attempts", type=int, default=3)
     args = parser.parse_args()
 
-    from chainermn_tpu.utils.retry import retry_transient
+    from chainermn_tpu.utils.compile_cache import place_compile_cache
 
-    out = retry_transient(lambda: run(args), attempts=args.attempts,
-                          label="bench_lm")
+    place_compile_cache()
+    out = run(args)
     from chainermn_tpu.observability.ledger import stamp_envelope
     stamp_envelope(out, "bench_lm/v1")
     print(json.dumps(out), flush=True)

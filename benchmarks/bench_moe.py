@@ -78,7 +78,6 @@ def _sweep(args):
     from chainermn_tpu.planner import (
         SWEEP_SCHEMA, candidate_plans, execute_alltoall, load_plan,
         plan_dcn_bytes, plan_modeled_time_s)
-    from chainermn_tpu.utils import shard_map
 
     kwargs = {}
     if args.intra_size is not None:
@@ -113,7 +112,7 @@ def _sweep(args):
         def raw(b):
             return lax.all_to_all(b, axis_arg, 0, 0, tiled=True)
 
-        want = np.asarray(jax.jit(shard_map(
+        want = np.asarray(jax.jit(jax.shard_map(
             raw, mesh=mesh, in_specs=spec, out_specs=spec,
             check_vma=False))(x))
         size_dcn = {}
@@ -121,7 +120,7 @@ def _sweep(args):
             def body(b, plan=plan):
                 return execute_alltoall(plan, topo, b)
 
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=spec,
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
                                    out_specs=spec, check_vma=False))
             got = np.asarray(fn(x))      # compile + correctness
             narrow = any(st.wire_dtype not in (None, args.dtype)
@@ -226,8 +225,6 @@ def _train(model, toks_stream, steps, lr, aux_weight, mesh, axis):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from chainermn_tpu.utils import shard_map
-
     is_moe = bool(model.moe_experts)
 
     def fwd(pp, tk):
@@ -245,10 +242,10 @@ def _train(model, toks_stream, steps, lr, aux_weight, mesh, axis):
             jax.lax.pmean(ce, axis)
 
     def loss_fn(pp, tk):
-        return shard_map(fwd, mesh=mesh, in_specs=(P(), P(axis)),
+        return jax.shard_map(fwd, mesh=mesh, in_specs=(P(), P(axis)),
                          out_specs=(P(), P()), check_vma=False)(pp, tk)
 
-    params = jax.jit(shard_map(
+    params = jax.jit(jax.shard_map(
         lambda tk: model.init(jax.random.key(0), tk), mesh=mesh,
         in_specs=P(axis), out_specs=P(),
         check_vma=False))(toks_stream(0))
@@ -370,6 +367,10 @@ def main():
     parser.add_argument("--aux-weight", type=float, default=1e-2)
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
+
+    from chainermn_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if bool(args.sweep) == bool(args.out):
         parser.error("pass exactly one of --sweep or --out")
     if args.sweep:

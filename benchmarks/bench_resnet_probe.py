@@ -185,6 +185,10 @@ def main():
                         "the perf-gate budget is smoke-run independent)")
     args = p.parse_args()
 
+    from chainermn_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     import flax.linen as nn
     import jax
     import jax.numpy as jnp

@@ -427,6 +427,10 @@ def main():
                              "tools/obs_report.py --serving)")
     args = parser.parse_args()
 
+    from chainermn_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     import jax
     import jax.numpy as jnp
 

@@ -93,7 +93,6 @@ def _long_context_target():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from chainermn_tpu.models import TransformerLM
-    from chainermn_tpu.utils import shard_map
 
     devices = jax.devices()
     n_sp = len(devices)
@@ -125,7 +124,7 @@ def _long_context_target():
         return total / count
 
     def loss_fn(p_, tk):
-        return shard_map(sp_body, mesh=mesh,
+        return jax.shard_map(sp_body, mesh=mesh,
                          in_specs=(P(), P(None, "sp")),
                          out_specs=P(), check_vma=False)(p_, tk)
 
@@ -240,7 +239,6 @@ def _moe_train_target():
     from chainermn_tpu.planner.compiler import execute_alltoall
     from chainermn_tpu.planner.ir import PlanTopology
     from chainermn_tpu.planner.plans import alltoall_plans
-    from chainermn_tpu.utils import shard_map
 
     devices = jax.devices()
     if len(devices) < 8:
@@ -258,7 +256,7 @@ def _moe_train_target():
     toks = jnp.zeros((8, 16), jnp.int32)
 
     # init inside the SPMD region (router/expert shapes bind the ep axis)
-    params = jax.jit(shard_map(
+    params = jax.jit(jax.shard_map(
         lambda tk: model.init(jax.random.key(0), tk), mesh=mesh,
         in_specs=P("data"), out_specs=P(), check_vma=False))(toks)
     opt = optax.sgd(1e-2)
@@ -272,7 +270,7 @@ def _moe_train_target():
             aux = mut["moe_stats"]["block_0"]["aux_loss"][0]
             return jax.lax.pmean(ce, ("ep", "data")) + 1e-2 * aux
 
-        return shard_map(body, mesh=mesh, in_specs=(P(), P(None, "data")),
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(), P(None, "data")),
                          out_specs=P(), check_vma=False)(p_, tk)
 
     @jax.jit
@@ -286,7 +284,7 @@ def _moe_train_target():
     buf = jnp.zeros((4, 8, 16), jnp.float32)
 
     def census_hlo():
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda b: execute_alltoall(plan, topo, b), mesh=mesh,
             in_specs=P("ep"), out_specs=P("ep"),
             check_vma=False)).lower(buf).compile().as_text()

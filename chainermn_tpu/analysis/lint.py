@@ -139,6 +139,10 @@ class LintContext:
             return self._cache[key]
         try:
             val = build()
+        except ImportError:
+            # the linter's own breakage (an API that moved), never a
+            # property of the target: an error, not a skip
+            raise
         except Exception as e:  # noqa: BLE001 — reason becomes the skip
             self.unavailable[key] = f"{type(e).__name__}: {e}"
             val = None
@@ -374,17 +378,17 @@ def allreduce_hlo(comm, nelems: int = 1024, dtype=jnp.float32,
 def build_grad_probe(comm, loss, loss_args, label: str = "") \
         -> Dict[str, CollectiveSchedule]:
     """Primal vs backward collective schedules of ``loss`` differentiated
-    INSIDE the communicator's SPMD region — the ``make_train_step`` shape,
-    where an unpinned psum transpose is both statically visible (an extra
-    backward psum) and numerically wrong (grads inflated by the axis
-    size).
+    INSIDE the communicator's SPMD region, traced WITHOUT varying-axes
+    tracking (``check_vma=False``) — the mode in which an unpinned psum
+    transpose is both statically visible (an extra backward psum) and
+    numerically wrong (grads inflated by the axis size).
 
     ``loss(params, *rest)`` must return a scalar per-rank loss (or an
     ``(loss, aux)`` tuple); ``loss_args = (params, *rest)`` in GLOBAL
     layout — params replicated, the rest sharded on their leading axis
     over the communicator's data axes (a stacked ``[size, ...]`` batch).
     """
-    from chainermn_tpu.utils import pvary, shard_map as _shard_map
+    from chainermn_tpu.utils import pvary
     from jax.sharding import PartitionSpec as P
 
     axes = comm.data_axes
@@ -407,7 +411,7 @@ def build_grad_probe(comm, loss, loss_args, label: str = "") \
     in_specs = (P(),) + tuple(P(axes) for _ in rest)
 
     def mapped(body):
-        return _shard_map(body, mesh=comm.mesh, in_specs=in_specs,
+        return jax.shard_map(body, mesh=comm.mesh, in_specs=in_specs,
                           out_specs=P(axes), check_vma=False)
 
     return {
@@ -454,11 +458,9 @@ def lint_step(fn, *args, comm=None, flavor=None, inter_size=None,
                        for m in missing]
             report.skipped[rule.id] = "; ".join(reasons)
             continue
-        try:
-            report.findings.extend(rule.run(ctx))
-        except Exception as e:  # noqa: BLE001 — a crashed rule is a skip
-            report.skipped[rule.id] = \
-                f"rule crashed: {type(e).__name__}: {e}"
+        # a rule that crashes is a bug in the linter and raises: recorded
+        # as a skip it once hid an ImportError for a whole JAX upgrade
+        report.findings.extend(rule.run(ctx))
     report.findings.sort(
         key=lambda f: ("error", "warning", "info").index(f.severity))
     if raise_on_error:

@@ -15,6 +15,7 @@ records the reason.
 
 from __future__ import annotations
 
+import itertools
 import re
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -199,7 +200,47 @@ CPU_WIRE_PROMOTIONS = {
 }
 
 
-def _interleaves(seqs, observed, match=None) -> bool:
+#: a quantizing hop's scale exchange in the census: one float32
+#: all-reduce, data-independent of the stage chains until the decode
+_SCALE_KINDS = ("all-reduce",)
+_SCALE_LANES = (("all-reduce", "f32"),)
+
+
+def _scale_exchanges_fitting(declared: int, chains, observed,
+                             merge: bool = False) -> int:
+    """The most scale exchanges (at most ``declared``) with which
+    ``observed`` is still an interleaving of ``chains``; 0 when none
+    fits.  Fewer than declared is not itself the finding: a program whose
+    quantizer was silently dropped has no scale exchange either, and the
+    per-hop wire check names that hop."""
+    return next(
+        (k for k in range(declared, 0, -1)
+         if _interleaves(list(chains) + [_SCALE_KINDS] * k, observed,
+                         merge=merge)), 0)
+
+
+def _without_scale_exchanges(ops, chain, n_scale: int):
+    """``ops`` minus ``n_scale`` scale exchanges, chosen so that what is
+    left still matches ``chain`` ((kind, hlo dtype) per hop, CPU wire
+    promotions allowed) hop for hop where it can: an op that is not the
+    next hop's but is a float32 all-reduce is taken for a scale
+    exchange.  With none left to take, ops stay in place and the per-hop
+    comparison reports them."""
+    kept, want = [], list(chain)
+    for op in ops:
+        nxt = want[len(kept)] if len(kept) < len(want) else None
+        is_next = nxt is not None and op.kind == nxt[0] and (
+            op.dtype == nxt[1]
+            or op.dtype in CPU_WIRE_PROMOTIONS.get(nxt[1], ()))
+        if (n_scale and not is_next
+                and (op.kind, op.dtype) == _SCALE_LANES[0]):
+            n_scale -= 1
+            continue
+        kept.append(op)
+    return kept
+
+
+def _interleaves(seqs, observed, match=None, merge: bool = False) -> bool:
     """True iff ``observed`` is a valid interleaving of the sequences in
     ``seqs`` — each sequence's internal order preserved, elements freely
     merged across sequences.  ``match(want, got)`` compares elements
@@ -210,34 +251,36 @@ def _interleaves(seqs, observed, match=None) -> bool:
     share no data), so the compiled schedule is only required to be SOME
     interleaving of the per-group expected sequences, never an arbitrary
     permutation — within a group the chain order is a data dependency
-    and must survive.  Memoized DP over per-sequence cursors.
+    and must survive.  With ``merge``, one observed element may also
+    stand for the matching heads of SEVERAL sequences at once: XLA's
+    all-reduce combiner fuses same-typed collectives of independent
+    groups into one op (the two stripes' gather-back psums compile to a
+    single all-reduce on the installed jaxlib).  Memoized DP over
+    per-sequence cursors.
     """
     if match is None:
         def match(w, g):
             return w == g
     seqs = [tuple(s) for s in seqs]
     observed = tuple(observed)
-    if sum(len(s) for s in seqs) != len(observed):
-        return False
     memo: Dict[tuple, bool] = {}
 
-    def _ok(idx: tuple) -> bool:
-        pos = sum(idx)
+    def _ok(pos: int, idx: tuple) -> bool:
         if pos == len(observed):
-            return True
-        if idx in memo:
-            return memo[idx]
-        res = False
-        for gi, s in enumerate(seqs):
-            j = idx[gi]
-            if j < len(s) and match(s[j], observed[pos]):
-                if _ok(idx[:gi] + (j + 1,) + idx[gi + 1:]):
-                    res = True
-                    break
-        memo[idx] = res
+            return all(j == len(s) for j, s in zip(idx, seqs))
+        key = (pos, idx)
+        if key in memo:
+            return memo[key]
+        heads = [gi for gi, s in enumerate(seqs)
+                 if idx[gi] < len(s) and match(s[idx[gi]], observed[pos])]
+        res = any(
+            _ok(pos + 1, tuple(j + (gi in take) for gi, j in enumerate(idx)))
+            for r in range(1, (len(heads) if merge else 1) + 1)
+            for take in itertools.combinations(heads, r))
+        memo[key] = res
         return res
 
-    return _ok(tuple(0 for _ in seqs))
+    return _ok(0, tuple(0 for _ in seqs))
 
 
 @rule("census-drift", "error",
@@ -256,7 +299,7 @@ def _census_drift(ctx) -> List[Finding]:
         # per-group expected sequences, first on kinds, then on
         # (kind, wire-dtype) lanes with the CPU promotion tolerance.
         from chainermn_tpu.planner.compiler import (
-            plan_census_kinds, plan_wire_dtypes)
+            plan_census_kinds, plan_scale_exchanges, plan_wire_dtypes)
         from chainermn_tpu.planner.ir import PlanTopology
         comm = getattr(ctx, "comm", None)
         topo = (comm.plan_topology() if comm is not None else
@@ -264,8 +307,13 @@ def _census_drift(ctx) -> List[Finding]:
         n_groups = len(plan.groups)
         group_kinds = [tuple(plan_census_kinds(plan, topo, group=g))
                        for g in range(n_groups)]
+        # each quantizing hop's scale exchange is one more independent
+        # one-op sequence (planner.compiler.plan_scale_exchanges)
         got = tuple(ctx.census_schedule.kinds())
-        if not _interleaves(group_kinds, got):
+        n_scale = _scale_exchanges_fitting(
+            plan_scale_exchanges(plan, topo), group_kinds, got, merge=True)
+        if not _interleaves(group_kinds + [_SCALE_KINDS] * n_scale, got,
+                            merge=True):
             return [_finding(
                 f"striped plan {plan.name!r} compiled allreduce_grad to "
                 f"{list(got) or '<no collectives>'} which is not an "
@@ -293,7 +341,8 @@ def _census_drift(ctx) -> List[Finding]:
                     and (g[1] == w[1]
                          or g[1] in CPU_WIRE_PROMOTIONS.get(w[1], ())))
 
-        if not _interleaves(group_lanes, got_lanes, _lane_match):
+        if not _interleaves(group_lanes + [_SCALE_LANES] * n_scale,
+                            got_lanes, _lane_match, merge=True):
             return [_finding(
                 f"striped plan {plan.name!r} compiled collectives "
                 f"{[list(l) for l in got_lanes]} do not interleave its "
@@ -313,22 +362,29 @@ def _census_drift(ctx) -> List[Finding]:
     if plan is not None:
         # explicit plan spec (e.g. an autotuned table entry) — derive
         # the census against the communicator's declared topology
-        from chainermn_tpu.planner.compiler import plan_census_kinds
+        from chainermn_tpu.planner.compiler import (
+            plan_census_kinds, plan_scale_exchanges)
         from chainermn_tpu.planner.ir import PlanTopology
         comm = getattr(ctx, "comm", None)
         topo = (comm.plan_topology() if comm is not None else
                 PlanTopology(axes=(("inter", inter), ("intra", 1))))
         want = plan_census_kinds(plan, topo)
+        n_scale = plan_scale_exchanges(plan, topo)
         spec_name = f"plan {plan.name!r}"
     else:
         want = expected_kinds(flavor, inter)
+        n_scale = 0
         spec_name = f"flavor {flavor!r}"
     got = ctx.census_schedule.kinds()
-    if got != want:
+    n_scale = _scale_exchanges_fitting(n_scale, [want], got)
+    if not _interleaves([want] + [_SCALE_KINDS] * n_scale, got):
         return [_finding(
             f"communicator {spec_name} compiled allreduce_grad to "
             f"{list(got) or '<no collectives>'} but its decomposition is "
-            f"specified as {list(want)} (inter_size={inter}).  The "
+            f"specified as {list(want)}"
+            + (f" plus {n_scale} freely-placed scale-exchange "
+               f"all-reduce(s)" if n_scale else "")
+            + f" (inter_size={inter}).  The "
             "decomposition IS the flavor (docs/performance.md census "
             "table; CENSUS_r*.json artifact): drift here means a "
             "different wire cost model and a schedule the other ranks do "
@@ -347,7 +403,12 @@ def _census_drift(ctx) -> List[Finding]:
     from chainermn_tpu.planner.compiler import plan_wire_dtypes
     want_np = plan_wire_dtypes(plan, topo)
     want_d = [NP_TO_HLO_DTYPE.get(d, d) for d in want_np]
-    got_d = [op.dtype for op in ctx.census_schedule]
+    ops = list(ctx.census_schedule)
+    if n_scale:
+        # take the freely-placed scale exchanges out of the observed
+        # schedule so the hops line up with the stage chain again
+        ops = _without_scale_exchanges(ops, list(zip(want, want_d)), n_scale)
+    got_d = [op.dtype for op in ops]
     out: List[Finding] = []
     for i, (w, g) in enumerate(zip(want_d, got_d)):
         if g == w or g in CPU_WIRE_PROMOTIONS.get(w, ()):
@@ -377,14 +438,18 @@ def _census_drift(ctx) -> List[Finding]:
       "transpose",
       requires=("grad_probe",))
 def _unpinned_transpose(ctx) -> List[Finding]:
-    """A loss differentiated INSIDE the SPMD region (the
-    ``make_train_step`` shape) that allreduces a replicated value with a
-    raw ``psum`` gets the psum→psum transpose: the cotangent is summed
-    again and every gradient arrives inflated by ``size``.  The pinned
-    path (``chainermn_tpu.functions.allreduce``, a custom VJP whose
-    backward is the identity) adds NO backward psum — so any psum excess
-    of the grad trace over the primal trace, per axis set, is an
-    unpinned transpose."""
+    """A loss differentiated INSIDE an SPMD region traced without
+    varying-axes tracking (``shard_map(check_vma=False)`` — what Pallas
+    interpret mode forces on the CPU mesh, and how the probe traces) that
+    allreduces with a raw ``psum`` gets the psum→psum transpose: the
+    cotangent is summed again and every gradient arrives inflated by
+    ``size``.  With tracking on (``make_train_step``'s default) JAX
+    transposes psum to the identity natively and the raw form is right
+    THERE; the pinned path (``chainermn_tpu.functions.allreduce``, a
+    custom VJP whose backward is the identity) is right in both and adds
+    NO backward psum — so any psum excess of the grad trace over the
+    primal trace, per axis set, is a transpose that only vma tracking is
+    keeping correct."""
     probe = ctx.grad_probe   # {"primal": schedule, "grad": schedule}
     primal_counts = probe["primal"].counts_by_axes("psum")
     grad_counts = probe["grad"].counts_by_axes("psum")
@@ -397,7 +462,8 @@ def _unpinned_transpose(ctx) -> List[Finding]:
         out.append(_finding(
             f"{extra} psum(s) over axes ({ax_txt}) appear in the "
             f"backward trace of the per-rank loss but not in its primal "
-            f"trace: a psum's VJP was transposed to another psum, so "
+            f"trace: traced without varying-axes tracking (check_vma="
+            f"False) a psum's VJP transposes to another psum, so "
             f"gradients are inflated by the axis size.  Wrap the "
             f"allreduce in chainermn_tpu.functions.allreduce (custom VJP "
             f"pinning the identity transpose) instead of calling "

@@ -125,7 +125,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[str, Any]]:
     """(tag, jaxpr-like) children reachable through this equation's
     params — ClosedJaxprs (pjit/scan/cond bodies) and raw Jaxprs
     (shard_map)."""
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
 
     out: List[Tuple[str, Any]] = []
     for pname, v in eqn.params.items():
@@ -190,7 +190,7 @@ def extract_schedule(fn_or_jaxpr, *args, label: str = "",
     schedule (tagged in ``path``): a collective in only one branch is
     exactly the divergence hazard the desync rule exists to catch.
     """
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
 
     closed = fn_or_jaxpr
     if not (isinstance(closed, ClosedJaxpr) or hasattr(closed, "eqns")):

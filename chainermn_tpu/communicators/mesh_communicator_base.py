@@ -23,8 +23,6 @@ from collections import OrderedDict
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
-
-from chainermn_tpu.utils import shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -313,7 +311,7 @@ class MeshCommunicator(CommunicatorBase):
             out = f(*squeezed)
             return jax.tree.map(lambda a: jnp.expand_dims(a, 0), out)
 
-        fn = _shard_map(per_rank, mesh=self._mesh,
+        fn = jax.shard_map(per_rank, mesh=self._mesh,
                            in_specs=spec, out_specs=spec)
         if jit:
             fn = jax.jit(fn)
@@ -595,7 +593,9 @@ CompressionState` from :meth:`init_compression_state`) and the call
                 obs.make_callback("decompress", "begin", "allreduce", 0,
                                   comp.name, bpp, saved),
                 rank, 0.0, summed[0])
-        out, state = comp.decompress(summed, state, world_size=n)
+        out, state = comp.decompress(
+            summed, state, world_size=n,
+            axes=self._axis_arg() if traced else None)
         if obs is not None:
             jax.debug.callback(
                 obs.make_callback("decompress", "end", "allreduce", 0,

@@ -195,16 +195,27 @@ class _ScaledQuantizer(Compressor):
         return (jnp.concatenate([codes, flags]),
                 state._replace(ef=new_ef, step=state.step + 1.0))
 
-    def decompress(self, wire, state, world_size: int = 1):
+    def decompress(self, wire, state, world_size: int = 1, axes=None):
         """Decode the SUMMED wire buffer back to a float32 SUM (the
         caller divides by world for mean semantics) and advance the
         delayed scales from its per-chunk amax and summed clip count —
-        identical on every rank because the summed wire is."""
+        identical on every rank because the summed wire is.
+
+        ``axes``: the mesh axes the wire was summed over, when called
+        inside ``shard_map``.  The scales are rank-identical over them
+        by construction but are read from a per-rank state slot, which
+        the varying-axes type system can only take for device-varying —
+        and with them everything decoded, down to the updated
+        parameters.  ``pmax`` is the identity on identical values and
+        types the scales replicated (one exponent per chunk on the
+        wire)."""
         mp = int(state.ef.shape[0])
-        sp = self.scale_per_pos(state.scale)
+        scale = state.scale if axes is None else jax.lax.pmax(
+            state.scale, axes)
+        sp = self.scale_per_pos(scale)
         out = self.decode(wire[:mp], sp)
         amax = jnp.max(jnp.abs(out).reshape(-1, self.chunk_size), axis=1)
-        new_e = self.next_exponent(state.scale, amax, world_size,
+        new_e = self.next_exponent(scale, amax, world_size,
                                    wire[mp:].astype(jnp.float32))
         return out, state._replace(scale=new_e)
 

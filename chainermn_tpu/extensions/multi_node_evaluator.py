@@ -23,7 +23,6 @@ from typing import Callable
 
 import jax
 
-from chainermn_tpu.utils import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -49,7 +48,7 @@ def make_eval_fn(communicator, metrics_fn: Callable,
             m = metrics_fn(params, state, batch)
             return comm.allreduce(m, "mean")
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             eval_step, mesh=comm.mesh,
             in_specs=(P(), P(comm.data_axes), P(comm.data_axes)),
             out_specs=P())
@@ -59,7 +58,7 @@ def make_eval_fn(communicator, metrics_fn: Callable,
         m = metrics_fn(params, batch)
         return comm.allreduce(m, "mean")
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         eval_step, mesh=comm.mesh,
         in_specs=(P(), P(comm.data_axes)), out_specs=P())
     return jax.jit(mapped)

@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.utils import pvary
+
 
 def allgather(communicator, x):
     """Gather every rank's ``x`` onto all ranks -> stacked [size, ...].
@@ -60,17 +62,27 @@ def _allreduce_diff(communicator, x, op):
 
 
 def _allreduce_fwd(communicator, x, op):
-    return communicator.allreduce(x, op=op), None
+    # residual: a scalar per leaf that carries the input's varying-axes
+    # type into the backward rule (types are not values, so a traced zero
+    # of that type is the carrier)
+    like = jax.tree.map(
+        lambda v: pvary(jnp.zeros((), v.dtype), tuple(jax.typeof(v).vma)), x)
+    return communicator.allreduce(x, op=op), like
 
 
-def _allreduce_bwd(communicator, op, _res, g):
+def _allreduce_bwd(communicator, op, like, g):
     # The cotangent of an allreduce output is replicated across ranks, so
     # the transpose is the identity (scaled by 1/size for the mean).  Pinned
-    # explicitly because jax versions without replication tracking would
-    # otherwise transpose psum to psum, inflating the gradient by ``size``.
+    # explicitly because a region traced WITHOUT varying-axes tracking
+    # (``shard_map(check_vma=False)``, which Pallas interpret mode forces
+    # on the CPU) transposes psum to psum, inflating the gradient by
+    # ``size``; with tracking on, this is what JAX does natively, and the
+    # cotangent only has to be retyped as varying like the input it
+    # answers for.
     if op == "mean":
         g = jax.tree.map(lambda v: v / communicator.size, g)
-    return (g,)
+    return (jax.tree.map(
+        lambda v, z: pvary(v, tuple(jax.typeof(z).vma)), g, like),)
 
 
 _allreduce_diff.defvjp(_allreduce_fwd, _allreduce_bwd)

@@ -100,7 +100,7 @@ KNOWN_SCHEMAS = {
 }
 
 #: legacy ``suite`` marker -> retroactive schema name
-_LEGACY_SUITES = {
+_PRE_ENVELOPE_SUITES = {
     "tpu_smoke": "tpu_smoke/v1",
     "convergence_ledger": "convergence_ledger/v1",
     "collective_census": "collective_census/v1",
@@ -110,7 +110,7 @@ _LEGACY_SUITES = {
 }
 
 #: legacy ``bench`` marker -> retroactive schema name
-_LEGACY_BENCHES = {
+_PRE_ENVELOPE_BENCHES = {
     "benchmarks/bench_lm.py": "bench_lm/v1",
     "benchmarks/bench_vit.py": "bench_vit/v1",
 }
@@ -252,8 +252,8 @@ def classify_artifact(doc, path: str = "") -> Optional[dict]:
                 "schema_version": doc.get("schema_version")
                 or schema_version(declared),
                 "legacy": doc.get("git_sha") is None}
-    for marker, table in (("kind", None), ("suite", _LEGACY_SUITES),
-                          ("bench", _LEGACY_BENCHES)):
+    for marker, table in (("kind", None), ("suite", _PRE_ENVELOPE_SUITES),
+                          ("bench", _PRE_ENVELOPE_BENCHES)):
         val = doc.get(marker)
         if not isinstance(val, str):
             continue

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from chainermn_tpu.utils import pvary, typeof
+from chainermn_tpu.utils import pvary
 
 _LANE = 128
 _BLOCK_ROWS = 256  # 256 x 128 f32 = 128 KiB per buffer; in+out fit VMEM easily
@@ -50,19 +50,10 @@ def cast_scale(x: jnp.ndarray, target_dtype: Optional[jnp.dtype], scale: float):
     orig_shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]
-    in_vma = getattr(typeof(flat), "vma", None)
-    in_spmd = bool(in_vma)
-    if in_vma is None:
-        # pre-vma jax: no vma metadata to inspect — detect "inside a
-        # shard_map/axis-bound trace" from the axis env instead (there is
-        # no shard_map replication rule for pallas_call there either)
-        try:
-            from jax._src import core as _src_core
-            in_spmd = bool(_src_core.get_axis_env().axis_sizes)
-        except Exception:
-            pass
+    # varying-over-mesh-axes set of the input: non-empty inside shard_map
+    vma = jax.typeof(flat).vma
     interpret = jax.default_backend() != "tpu"
-    if interpret and in_spmd:
+    if interpret and vma:
         # jax's HLO interpreter for pallas is not vma-aware (its internal
         # dynamic_slice mixes varying/invariant operands and trips
         # check_vma), so inside a shard_map off-TPU we emit the XLA-fused
@@ -71,11 +62,8 @@ def cast_scale(x: jnp.ndarray, target_dtype: Optional[jnp.dtype], scale: float):
         return (flat.astype(jnp.float32) * jnp.float32(scale)).astype(dst).reshape(orig_shape)
 
     def _zeros(k):
-        z = jnp.zeros((k,), flat.dtype)
-        if in_vma:
-            # match the input's varying-axes set so concatenate is legal
-            z = pvary(z, tuple(in_vma))
-        return z
+        # match the input's varying-axes set so concatenate is legal
+        return pvary(jnp.zeros((k,), flat.dtype), tuple(vma))
 
     rows = -(-n // _LANE)
     pad = rows * _LANE - n
@@ -90,20 +78,14 @@ def cast_scale(x: jnp.ndarray, target_dtype: Optional[jnp.dtype], scale: float):
     # Under shard_map with vma-checking, the out aval must carry the same
     # varying-across-mesh-axes set as the input (a cast is rank-local), and
     # every kernel input must share it.
-    vma = getattr(typeof(x2), "vma", None)
-    if vma is not None:
-        if vma:
-            s_arr = pvary(s_arr, tuple(vma))
-        out_sds = jax.ShapeDtypeStruct((padded_rows, _LANE), dst, vma=vma)
-    else:
-        out_sds = jax.ShapeDtypeStruct((padded_rows, _LANE), dst)
+    s_arr = pvary(s_arr, tuple(vma))
     out = pl.pallas_call(
         _kernel,
-        out_shape=out_sds,
+        out_shape=jax.ShapeDtypeStruct((padded_rows, _LANE), dst, vma=vma),
         grid=(grid_rows,),
         in_specs=[pl.BlockSpec((_BLOCK_ROWS, _LANE), lambda i: (i, 0)),
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((_BLOCK_ROWS, _LANE), lambda i: (i, 0)),
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )(x2, s_arr)
     return out.reshape(-1)[:n].reshape(orig_shape)

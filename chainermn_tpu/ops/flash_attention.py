@@ -93,11 +93,8 @@ def _keep_mask(seed_u32, bh_idx, q_pos, k_pos, rate):
 
 def _shape_like(template, shape, dtype):
     """ShapeDtypeStruct carrying ``template``'s varying-axes (vma) metadata
-    when the JAX version supports it — needed for shard_map composition."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(template).vma)
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    — needed for shard_map composition."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(template).vma)
 
 
 def _unpack_rest(rest, has_seg, dropout_rate, has_offsets=False):

@@ -59,6 +59,7 @@ from jax.experimental import pallas as pl
 import flax.linen as nn
 
 from chainermn_tpu.ops.flash_attention import _scratch, _shape_like, _VMEM
+from chainermn_tpu.utils import pvary
 
 __all__ = [
     "fused_norm",
@@ -256,20 +257,27 @@ def _fused_core_fwd(x2, scale, bias, mean_in, var_in, train, eps, relu,
     invstd = jax.lax.rsqrt(var + eps)
     y2 = _apply_call(x2, mean, invstd, scale, bias, relu, block_rows,
                      interpret)
-    return (y2, mean, var), (x2, scale, bias, mean, invstd)
+    return (y2, mean, var), (x2, scale, bias, mean, invstd, mean_in, var_in)
 
 
 def _fused_core_bwd(train, eps, relu, block_rows, interpret, res, cts):
     # mean/var cotangents are dropped: running-stat updates sit outside
     # autodiff (flax variable writes), so nothing real flows through them.
     gy2, _, _ = cts
-    x2, scale, bias, mean, invstd = res
+    x2, scale, bias, mean, invstd, mean_in, var_in = res
     dbeta, dgamma = _bwd_reduce_call(x2, gy2, mean, invstd, scale, bias,
                                      relu, block_rows, interpret)
     dx2 = _bwd_dx_call(x2, gy2, mean, invstd, scale, bias, dbeta, dgamma,
                        relu, train, block_rows, interpret)
+    # Zero cotangents typed like the INPUTS they answer for: inside
+    # shard_map the train-mode placeholders are invariant while the batch
+    # statistics are device-varying, and a custom VJP must return each
+    # input's own varying-axes type.
+    def zero_like_input(ref):
+        return pvary(jnp.zeros_like(ref), tuple(jax.typeof(ref).vma))
+
     return (dx2, dgamma.astype(scale.dtype), dbeta.astype(bias.dtype),
-            jnp.zeros_like(mean), jnp.zeros_like(invstd))
+            zero_like_input(mean_in), zero_like_input(var_in))
 
 
 _fused_core.defvjp(_fused_core_fwd, _fused_core_bwd)
@@ -318,6 +326,15 @@ def fused_norm(x, scale, bias, mean=None, var=None, *,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if interpret and jax.typeof(x).vma:
+        # Same rule as ops/cast_scale.py: jax's Pallas interpreter is not
+        # vma-aware, so a kernel cannot be interpreted inside a shard_map
+        # body (the CPU mesh).  There the oracle's identical math stands
+        # in; on the chip the kernels are compiled and this never runs.
+        return fused_norm_reference(
+            x, scale, bias, mean, var,
+            use_running_average=use_running_average, epsilon=epsilon,
+            relu=relu)
     c = x.shape[-1]
     r = x.size // c
     if r == 0:

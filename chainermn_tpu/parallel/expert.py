@@ -39,8 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.utils import axis_size as _axis_size
-
 
 def moe_plan_topology(axis_name):
     """The :class:`~chainermn_tpu.planner.ir.PlanTopology` of the MoE
@@ -52,7 +50,7 @@ def moe_plan_topology(axis_name):
     names = (tuple(axis_name) if isinstance(axis_name, (tuple, list))
              else (axis_name,))
     return PlanTopology(axes=tuple(
-        (str(n), int(_axis_size(n))) for n in names))
+        (str(n), int(jax.lax.axis_size(n))) for n in names))
 
 
 def moe_apply(expert_fn: Callable, gate_logits, x, axis_name,
@@ -90,7 +88,7 @@ def moe_apply(expert_fn: Callable, gate_logits, x, axis_name,
     (``observability.spans.get_plan_obs()``) turns on per-hop
     ``plan_stage`` spans.  ``plan=None`` is today's raw path, untouched.
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     n, d = x.shape
     e = int(num_experts) if num_experts is not None else gate_logits.shape[-1]
     if gate_logits.shape[-1] != e:
@@ -212,7 +210,7 @@ class ExpertParallelMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        p = _axis_size(self.axis_name)
+        p = jax.lax.axis_size(self.axis_name)
         e = self.num_experts if self.num_experts is not None else p
         if e % p:
             raise ValueError(f"num_experts ({e}) must be a multiple of the "

@@ -61,14 +61,6 @@ import types
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
-
-from chainermn_tpu.utils import shard_map as _shard_map
-from chainermn_tpu.utils import _native_shard_map
-
-# Pre-vma jax transposes psum to psum instead of the identity broadcast,
-# so a global_loss objective (psum'd inside loss_fn) comes back with its
-# gradient inflated by the world size; the step divides it back out.
-_LEGACY_PSUM_TRANSPOSE = _native_shard_map is None
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -865,7 +857,11 @@ def make_fsdp_train_step(
             # the summed shard grads ARE the global gradient.)
             gshards = jax.tree.map(
                 lambda g: g / jnp.asarray(size, g.dtype), gshards)
-        elif _LEGACY_PSUM_TRANSPOSE:
+        elif not check_vma:
+            # Without vma tracking psum transposes to psum instead of
+            # the identity broadcast, so the gradient of a psum'd
+            # (global_loss) objective comes back inflated by the world
+            # size; divide it back out.
             gshards = jax.tree.map(
                 lambda g: g / jnp.asarray(size, g.dtype), gshards)
         updates, inner = optimizer.update(gshards, inner, shards)
@@ -900,7 +896,7 @@ def make_fsdp_train_step(
     if not with_model_state:
         def inner_fn(state, batch):  # noqa: F811
             return step(state, None, batch)
-    mapped = _shard_map(inner_fn, mesh=comm.mesh,
+    mapped = jax.shard_map(inner_fn, mesh=comm.mesh,
                            in_specs=in_specs, out_specs=out_specs,
                            check_vma=check_vma)
     donate_argnums = ((0, 1) if with_model_state else (0,)) if donate else ()

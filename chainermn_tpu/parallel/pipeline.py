@@ -25,18 +25,10 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from chainermn_tpu.utils import shard_map as _shard_map
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.utils import axis_size as _axis_size, pvary
-from chainermn_tpu.utils import _native_shard_map
-
-# Pre-vma shard_map cannot reconcile the scan carry's replication types in
-# the 1F1B schedule (jax suggests check_rep=False as the workaround); newer
-# jax keeps full vma checking.
-_LEGACY_KW = {} if _native_shard_map is not None else {"check_vma": False}
+from chainermn_tpu.utils import pvary
 
 
 def pipeline_apply(
@@ -68,7 +60,7 @@ def pipeline_apply(
     if collect not in ("all_gather", "last"):
         raise ValueError(f"collect must be 'all_gather' or 'last', "
                          f"got {collect!r}")
-    size = _axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     m = x.shape[0]
     ticks = size + m - 1
@@ -149,7 +141,7 @@ def pipeline_1f1b(
     loss over microbatches (replicated via a scalar psum) and THIS
     device's parameter gradients of that mean.
     """
-    size = _axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     m = x.shape[0]
     if targets.shape[0] != m:
@@ -246,10 +238,10 @@ def make_pipeline_train_fn(
                                         axis_name)
             return loss, jax.tree.map(lambda g: g[None], grads)
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis_name), P(), P()),
-            out_specs=(P(), P(axis_name)), **_LEGACY_KW)(
+            out_specs=(P(), P(axis_name)))(
                 stacked_params, batch, targets)
 
     return jax.jit(fn)
@@ -279,10 +271,10 @@ def make_pipeline_fn(
             out = pipeline_apply(stage_fn, local, mb, axis_name)
             return out.reshape((-1,) + out.shape[2:])
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis_name), P()),
-            out_specs=P(), **_LEGACY_KW)(stacked_params, batch)
+            out_specs=P())(stacked_params, batch)
 
     return jax.jit(fn)
 

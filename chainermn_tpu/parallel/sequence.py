@@ -40,11 +40,8 @@ import functools
 from typing import Callable, Optional
 
 import jax
-
 import jax.numpy as jnp
 from jax import lax
-
-from chainermn_tpu.utils import axis_size as _axis_size
 
 
 def attention(q, k, v, *, causal: bool = False, sm_scale: Optional[float] = None,
@@ -96,7 +93,7 @@ def ring_attention(q, k, v, axis_name, *, causal: bool = False,
     if attn_fn is not None:
         return _ring_attention_kernel(q, k, v, axis_name, causal=causal,
                                       sm_scale=sm_scale, attn_fn=attn_fn)
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     t_local = q.shape[1]
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
@@ -147,7 +144,7 @@ def _ring_attention_kernel(q, k, v, axis_name, *, causal, sm_scale, attn_fn):
     """Ring attention with a fused per-block kernel (see ring_attention)."""
     from chainermn_tpu.utils import pvary
 
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     # Only the causal mask consumes the global block offsets; computing
     # axis_index in the non-causal trace would leave a dead PartitionId
     # that XLA hoists out of the manual region and then refuses to
@@ -205,7 +202,7 @@ def ulysses_attention(q, k, v, axis_name, *, causal: bool = False,
     ``attn_fn(q, k, v, causal=..., sm_scale=...)`` defaults to
     :func:`attention`; pass a fused kernel to swap the inner math.
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % size != 0:
         raise ValueError(

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.utils import axis_size as _axis_size
-
 
 class ColumnParallelDense(nn.Module):
     """Output-feature-sharded Dense: full input -> local feature slice.
@@ -58,7 +56,7 @@ class ColumnParallelDense(nn.Module):
             # value-identical, but typed INVARIANT over the axis (the vma
             # system cannot infer invariance for all_gather outputs), so
             # the result composes with replicated out_specs.
-            size = _axis_size(self.axis_name)
+            size = jax.lax.axis_size(self.axis_name)
             idx = lax.axis_index(self.axis_name)
             full = jnp.zeros(y.shape[:-1] + (size * self.features,),
                              y.dtype)
@@ -108,7 +106,7 @@ class TensorParallelMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        size = _axis_size(self.axis_name)
+        size = jax.lax.axis_size(self.axis_name)
         if self.hidden % size:
             raise ValueError(
                 f"hidden ({self.hidden}) must divide by the tp axis "

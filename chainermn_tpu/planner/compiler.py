@@ -243,7 +243,8 @@ def _compressed_psum(st: Stage, idx: int, axes, world: int, buf, state,
             obs.make_callback("decompress", "begin", seam, idx,
                               comp.name, bpp, saved),
             rank, 0.0, summed[0])
-    out, state = comp.decompress(summed, state, world_size=world)
+    out, state = comp.decompress(summed, state, world_size=world,
+                                 axes=_axis_arg(axes))
     if obs is not None:
         mp = comp._padded(m)
         sat = jnp.sum(summed[mp:].astype(jnp.float32))
@@ -746,6 +747,17 @@ def plan_census_kinds(plan: Plan, topology: PlanTopology,
         else:
             kinds.append(_CENSUS_KIND[st.op])
     return tuple(kinds)
+
+
+def plan_scale_exchanges(plan: Plan, topology: PlanTopology) -> int:
+    """How many scale exchanges ``plan`` compiles to: each quantizing hop
+    the compiler emits adds one float32 all-reduce (a ``pmax`` typing its
+    rank-identical per-chunk scale exponents replicated — see
+    ``_ScaledQuantizer.decompress``).  It depends on nothing but the
+    carried state until the decode, so XLA schedules it freely: in the
+    census it is a one-op sequence interleaved with the stage chains,
+    not a position in them."""
+    return len(plan_compressed_hops(plan, topology))
 
 
 def plan_wire_dtypes(plan: Plan, topology: PlanTopology,

@@ -14,11 +14,14 @@ One env contract covers the whole stack (shared with
 
 ``init_distributed()`` wires ``jax.distributed.initialize`` from it —
 the JAX coordination service listens on ``port + 1`` (the control plane
-owns ``port``).  On real TPU slices the arguments can be omitted
+owns ``port``).  On a multi-host TPU slice the arguments can be omitted
 entirely: ``jax.distributed.initialize()`` discovers everything from
-slice metadata, which IS the no-launcher path.  On CPU it also selects
-gloo cross-process collectives so the multi-controller tests/examples
-run on any machine.
+slice metadata, which IS the no-launcher path.  A slice of ONE host —
+what the TPU runtime's own environment says with a single entry in
+``TPU_WORKER_HOSTNAMES`` — is the plain case: one controller already
+drives every chip of the host, and nothing is initialized or probed.  On
+CPU the explicit path also selects gloo cross-process collectives so the
+multi-controller tests/examples run on any machine.
 """
 
 from __future__ import annotations
@@ -131,6 +134,10 @@ def install_crash_dumps(out_dir: Optional[str] = None,
     return uninstall
 
 
+_SLICE_ENV = ("TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID", "CLOUD_TPU_TASK_ID",
+              "TPU_ACCELERATOR_TYPE")
+
+
 def _tpu_metadata_present() -> bool:
     """True when this host looks like part of a Cloud TPU slice.
 
@@ -140,13 +147,23 @@ def _tpu_metadata_present() -> bool:
     should run.  Check the slice-metadata env the TPU runtime exports
     (any one suffices).  Deliberately NOT a libtpu-presence check: the
     wheel being installed says nothing about running on a slice, and a
-    false positive here costs an off-GCP metadata-server probe.
+    false positive here costs an off-GCP metadata-server probe.  Nor
+    ``TPU_SKIP_MDS_QUERY``: ``import jax`` sets that itself on a host
+    where it finds no chip.
     """
-    for var in ("TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID", "CLOUD_TPU_TASK_ID",
-                "TPU_SKIP_MDS_QUERY", "TPU_ACCELERATOR_TYPE"):
-        if os.environ.get(var):
-            return True
-    return False
+    return any(os.environ.get(var) for var in _SLICE_ENV)
+
+
+def _declared_single_host() -> bool:
+    """True when the TPU runtime's environment declares a slice of one
+    host: ``TPU_WORKER_HOSTNAMES`` lists a single worker and no
+    multislice coordinator is named.  An undeclared worker list is NOT
+    single-host — on a GCE pod it lives on the metadata server, and only
+    ``jax.distributed.initialize()`` can ask."""
+    hosts = [h for h in os.environ.get(
+        "TPU_WORKER_HOSTNAMES", "").split(",") if h.strip()]
+    return (len(hosts) == 1
+            and not os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"))
 
 
 def init_distributed(
@@ -178,41 +195,26 @@ def init_distributed(
         # slice metadata.  Attempt it when the configured platform looks
         # like TPU — or when Cloud TPU metadata is present even though
         # JAX_PLATFORMS is unset (the common case: the TPU plugin is
-        # auto-discovered, nobody exports JAX_PLATFORMS).  Off-TPU stay
+        # auto-discovered, nobody exports JAX_PLATFORMS) — unless the
+        # runtime declares a one-host slice: single-controller is then
+        # the whole world, and the call would only probe a metadata
+        # server that a sealed host cannot reach.  Off-TPU stay
         # single-controller.
         platforms = (os.environ.get("JAX_PLATFORMS")
                      or getattr(jax.config, "jax_platforms", None) or "")
-        if "tpu" in platforms or ("cpu" not in platforms and _tpu_metadata_present()):
+        on_slice = "tpu" in platforms or (
+            "cpu" not in platforms and _tpu_metadata_present())
+        if on_slice and not _declared_single_host():
             try:
                 jax.distributed.initialize()
             except RuntimeError as e:
-                # "already initialized" is fine; so is "must be called
-                # before any JAX calls" on a SINGLE-host slice (some TPU
-                # platform plugins initialize the backend at interpreter
-                # startup, before user code can run — single-controller
-                # is then exactly the right world).  On a multi-host
-                # slice the same condition must NOT be swallowed: each
-                # host silently proceeding as its own single-controller
-                # world would train divergent models.
-                msg = str(e).lower()
-                hosts = [h for h in os.environ.get(
-                    "TPU_WORKER_HOSTNAMES", "").split(",") if h]
-                single_host = len(hosts) <= 1
-                if "already" in msg:
-                    pass
-                elif "must be called before" in msg and single_host:
-                    pass
-                else:
+                # "already initialized" is fine.  Anything else is a
+                # multi-host bootstrap that did not come up, and must
+                # not be swallowed: each host silently proceeding as its
+                # own single-controller world would train divergent
+                # models.
+                if "already" not in str(e).lower():
                     raise
-            except Exception as e:
-                import warnings
-
-                warnings.warn(
-                    f"jax.distributed.initialize() from TPU metadata "
-                    f"failed ({e!r}); continuing single-controller. If "
-                    f"this host is part of a multi-host slice, fix the "
-                    f"bootstrap — training would silently diverge.",
-                    RuntimeWarning)
         install_crash_dumps()   # no-op when observability is disabled
         return
 
@@ -230,12 +232,7 @@ def init_distributed(
         # cross-process CPU collectives (the tests' multi-host analogue)
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if local_device_count is not None:
-        # jax < 0.5 has no jax_num_cpu_devices option; fall back to the
-        # XLA_FLAGS knob (must land before the first backend exists,
-        # which holds here — bootstrap precedes any jax.devices() call)
-        from chainermn_tpu.utils.cpu_mesh import _set_cpu_device_flags
-
-        _set_cpu_device_flags(local_device_count)
+        jax.config.update("jax_num_cpu_devices", local_device_count)
 
     jax.distributed.initialize(
         coordinator_address=jax_coord,

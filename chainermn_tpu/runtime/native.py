@@ -7,31 +7,42 @@ prefers this backend and falls back to pure Python when no compiler is
 available (mirroring the reference's pure-Python install path, which ran
 without its optional Cython NCCL extension).
 
-Build cache: ``_libdcn.so`` next to the source, rebuilt when the source is
-newer.  Disable with ``CHAINERMN_TPU_NATIVE_BUILD=0``.
+Build cache: ``_libdcn-<source hash>.so`` next to the source.  The name IS
+the key: a library built from another ``dcn_transport.cpp`` (a stale build
+copied along with a checkout, whatever its mtime says) has another name and
+is never loaded.  Disable building with ``CHAINERMN_TPU_NATIVE_BUILD=0``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 from typing import Optional
 
-_SRC = os.path.join(os.path.dirname(__file__), "dcn_transport.cpp")
-_LIB = os.path.join(os.path.dirname(__file__), "_libdcn.so")
+_DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_DIR, "dcn_transport.cpp")
 _BUILD_LOCK = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _lib_path() -> str:
+    """Where the library built from the present source lives."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_libdcn-{digest}.so")
+
+
 def _build() -> str:
+    lib = _lib_path()
+    if os.path.exists(lib):
+        return lib
     if os.environ.get("CHAINERMN_TPU_NATIVE_BUILD") == "0":
         raise ImportError("native build disabled (CHAINERMN_TPU_NATIVE_BUILD=0)")
-    if (os.path.exists(_LIB)
-            and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-        return _LIB
-    tmp = _LIB + f".tmp{os.getpid()}"
+    tmp = lib + f".tmp{os.getpid()}"
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
            _SRC, "-o", tmp]
     try:
@@ -40,8 +51,14 @@ def _build() -> str:
         stderr = getattr(e, "stderr", b"") or b""
         raise ImportError(
             f"building dcn_transport failed: {e}\n{stderr.decode()}") from e
-    os.replace(tmp, _LIB)  # atomic under concurrent builders
-    return _LIB
+    os.replace(tmp, lib)  # atomic under concurrent builders
+    for stale in glob.glob(os.path.join(_DIR, "_libdcn*.so")):
+        if stale != lib:
+            try:
+                os.remove(stale)   # built from a source that is gone
+            except OSError:
+                pass
+    return lib
 
 
 def _load() -> ctypes.CDLL:

@@ -17,6 +17,7 @@ reference's hostname-allgather bootstrap 〔_communication_utility.py〕.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import queue
 import socket
@@ -26,6 +27,7 @@ import time
 from typing import Dict, Tuple
 
 _HDR = struct.Struct("<IIQ")
+_log = logging.getLogger(__name__)
 
 # Inbox high-water mark (bytes).  When a reader thread would push the inbox
 # past this, it blocks until a consumer drains — TCP flow control then
@@ -265,12 +267,21 @@ class PyTransport:
 
 
 def create_transport(rank: int, size: int, coordinator: str):
-    """Prefer the native C++ core; fall back to pure Python (same protocol)."""
-    if os.environ.get("CHAINERMN_TPU_PURE_PY_TRANSPORT") != "1":
-        try:
-            from chainermn_tpu.runtime.native import NativeTransport
+    """Prefer the native C++ core; fall back to pure Python (same protocol).
+    Which one loaded is logged — the fallback as a warning, since a missing
+    compiler silently costs the native core's framing throughput."""
+    if os.environ.get("CHAINERMN_TPU_PURE_PY_TRANSPORT") == "1":
+        _log.info("DCN transport: pure Python "
+                  "(CHAINERMN_TPU_PURE_PY_TRANSPORT=1)")
+        return PyTransport(rank, size, coordinator)
+    try:
+        from chainermn_tpu.runtime import native
 
-            return NativeTransport(rank, size, coordinator)
-        except (ImportError, OSError):
-            pass
-    return PyTransport(rank, size, coordinator)
+        transport = native.NativeTransport(rank, size, coordinator)
+    except (ImportError, OSError) as e:
+        _log.warning("DCN transport: pure Python — the native core did "
+                     "not load (%s: %s)", type(e).__name__, e)
+        return PyTransport(rank, size, coordinator)
+    _log.info("DCN transport: native (%s)",
+              os.path.basename(native._lib_path()))
+    return transport

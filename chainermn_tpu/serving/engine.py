@@ -418,8 +418,6 @@ class InferenceEngine:
 
         from jax.sharding import PartitionSpec as P
 
-        from chainermn_tpu import utils as _utils
-
         def body(params_st, ck_st, cv_st, page_table, tokens, pos0, n_new):
             params = jax.tree.map(lambda x: x[0], params_st)
             sampled, last_logits, nk, nv = forward(
@@ -427,7 +425,7 @@ class InferenceEngine:
                 n_new)
             return sampled, last_logits, nk[None], nv[None]
 
-        return jax.jit(_utils.shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P(), P(), P(), P()),
             out_specs=(P(), P(), P("tp"), P("tp")), check_vma=False))
@@ -534,8 +532,6 @@ class InferenceEngine:
 
         from jax.sharding import PartitionSpec as P
 
-        from chainermn_tpu import utils as _utils
-
         def body(params_st, dparams_st, ck_st, cv_st, dck_st, dcv_st,
                  page_table, tokens, pos0, n_new, is_decode, prev):
             params = jax.tree.map(lambda x: x[0], params_st)
@@ -546,7 +542,7 @@ class InferenceEngine:
             return (out, n_out, last_logits, ck[None], cv[None],
                     dck[None], dcv[None])
 
-        return jax.jit(_utils.shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=(P("tp"), P("tp"), P("tp"), P("tp"), P("tp"),
                       P("tp"), P(), P(), P(), P(), P(), P()),

@@ -1,4 +1,4 @@
-"""Small shared utilities (version compatibility, tree helpers)."""
+"""Small shared utilities."""
 
 from __future__ import annotations
 
@@ -8,76 +8,14 @@ import jax
 def pvary(x, axes):
     """Mark ``x`` as varying over mesh ``axes`` inside shard_map.
 
-    Idempotent (axes already in the value's vma are skipped — pcast
-    rejects varying→varying).  ``jax.lax.pvary`` is deprecated in favor
-    of ``jax.lax.pcast(..., to='varying')``; this shim targets whichever
-    this jax version provides.
-    """
+    Idempotent: axes already in the value's vma are skipped
+    (``jax.lax.pcast`` rejects varying→varying)."""
     want = (axes,) if isinstance(axes, str) else tuple(axes)
-    try:
-        have = jax.typeof(x).vma
-        missing = tuple(a for a in want if a not in have)
-    except (AttributeError, TypeError):
-        missing = want
+    have = jax.typeof(x).vma
+    missing = tuple(a for a in want if a not in have)
     if not missing:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, missing, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, missing)
-    return x  # pre-vma jax: shard_map's check_rep tracks replication itself
+    return jax.lax.pcast(x, missing, to="varying")
 
 
-_native_shard_map = getattr(jax, "shard_map", None)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` where this jax exports it (>= 0.5), else the
-    ``jax.experimental.shard_map`` spelling of older versions, with the
-    ``check_vma``/``check_rep`` kwarg rename translated."""
-    if _native_shard_map is not None:
-        return _native_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    # Old shard_map's check_rep raises spurious "Scan carry ... mismatched
-    # replication types" errors on valid programs (the error text itself
-    # suggests check_rep=False); default it off unless the caller asked.
-    kwargs.setdefault("check_rep", False)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """Size of a bound mesh axis (``lax.axis_size`` where available, else
-    the ``psum(1)`` idiom older jax versions require)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def typeof(x):
-    """``jax.typeof`` where available; older versions fall back to the
-    abstract value, which simply lacks ``vma`` metadata (callers probe it
-    with ``getattr(..., "vma", None)``)."""
-    t = getattr(jax, "typeof", None)
-    if t is not None:
-        return t(x)
-    from jax import core
-
-    return core.get_aval(x)
-
-
-def _install_jax_shard_map_alias() -> None:
-    # jax < 0.5 has no jax.shard_map; alias the compat wrapper onto the
-    # jax namespace so tests/examples written against the current API
-    # (jax.shard_map(..., check_vma=...)) run unchanged on this version.
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
-
-
-_install_jax_shard_map_alias()
-
-__all__ = ["axis_size", "pvary", "shard_map", "typeof"]
+__all__ = ["pvary"]

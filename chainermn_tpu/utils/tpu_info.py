@@ -17,26 +17,19 @@ PEAK_TFLOPS = {
     "tpu v6e": 918.0,
 }
 
-_DEFAULT_PEAK = 197.0  # assume v5e-class when the kind string is unknown
-
 
 def peak_tflops(device) -> float:
-    """bf16 peak of ``device`` (a ``jax.Device``), by device_kind substring."""
-    return peak_tflops_info(device)[0]
-
-
-def peak_tflops_info(device):
-    """``(peak, matched_kind)`` — ``matched_kind`` is the PEAK_TFLOPS key
-    that matched ``device.device_kind``, or ``None`` when the device is
-    unknown and ``peak`` is the assumed v5e-class default.  Benchmarks use
-    the None case to mark their MFU as computed against an ASSUMED peak
-    (``peak_assumed: true`` in the bench JSON) instead of presenting a
-    made-up utilization as fact (ADVICE r5)."""
+    """bf16 peak of ``device`` (a ``jax.Device``), by device_kind
+    substring.  A device that is not in the table is an error: a
+    utilization against an assumed peak is a made-up number."""
     kind = getattr(device, "device_kind", "").lower()
     for k, v in PEAK_TFLOPS.items():
         if k in kind:
-            return v, k
-    return _DEFAULT_PEAK, None
+            return v
+    raise KeyError(
+        f"no bf16 peak known for device_kind {kind!r}; add it to "
+        f"chainermn_tpu.utils.tpu_info.PEAK_TFLOPS with its source "
+        f"(known: {sorted(PEAK_TFLOPS)})")
 
 
-__all__ = ["PEAK_TFLOPS", "peak_tflops", "peak_tflops_info"]
+__all__ = ["PEAK_TFLOPS", "peak_tflops"]

@@ -34,7 +34,6 @@ from chainermn_tpu.analysis import (
     parse_hlo_collectives,
     schedule_from_hlo,
 )
-from chainermn_tpu.utils import shard_map
 from jax.sharding import PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -139,7 +138,7 @@ def test_hlo_parser_flags_unmatched_async_halves():
 # ---------------------------------------------------------------------------
 
 def test_extract_schedule_descends_into_spmd_bodies(devices):
-    """Collectives inside jit(shard_map(...)) bodies — the make_train_step
+    """Collectives inside jit(jax.shard_map(...)) bodies — the make_train_step
     nesting — are all visible, in issue order, with axes and payload."""
     comm = chainermn_tpu.create_communicator("xla")
     ax = comm.data_axes
@@ -149,7 +148,7 @@ def test_extract_schedule_descends_into_spmd_bodies(devices):
         z = jax.lax.pmax(y, ax)
         return z
 
-    step = jax.jit(shard_map(body, mesh=comm.mesh, in_specs=P(ax),
+    step = jax.jit(jax.shard_map(body, mesh=comm.mesh, in_specs=P(ax),
                              out_specs=P(ax), check_vma=False))
     sched = extract_schedule(step, jnp.ones((comm.size, 4)))
     assert sched.kinds() == ("psum", "pmax")
@@ -168,7 +167,7 @@ def test_extract_schedule_sees_both_cond_branches(devices):
                             lambda v: jax.lax.psum(v, ax),
                             lambda v: v * 2.0, x)
 
-    step = shard_map(body, mesh=comm.mesh, in_specs=P(ax),
+    step = jax.shard_map(body, mesh=comm.mesh, in_specs=P(ax),
                      out_specs=P(ax), check_vma=False)
     sched = extract_schedule(step, jnp.ones((comm.size, 4)))
     assert sched.kinds() == ("psum",)
@@ -213,7 +212,7 @@ def test_rule_schedule_desync_catches_rank_divergent_order(devices):
             if rank == 0:
                 return jax.lax.pmax(jax.lax.psum(x, ax), ax)
             return jax.lax.psum(jax.lax.pmax(x, ax), ax)
-        return shard_map(body, mesh=comm.mesh, in_specs=P(ax),
+        return jax.shard_map(body, mesh=comm.mesh, in_specs=P(ax),
                          out_specs=P(ax), check_vma=False)
 
     x = jnp.ones((comm.size, 4))
@@ -784,7 +783,7 @@ def test_rules_still_fire_through_fused_norm(devices):
             if rank == 0:
                 return jax.lax.pmax(jax.lax.psum(y, ax), ax)
             return jax.lax.psum(jax.lax.pmax(y, ax), ax)
-        return shard_map(body, mesh=comm.mesh, in_specs=P(ax),
+        return jax.shard_map(body, mesh=comm.mesh, in_specs=P(ax),
                          out_specs=P(ax), check_vma=False)
 
     x = jnp.ones((comm.size * 2, 8))

@@ -35,7 +35,7 @@ def _census():
 
 
 def test_repo_root_has_committed_artifacts():
-    assert len(_census()) >= 40      # the walk actually finds the set
+    assert len(_census()) >= 38      # the walk actually finds the set
 
 
 def test_every_committed_artifact_has_a_registered_schema():

@@ -1,0 +1,121 @@
+"""The main path's Pallas kernels, compiled for a described v5e — no chip.
+
+Interpret-mode tests cannot see what the TPU compiler refuses: a slice not
+aligned to the tiling, more VMEM than a kernel may use, a kernel that
+cannot be partitioned.  libtpu compiles for a chip that is described and
+not attached (on-chip-measurement guide, section 2), so each kernel is
+compiled here at the widths the main path runs — about two seconds a case —
+and must come out as a ``tpu_custom_call``.  ``jax.default_backend`` is
+steered to ``"tpu"`` while tracing so the entries leave interpret mode;
+nothing runs, so this says nothing about results or times (``chip_smoke.py``
+does, on the chip).  Whole step programs: ``tools/compile_for_chip.py``.
+"""
+
+import os
+import unittest.mock
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from chainermn_tpu.ops.cast_scale import cast_scale  # noqa: E402
+from chainermn_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from chainermn_tpu.ops.fused_norm import fused_norm  # noqa: E402
+
+# ResNet-50's packed float32 gradient buffer: 25.5M elements
+RESNET50_PARAMS = 25_557_032
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+def _flash_loss(q, k, v, segment_ids=None):
+    out = flash_attention(q, k, v, causal=True, q_segment_ids=segment_ids,
+                          kv_segment_ids=segment_ids)
+    return out.astype(jnp.float32).sum()
+
+
+_flash_grad = jax.grad(_flash_loss, argnums=(0, 1, 2))
+
+
+def _norm_grad(x, scale, bias):
+    return jax.grad(
+        lambda *a: fused_norm(*a)[0].astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(x, scale, bias)
+
+
+_Q = ((1, 8192, 16, 128), jnp.bfloat16)     # the LM's [B, T, H, D]
+_KV_GQA = ((1, 8192, 4, 128), jnp.bfloat16)
+_SEG = ((1, 8192), jnp.int32)
+_GRADS = ((RESNET50_PARAMS,), jnp.float32)
+
+
+def _norm_args(shape):
+    return [(shape, jnp.bfloat16), ((shape[-1],), jnp.float32),
+            ((shape[-1],), jnp.float32)]
+
+
+# name -> (function, [(shape, dtype)], tpu_custom_calls expected at least)
+CASES = {
+    "flash_fwd": (lambda q, k, v: flash_attention(q, k, v, causal=True),
+                  [_Q, _Q, _Q], 1),
+    "flash_fwd_bwd": (_flash_grad, [_Q, _Q, _Q], 3),
+    "flash_gqa_fwd_bwd": (_flash_grad, [_Q, _KV_GQA, _KV_GQA], 3),
+    "flash_segment_ids_fwd_bwd": (_flash_grad, [_Q, _Q, _Q, _SEG], 3),
+    "cast_scale_resnet50_grads": (
+        lambda g: cast_scale(g, jnp.bfloat16, 0.25), [_GRADS], 1),
+    "fused_norm_fwd_stage1": (
+        lambda *a: fused_norm(*a)[0], _norm_args((256, 112, 112, 64)), 2),
+    "fused_norm_fwd_bwd_stage1": (
+        _norm_grad, _norm_args((256, 112, 112, 64)), 3),
+    "fused_norm_fwd_stage4": (
+        lambda *a: fused_norm(*a)[0], _norm_args((256, 7, 7, 2048)), 2),
+    "fused_norm_fwd_bwd_stage4": (
+        _norm_grad, _norm_args((256, 7, 7, 2048)), 3),
+}
+
+
+def _compile(fn, args):
+    with unittest.mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = jax.jit(fn).lower(*args)
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_described_v5e(topo, name):
+    fn, specs, want = CASES[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    text = _compile(fn, args)
+    assert text.count("tpu_custom_call") >= want, (
+        f"{name}: {text.count('tpu_custom_call')} tpu_custom_call in the "
+        f"compiled program, expected at least {want}")
+
+
+def test_cast_scale_compiles_inside_four_chip_shard_map(topo):
+    """The gradient-wire kernel where the train step runs it: inside
+    ``shard_map`` over four chips, on device-varying buffers."""
+    mesh = Mesh(topo.devices, ("d",))
+    sharded = NamedSharding(mesh, P("d"))
+    grads = jax.ShapeDtypeStruct((4 * RESNET50_PARAMS,), jnp.float32,
+                                 sharding=sharded)
+
+    def wire(g):
+        return jax.shard_map(
+            lambda v: cast_scale(v, jnp.bfloat16, 0.25), mesh=mesh,
+            in_specs=P("d"), out_specs=P("d"))(g)
+
+    assert "tpu_custom_call" in _compile(wire, [grads])

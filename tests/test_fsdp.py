@@ -384,7 +384,12 @@ class TestSequenceParallelComposition:
     FSDP x sequence-parallel composition; examples/long_context
     --fsdp pins it end to end)."""
 
-    def test_global_loss_matches_replicated(self, comm):
+    @pytest.mark.parametrize("check_vma", [True, False])
+    def test_global_loss_matches_replicated(self, comm, check_vma):
+        """With vma tracking on, the loss's psum transposes to the
+        identity; with it off (what Pallas interpret mode forces on the
+        CPU) psum transposes to psum and the step divides the world size
+        back out — the same trained weights either way."""
         from jax.sharding import PartitionSpec as P
 
         # params [D]; batch [B, T] sharded over T; global objective =
@@ -410,7 +415,8 @@ class TestSequenceParallelComposition:
         state, meta = fsdp_init(comm, params, optax.sgd(0.1))
         step = make_fsdp_train_step(
             comm, loss_fn, optax.sgd(0.1), meta,
-            batch_spec=P(None, axes), global_loss=True, donate=False)
+            batch_spec=P(None, axes), global_loss=True, donate=False,
+            check_vma=check_vma)
 
         # replicated reference: same objective, plain jit
         def ref_loss(p):

@@ -46,7 +46,6 @@ from chainermn_tpu.planner import (
     plan_wire_dtypes,
 )
 from chainermn_tpu.planner.ir import Plan, Stage, StageGroup
-from chainermn_tpu.utils import shard_map
 
 TOPO_1D = PlanTopology(axes=(("ep", 8),))
 TOPO_2D = PlanTopology(axes=(("inter", 2), ("intra", 4)))
@@ -79,7 +78,7 @@ def _exchange_pair(plan, topo, n=4, d=3, pobs=None):
                 lax.all_to_all(buf, axis_arg, 0, 0, tiled=True))
 
     out_spec = P(names if len(names) > 1 else names[0])
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(*names),
         out_specs=(out_spec, out_spec), check_vma=False))
     a, b = fn(jnp.zeros(tuple(s for _, s in topo.axes)))
@@ -262,7 +261,7 @@ def _moe_pair(plan, topo, expert_fn=lambda t: t * 2.0, top_k=2, n=16,
                 moe_apply(expert_fn, g, x, axis_arg, **kw), x, g)
 
     spec = P(names if len(names) > 1 else names[0])
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(*names),
         out_specs=(spec, spec, spec, spec), check_vma=False))
     out = fn(jnp.zeros(tuple(s for _, s in topo.axes)))
@@ -309,7 +308,7 @@ class TestMoePlanSeam:
             w = jax.nn.softmax(g.astype(jnp.float32), -1)[:, :1]
             return y, x, w
 
-        y, x, w = jax.jit(shard_map(
+        y, x, w = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("ep"),
             out_specs=(P("ep"),) * 3, check_vma=False))(jnp.zeros((8,)))
         y = y.reshape(8, n, d)
@@ -328,7 +327,7 @@ class TestMoePlanSeam:
             assert topo.axes == (("inter", 2), ("intra", 4))
             return z
 
-        jax.jit(shard_map(body, mesh=mesh, in_specs=P("inter", "intra"),
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("inter", "intra"),
                           out_specs=P("inter", "intra"),
                           check_vma=False))(jnp.zeros((2, 4)))
 
@@ -371,7 +370,7 @@ class TestMoeObservability:
                              top_k=2, num_experts=8, plan=plan,
                              plan_obs=pobs)
 
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("inter", "intra"),
             out_specs=P(("inter", "intra")),
             check_vma=False))(jnp.zeros((2, 4)))
@@ -431,7 +430,7 @@ def _moe_lm(vocab=32):
 
 def _moe_lm_params(model):
     mesh = Mesh(np.array(jax.devices()[:2]), ("ep",))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda tk: model.init(jax.random.key(0), tk), mesh=mesh,
         in_specs=P(), out_specs=P(),
         check_vma=False))(jnp.zeros((1, 4), jnp.int32))
@@ -518,7 +517,7 @@ def _exchange_hlo(plan, topo):
     mesh, names = _mesh_for(topo)
     block = topo.size
     buf = jnp.zeros((block * block, 4, 4), jnp.float32)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda b: execute_alltoall(plan, topo, b), mesh=mesh,
         in_specs=P(names if len(names) > 1 else names[0]),
         out_specs=P(names if len(names) > 1 else names[0]),
@@ -600,7 +599,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from chainermn_tpu.parallel.expert import ExpertParallelMLP
 from chainermn_tpu.planner import alltoall_plans
-from chainermn_tpu.utils import shard_map
 
 assert jax.process_count() == 2 and jax.device_count() == 8
 
@@ -635,10 +633,10 @@ def run(plan_name):
         return lax.pmean(jnp.mean((out - y) ** 2), AX)
 
     def loss_fn(pp, z):
-        return shard_map(fwd, mesh=mesh, in_specs=(P(), P()),
+        return jax.shard_map(fwd, mesh=mesh, in_specs=(P(), P()),
                          out_specs=P(), check_vma=False)(pp, z)
 
-    params = jax.jit(shard_map(
+    params = jax.jit(jax.shard_map(
         lambda z: model.init(jax.random.key(0),
                              data(lax.axis_index(AX))[0]),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))(tok)

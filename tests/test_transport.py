@@ -5,6 +5,7 @@ real "ranks" (threads standing in for host controllers, as the reference's
 CPU CI ran multiple MPI ranks on one box).
 """
 
+import os
 import pickle
 import socket
 import threading
@@ -439,3 +440,39 @@ def test_transport_microbench_perf():
             return
         last = (ratio, nat, py)
     raise AssertionError(f"goodput floor failed on all attempts: {last}")
+
+
+class TestNativeBuildKey:
+    """The native library is keyed on a hash of its source: whatever sits
+    next to a CHANGED ``dcn_transport.cpp`` — a stale build copied along
+    with a checkout, mtimes and all — is not what gets loaded."""
+
+    def test_stale_library_next_to_changed_source_is_not_loaded(
+            self, tmp_path, monkeypatch):
+        import shutil
+
+        from chainermn_tpu.runtime import native
+
+        if not _native_available():
+            pytest.skip("native transport not buildable here")
+        built_from_committed = native._lib_path()
+        src = tmp_path / "dcn_transport.cpp"
+        shutil.copy(native._SRC, src)
+        # a "stale" library: the one built from the committed source,
+        # made NEWER than the source it will sit next to
+        stale = tmp_path / os.path.basename(built_from_committed)
+        shutil.copy(built_from_committed, stale)
+        with open(src, "a") as f:
+            f.write("\n// changed after the library was built\n")
+        os.utime(src, (1, 1))
+        monkeypatch.setattr(native, "_SRC", str(src))
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+
+        wanted = native._lib_path()
+        assert wanted != str(stale)
+        assert native._build() == wanted
+        assert os.path.exists(wanted)
+        assert not stale.exists()      # swept: its source is gone
+        # and an unchanged source finds its library again without a build
+        monkeypatch.setenv("CHAINERMN_TPU_NATIVE_BUILD", "0")
+        assert native._build() == wanted

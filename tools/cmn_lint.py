@@ -195,13 +195,8 @@ def main(argv=None) -> int:
 
     if not args.list_entries:
         # Real accelerators win; otherwise bring up a virtual CPU mesh so
-        # the linted schedules are the multi-device ones.  The CPU device
-        # count must be configured BEFORE the first backend exists (on
-        # jax < 0.5 it latches at first client creation and no reset can
-        # grow it), and the flag is harmless when a TPU backend wins.
+        # the linted schedules are the multi-device ones.
         from chainermn_tpu.utils import cpu_mesh
-        if cpu_mesh._backend_uninitialized():
-            cpu_mesh._set_cpu_device_flags(args.devices)
         cpu_mesh.ensure_device_count(args.devices)
 
     from chainermn_tpu.analysis import all_rules
