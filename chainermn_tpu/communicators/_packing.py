@@ -12,6 +12,11 @@ allreduce; XLA owns the actual memory.  Leaves are grouped by dtype (one flat
 buffer per dtype) unless a communication dtype is forced, in which case a
 single buffer is used and the cast in/out is fused by XLA (or by the Pallas
 cast+scale kernel, see ``chainermn_tpu/ops/cast_scale.py``).
+
+What ``pack`` and ``unpack`` put into a traced program carries the named
+scopes ``chainermn.pack`` / ``chainermn.unpack`` (wire cast and 1/size scale
+included), so a device trace can tell the copies around a collective from
+the collective (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -34,17 +39,18 @@ def pack(tree: Any, comm_dtype: Optional[jnp.dtype] = None):
         return [], (treedef, [], [])
     groups: dict = {}
     order = []  # (group_key, index_within_group, shape, orig_dtype)
-    for leaf in leaves:
-        key = "comm" if comm_dtype is not None else str(leaf.dtype)
-        groups.setdefault(key, [])
-        order.append((key, len(groups[key]), leaf.shape, leaf.dtype))
-        flat = leaf.reshape(-1)
-        if comm_dtype is not None and leaf.dtype != comm_dtype:
-            flat = flat.astype(comm_dtype)
-        groups[key].append(flat)
-    keys = list(groups.keys())
-    buffers = [jnp.concatenate(groups[k]) if len(groups[k]) > 1 else groups[k][0]
-               for k in keys]
+    with jax.named_scope("chainermn.pack"):
+        for leaf in leaves:
+            key = "comm" if comm_dtype is not None else str(leaf.dtype)
+            groups.setdefault(key, [])
+            order.append((key, len(groups[key]), leaf.shape, leaf.dtype))
+            flat = leaf.reshape(-1)
+            if comm_dtype is not None and leaf.dtype != comm_dtype:
+                flat = flat.astype(comm_dtype)
+            groups[key].append(flat)
+        keys = list(groups.keys())
+        buffers = [jnp.concatenate(groups[k]) if len(groups[k]) > 1
+                   else groups[k][0] for k in keys]
     return buffers, (treedef, keys, order)
 
 
@@ -68,17 +74,18 @@ def unpack(buffers: List[jnp.ndarray], meta, scale: Optional[float] = None):
         sizes[key].append(n)
         offsets[key].append(offsets[key][-1] + n)
     pieces_by_group = {}
-    for k, buf in zip(keys, buffers):
-        cuts = offsets[k][1:-1]
-        pieces_by_group[k] = jnp.split(buf, cuts) if cuts else [buf]
     leaves = []
-    for key, idx, shape, dtype in order:
-        piece = pieces_by_group[key][idx].reshape(shape)
-        if piece.dtype != dtype:
-            piece = piece.astype(dtype)
-        if scale is not None:
-            piece = piece * jnp.asarray(scale, piece.dtype)
-        leaves.append(piece)
+    with jax.named_scope("chainermn.unpack"):
+        for k, buf in zip(keys, buffers):
+            cuts = offsets[k][1:-1]
+            pieces_by_group[k] = jnp.split(buf, cuts) if cuts else [buf]
+        for key, idx, shape, dtype in order:
+            piece = pieces_by_group[key][idx].reshape(shape)
+            if piece.dtype != dtype:
+                piece = piece.astype(dtype)
+            if scale is not None:
+                piece = piece * jnp.asarray(scale, piece.dtype)
+            leaves.append(piece)
     return jax.tree.unflatten(treedef, leaves)
 
 

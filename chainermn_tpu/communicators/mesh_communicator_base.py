@@ -457,7 +457,16 @@ CompressionState` from :meth:`init_compression_state`) and the call
           the plan runs from cold in-trace EF (one-shot semantics).
           Passing a stage-keyed ``state`` dict with ``compressor=None``
           runs this communicator's own :meth:`plan` per hop.
+
+        Everything the call puts into a traced program carries the named
+        scope ``chainermn.allreduce_grad`` (docs/observability.md): the
+        device trace then says what the exchange costs, whatever the
+        flavor, the codec or the optimizer wrapper around it.
         """
+        with jax.named_scope("chainermn.allreduce_grad"):
+            return self._allreduce_grad(grads, compressor, state)
+
+    def _allreduce_grad(self, grads, compressor, state):
         from chainermn_tpu.compression import base as _cbase
         from chainermn_tpu.compression import quantize as _cq
         from chainermn_tpu.planner.ir import Plan as _Plan

@@ -20,6 +20,7 @@ used, which profiling shows is already a single fused op.
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -56,10 +57,14 @@ class XlaCommunicator(MeshCommunicator):
         # cast-back target is known per buffer.
         buffers, meta = _packing.pack(grads)
         _, group_dtypes, _ = meta
-        comm_bufs = [cast_scale(b, comm_dtype, 1.0) for b in buffers]
+        # the kernel is the wire cast (and the 1/size scale) of this path:
+        # it reads under the same names as the cast inside pack / unpack
+        with jax.named_scope("chainermn.pack"):
+            comm_bufs = [cast_scale(b, comm_dtype, 1.0) for b in buffers]
         comm_bufs = [lax.psum(b, ax) for b in comm_bufs]
-        out = [cast_scale(b, jnp.dtype(k), scale)
-               for b, k in zip(comm_bufs, group_dtypes)]
+        with jax.named_scope("chainermn.unpack"):
+            out = [cast_scale(b, jnp.dtype(k), scale)
+                   for b, k in zip(comm_bufs, group_dtypes)]
         return _packing.unpack(out, meta, scale=None)
 
     def _legacy_allreduce_grad_traced(self, grads):

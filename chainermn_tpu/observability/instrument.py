@@ -14,17 +14,21 @@ and runs under a ``jax.profiler.TraceAnnotation`` span named
 ``chainermn_tpu.<op>`` so profiler captures line up with the
 ``utils/trace.py`` tables.
 
-Semantics note: array collectives here are *traced* ops — when a call
-happens inside ``run_spmd``/``shard_map``/``jit`` tracing, the recorded
-latency is trace-construction time and the call count is once per
-(re)trace, not once per executed step (XLA owns the executed collective;
-its device time shows up in the profiler span and in the trainer's
-``device_block`` phase).  Eager calls (``bcast_data``, the whole object
-plane, eager ``allreduce_grad``) record real per-call wall latency.
+Semantics note: array collectives here are *traced* ops.  When a call
+happens inside ``run_spmd``/``shard_map``/``jit`` tracing (its payload is
+a tracer), what the host could time is trace construction, not
+communication: such a call records its count (once per (re)trace, not
+once per executed step), its bytes and its wire dtype, and neither a
+latency nor a profiler span.  What the executed collective costs is on
+the device's clock, under the named scope ``chainermn.allreduce_grad``
+the communicator opens (docs/observability.md).  Eager calls
+(``bcast_data``, the whole object plane, eager ``allreduce_grad``) record
+real per-call wall latency under their span.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Optional
 
@@ -48,6 +52,13 @@ def _payload_bytes(tree) -> int:
             n *= int(d)
         total += n * np.dtype(dtype).itemsize
     return total
+
+
+def _is_traced(tree) -> bool:
+    import jax
+
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree.leaves(tree))
 
 
 def _leaf_dtype(tree) -> str:
@@ -94,7 +105,8 @@ class InstrumentedCommunicator:
             "payload bytes entering each collective, labeled by wire dtype")
         self._seconds = r.histogram(
             "comm_collective_seconds",
-            "host-side collective latency (trace time for traced ops)")
+            "host-side latency of eager collectives (a traced call "
+            "records none)")
         self._obj_calls = r.counter(
             "comm_object_calls", "control-plane object-op invocations")
         self._obj_seconds = r.histogram(
@@ -120,15 +132,17 @@ class InstrumentedCommunicator:
             tok = self._flight.span_begin("collective", op,
                                           comm=self._comm_label,
                                           nbytes=nbytes)
+        traced = _is_traced(payload)
         t0 = time.perf_counter()
         try:
-            with self._span(op):
+            with contextlib.nullcontext() if traced else self._span(op):
                 out = fn()
         finally:
             if tok is not None:
                 self._flight.span_end(tok)
-        self._seconds.observe(time.perf_counter() - t0, op=op,
-                              comm=self._comm_label)
+        if not traced:
+            self._seconds.observe(time.perf_counter() - t0, op=op,
+                                  comm=self._comm_label)
         return out
 
     def _run_object(self, op: str, fn):

@@ -62,7 +62,9 @@ class _MultiNodeOptimizer:
     def update(self, grads, state, params=None, **kwargs):
         grads = self.communicator.allreduce_grad(
             grads, compressor=self.compression)
-        return self.actual_optimizer.update(grads, state, params, **kwargs)
+        with jax.named_scope("chainermn.update"):
+            return self.actual_optimizer.update(
+                grads, state, params, **kwargs)
 
     # pytree spec of this optimizer's state inside an SPMD train step:
     # everything is device-invariant (replicated).
@@ -101,8 +103,9 @@ class _CompressedOptimizer:
     def update(self, grads, state, params=None, **kwargs):
         grads, comp = self.communicator.allreduce_grad(
             grads, compressor=self.compression, state=state.comp)
-        updates, inner = self.actual_optimizer.update(
-            grads, state.inner, params, **kwargs)
+        with jax.named_scope("chainermn.update"):
+            updates, inner = self.actual_optimizer.update(
+                grads, state.inner, params, **kwargs)
         return updates, _CompressedState(inner=inner, comp=comp)
 
     def state_partition_spec(self):
@@ -139,8 +142,9 @@ class _DoubleBufferingOptimizer:
 
     def update(self, grads, state, params=None, **kwargs):
         comm_grads = self.communicator.allreduce_grad(state.pending)
-        updates, inner = self.actual_optimizer.update(
-            comm_grads, state.inner, params, **kwargs)
+        with jax.named_scope("chainermn.update"):
+            updates, inner = self.actual_optimizer.update(
+                comm_grads, state.inner, params, **kwargs)
         new_state = _DoubleBufferState(
             inner=inner, pending=grads, step=state.step + 1)
         return updates, new_state
@@ -254,40 +258,47 @@ class _Zero1Optimizer:
         # reduce in the wire dtype, cast back — same numerics as
         # allreduce_grad's cast-allreduce-cast path
         wire_dtype = self._wire_dtype()
-        g_bufs, meta = _packing.pack(grads)
-        p_bufs, _ = _packing.pack(params) if params is not None else (
-            [None] * len(g_bufs), None)
-        g_shards, p_shards, strips = [], [], []
-        for g, p in zip(g_bufs, p_bufs):
-            g, strip = _packing.pad_to_multiple(g, size)
-            strips.append(strip)
-            orig_dtype = g.dtype
-            if wire_dtype is not None and g.dtype != wire_dtype:
-                g = g.astype(wire_dtype)
-            # reduce_scatter sums; the reference's allreduce_grad is a mean
-            gs = comm.reduce_scatter(g) / size
-            g_shards.append(gs.astype(orig_dtype))
-            if p is not None:
-                p, _ = _packing.pad_to_multiple(p, size)
-                p_shards.append(
-                    jax.lax.dynamic_index_in_dim(
-                        p.reshape(size, -1), idx, axis=0, keepdims=False))
-        updates_sh, inner = self.actual_optimizer.update(
-            g_shards, state.inner,
-            p_shards if params is not None else None, **kwargs)
+        # both legs of the exchange carry allreduce_grad's scope, so that
+        # "the gradient exchange" reads the same whatever the wrapper
+        with jax.named_scope("chainermn.allreduce_grad"):
+            g_bufs, meta = _packing.pack(grads)
+            p_bufs, _ = _packing.pack(params) if params is not None else (
+                [None] * len(g_bufs), None)
+            g_shards, p_shards, strips = [], [], []
+            for g, p in zip(g_bufs, p_bufs):
+                g, strip = _packing.pad_to_multiple(g, size)
+                strips.append(strip)
+                orig_dtype = g.dtype
+                if wire_dtype is not None and g.dtype != wire_dtype:
+                    g = g.astype(wire_dtype)
+                # reduce_scatter sums; the reference's allreduce_grad means
+                gs = comm.reduce_scatter(g) / size
+                g_shards.append(gs.astype(orig_dtype))
+                if p is not None:
+                    p, _ = _packing.pad_to_multiple(p, size)
+                    p_shards.append(
+                        jax.lax.dynamic_index_in_dim(
+                            p.reshape(size, -1), idx, axis=0,
+                            keepdims=False))
+        with jax.named_scope("chainermn.update"):
+            updates_sh, inner = self.actual_optimizer.update(
+                g_shards, state.inner,
+                p_shards if params is not None else None, **kwargs)
         # Gather-back as a masked psum rather than all_gather: value-
         # identical, but psum output is INVARIANT in JAX's varying-axes
         # type system, so the updated parameters keep their replicated
         # out_spec (same trick as the two_dimensional communicator's
         # gather-back leg; ~2x the bytes of a ring gather on the cheap
         # ICI resource).
-        upd_bufs = []
-        for u, strip in zip(updates_sh, strips):
-            placed = jax.lax.dynamic_update_slice_in_dim(
-                jnp.zeros((u.shape[0] * size,), u.dtype), u,
-                idx * u.shape[0], 0)
-            upd_bufs.append(strip(comm.allreduce(placed, "sum")))
-        return _packing.unpack(upd_bufs, meta), _ZeroState(inner=inner)
+        with jax.named_scope("chainermn.allreduce_grad"):
+            upd_bufs = []
+            for u, strip in zip(updates_sh, strips):
+                placed = jax.lax.dynamic_update_slice_in_dim(
+                    jnp.zeros((u.shape[0] * size,), u.dtype), u,
+                    idx * u.shape[0], 0)
+                upd_bufs.append(strip(comm.allreduce(placed, "sum")))
+            updates = _packing.unpack(upd_bufs, meta)
+        return updates, _ZeroState(inner=inner)
 
     def state_partition_spec(self):
         # the whole inner state lives on per-device shards
@@ -495,15 +506,19 @@ def make_train_step(
                 aux = None
             return loss, aux, model_state, grads
 
-        if accum_steps > 1:
-            from chainermn_tpu.utils.accum import accumulate_microbatches
+        # The step names its parts for the device trace: a scope is HLO
+        # metadata, never a different program (docs/observability.md).
+        with jax.named_scope("chainermn.grad"):
+            if accum_steps > 1:
+                from chainermn_tpu.utils.accum import accumulate_microbatches
 
-            loss, aux, model_state, grads = accumulate_microbatches(
-                compute, model_state, batch, accum_steps, has_aux)
-        else:
-            loss, aux, model_state, grads = compute(model_state, batch)
+                loss, aux, model_state, grads = accumulate_microbatches(
+                    compute, model_state, batch, accum_steps, has_aux)
+            else:
+                loss, aux, model_state, grads = compute(model_state, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("chainermn.update"):
+            params = optax.apply_updates(params, updates)
         if isinstance(opt_state, _DoubleBufferState):
             # Anchor the loss/aux reporting reductions AFTER the parameter
             # update: XLA's all-reduce combiner otherwise merges them with
@@ -528,9 +543,10 @@ def make_train_step(
                 lambda a: a[None], opt_state.comp))
         if with_model_state:
             model_state = jax.tree.map(lambda a: a[None], model_state)
-        loss = comm.allreduce(loss, "mean")
-        if has_aux:
-            aux = comm.allreduce(aux, "mean")
+        with jax.named_scope("chainermn.report"):
+            loss = comm.allreduce(loss, "mean")
+            if has_aux:
+                aux = comm.allreduce(aux, "mean")
         outs = (params, model_state, opt_state, loss, aux)
         keep = (True, with_model_state, True, True, has_aux)
         return tuple(o for o, k in zip(outs, keep) if k)

@@ -38,6 +38,7 @@ the census table is derived, not maintained.
 
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -65,6 +66,17 @@ def _with_wire(buf, wire_dtype: Optional[str], fn):
     if wire == orig:
         return fn(buf)
     return fn(buf.astype(wire)).astype(orig)
+
+
+def _stage_scope(i: int, st: Stage):
+    """The named scope one emitted stage runs under:
+    ``chainermn.plan.<i>.<op>`` (``all-reduce`` reads ``all_reduce``).  It
+    names the stage's instructions in the device trace — what the
+    ``plan_stage`` begin/end callbacks time from the host, read on the
+    device's own clock and at no cost to the program
+    (docs/observability.md)."""
+    return jax.named_scope(
+        f"chainermn.plan.{i}.{re.sub('[^0-9A-Za-z]', '_', st.op)}")
 
 
 class _ShardFrame:
@@ -319,82 +331,85 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
         if not axes:
             continue
         _stage_hook(pobs, plan, topology, i, st, buf, "begin", group=group)
-        quant = _quantizer_for(st)
-        if quant is not None:
-            world = topology.scope_size(st.scope)
-            state = states.get(key)
-            if state is None:
-                # One-shot path (benchmark sweeps, candidate validation):
-                # a cold EF state built inside the trace, discarded by
-                # the caller.  Training seams thread persistent states.
-                state = quant.init_state(int(buf.shape[0]), world, hop=key)
-            buf, new_states[key] = _compressed_psum(
-                st, key, axes, world, buf, state, obs)
-        elif st.op == "all-reduce":
-            if st.compression is not None:
-                # identity compressor: exactly the wire-dtype cast path
-                comp = st.compressor()
-                buf = _with_wire(buf, comp.wire_dtype,
-                                 lambda b: lax.psum(b, _axis_arg(axes)))
-            else:
-                buf = _with_wire(buf, st.wire_dtype,
-                                 lambda b: lax.psum(b, _axis_arg(axes)))
-        elif st.op == "reduce-scatter":
-            if len(axes) != 1:
-                raise PlanError(
-                    f"reduce-scatter scope {st.scope!r} resolves to "
-                    f"{axes} — psum_scatter shards over exactly one axis; "
-                    "declare a topology whose scope is a single axis")
-            size = topology.scope_size(st.scope)
-            buf, strip = _packing.pad_to_multiple(buf, size)
-            frame = _ShardFrame(st.scope, axes[0], size,
-                                int(buf.shape[0]), strip)
-            buf = _with_wire(
-                buf, st.wire_dtype,
-                lambda b: lax.psum_scatter(b, axes[0], tiled=True))
-            shard_stack.append(frame)
-        elif st.op == "all-gather":
-            frame = shard_stack.pop()  # validate() guarantees matching
-            if st.lowering == "native":
+        with _stage_scope(i, st):
+            quant = _quantizer_for(st)
+            if quant is not None:
+                world = topology.scope_size(st.scope)
+                state = states.get(key)
+                if state is None:
+                    # One-shot path (benchmark sweeps, candidate validation):
+                    # a cold EF state built inside the trace, discarded by
+                    # the caller.  Training seams thread persistent states.
+                    state = quant.init_state(
+                        int(buf.shape[0]), world, hop=key)
+                buf, new_states[key] = _compressed_psum(
+                    st, key, axes, world, buf, state, obs)
+            elif st.op == "all-reduce":
+                if st.compression is not None:
+                    # identity compressor: exactly the wire-dtype cast path
+                    comp = st.compressor()
+                    buf = _with_wire(buf, comp.wire_dtype,
+                                     lambda b: lax.psum(b, _axis_arg(axes)))
+                else:
+                    buf = _with_wire(buf, st.wire_dtype,
+                                     lambda b: lax.psum(b, _axis_arg(axes)))
+            elif st.op == "reduce-scatter":
+                if len(axes) != 1:
+                    raise PlanError(
+                        f"reduce-scatter scope {st.scope!r} resolves to "
+                        f"{axes} — psum_scatter shards over exactly one "
+                        "axis; "
+                        "declare a topology whose scope is a single axis")
+                size = topology.scope_size(st.scope)
+                buf, strip = _packing.pad_to_multiple(buf, size)
+                frame = _ShardFrame(st.scope, axes[0], size,
+                                    int(buf.shape[0]), strip)
                 buf = _with_wire(
                     buf, st.wire_dtype,
-                    lambda b: lax.all_gather(b, frame.axis, tiled=True))
-            else:
-                me = lax.axis_index(frame.axis)
-                shard_len = frame.padded_len // frame.size
+                    lambda b: lax.psum_scatter(b, axes[0], tiled=True))
+                shard_stack.append(frame)
+            elif st.op == "all-gather":
+                frame = shard_stack.pop()  # validate() guarantees matching
+                if st.lowering == "native":
+                    buf = _with_wire(
+                        buf, st.wire_dtype,
+                        lambda b: lax.all_gather(b, frame.axis, tiled=True))
+                else:
+                    me = lax.axis_index(frame.axis)
+                    shard_len = frame.padded_len // frame.size
 
-                def gather(b):
-                    placed = lax.dynamic_update_slice_in_dim(
-                        jnp.zeros((frame.padded_len,), b.dtype), b,
-                        me * shard_len, 0)
-                    return lax.psum(placed, frame.axis)
+                    def gather(b):
+                        placed = lax.dynamic_update_slice_in_dim(
+                            jnp.zeros((frame.padded_len,), b.dtype), b,
+                            me * shard_len, 0)
+                        return lax.psum(placed, frame.axis)
 
-                buf = _with_wire(buf, st.wire_dtype, gather)
-            buf = frame.strip(buf)
-        elif st.op == "multicast":
-            idx = lax.axis_index(_axis_arg(axes))
+                    buf = _with_wire(buf, st.wire_dtype, gather)
+                buf = frame.strip(buf)
+            elif st.op == "multicast":
+                idx = lax.axis_index(_axis_arg(axes))
 
-            def bcast(b):
-                masked = jnp.where(idx == st.root, b, jnp.zeros_like(b))
-                return lax.psum(masked, _axis_arg(axes))
+                def bcast(b):
+                    masked = jnp.where(idx == st.root, b, jnp.zeros_like(b))
+                    return lax.psum(masked, _axis_arg(axes))
 
-            buf = _with_wire(buf, st.wire_dtype, bcast)
-        elif st.op == "p2p":
-            if len(axes) != 1:
+                buf = _with_wire(buf, st.wire_dtype, bcast)
+            elif st.op == "p2p":
+                if len(axes) != 1:
+                    raise PlanError(
+                        f"p2p scope {st.scope!r} resolves to {axes} — "
+                        "ppermute rings run over exactly one axis")
+                n = topology.scope_size(st.scope)
+                perm = [(i, (i + 1) % n) for i in range(n)]
+                buf = _with_wire(buf, st.wire_dtype,
+                                 lambda b: lax.ppermute(b, axes[0], perm))
+            elif st.op == "all-to-all":
                 raise PlanError(
-                    f"p2p scope {st.scope!r} resolves to {axes} — "
-                    "ppermute rings run over exactly one axis")
-            n = topology.scope_size(st.scope)
-            perm = [(i, (i + 1) % n) for i in range(n)]
-            buf = _with_wire(buf, st.wire_dtype,
-                             lambda b: lax.ppermute(b, axes[0], perm))
-        elif st.op == "all-to-all":
-            raise PlanError(
-                f"plan {plan.name!r}: all-to-all stages lower through "
-                "execute_alltoall (a block exchange over [P, ...] "
-                "buffers), not the gradient-mean executor")
-        else:  # pragma: no cover — ir validation rejects unknown ops
-            raise PlanError(f"unknown stage op {st.op!r}")
+                    f"plan {plan.name!r}: all-to-all stages lower through "
+                    "execute_alltoall (a block exchange over [P, ...] "
+                    "buffers), not the gradient-mean executor")
+            else:  # pragma: no cover — ir validation rejects unknown ops
+                raise PlanError(f"unknown stage op {st.op!r}")
         _stage_hook(pobs, plan, topology, i, st, buf, "end", group=group)
     return buf, new_states
 
@@ -427,8 +442,9 @@ def _leaf_stage_op(plan: Plan, topology: PlanTopology, st: Stage, leaf):
 
 def _run_stages_leaf(plan: Plan, topology: PlanTopology, leaf):
     """Leaf-mode chain: all-reduce/multicast/p2p only (ir.validate)."""
-    for st in plan.stages:
-        leaf = _leaf_stage_op(plan, topology, st, leaf)
+    for i, st in enumerate(plan.stages):
+        with _stage_scope(i, st):
+            leaf = _leaf_stage_op(plan, topology, st, leaf)
     return leaf
 
 
@@ -455,7 +471,8 @@ def _run_stages_leaf_traced(plan: Plan, topology: PlanTopology, grads,
         dep = max(sized, key=lambda l: l.size)
         _stage_hook(pobs, plan, topology, i, st, dep, "begin",
                     wire_bytes=wire_bytes)
-        leaves = [_leaf_stage_op(plan, topology, st, l) for l in leaves]
+        with _stage_scope(i, st):
+            leaves = [_leaf_stage_op(plan, topology, st, l) for l in leaves]
         sized = [l for l in leaves if getattr(l, "size", 0)]
         dep = max(sized, key=lambda l: l.size)
         _stage_hook(pobs, plan, topology, i, st, dep, "end",
@@ -607,9 +624,11 @@ def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
         axes = topology.scope_axes(st.scope)
         _exchange_hook(pobs, plan, topology, i, st, buf, "begin",
                        group=group)
-        buf = _with_wire(
-            buf, st.wire_dtype,
-            lambda b: lax.all_to_all(b, _axis_arg(axes), 0, 0, tiled=True))
+        with _stage_scope(i, st):
+            buf = _with_wire(
+                buf, st.wire_dtype,
+                lambda b: lax.all_to_all(b, _axis_arg(axes), 0, 0,
+                                         tiled=True))
         _exchange_hook(pobs, plan, topology, i, st, buf, "end",
                        group=group)
         return buf
@@ -629,9 +648,10 @@ def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
     x = jnp.moveaxis(x, 1, 0).reshape((jsz * isz,) + rest)
     _exchange_hook(pobs, plan, topology, ii, intra_st, x, "begin",
                    group=group)
-    x = _with_wire(
-        x, intra_st.wire_dtype,
-        lambda b: lax.all_to_all(b, intra_axis, 0, 0, tiled=True))
+    with _stage_scope(ii, intra_st):
+        x = _with_wire(
+            x, intra_st.wire_dtype,
+            lambda b: lax.all_to_all(b, intra_axis, 0, 0, tiled=True))
     _exchange_hook(pobs, plan, topology, ii, intra_st, x, "end",
                    group=group)
     # x[b'*I + i] = block from intra peer b' destined (i, self_j);
@@ -640,10 +660,11 @@ def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
     x = jnp.moveaxis(x, 1, 0).reshape((isz * jsz,) + rest)
     _exchange_hook(pobs, plan, topology, ji, inter_st, x, "begin",
                    group=group)
-    x = _with_wire(
-        x, inter_st.wire_dtype,
-        lambda b: lax.all_to_all(b, _axis_arg(inter_axes), 0, 0,
-                                 tiled=True))
+    with _stage_scope(ji, inter_st):
+        x = _with_wire(
+            x, inter_st.wire_dtype,
+            lambda b: lax.all_to_all(b, _axis_arg(inter_axes), 0, 0,
+                                     tiled=True))
     _exchange_hook(pobs, plan, topology, ji, inter_st, x, "end",
                    group=group)
     # x[a'*J + b'] = block from source (a', b') — source global-rank

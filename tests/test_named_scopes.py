@@ -1,0 +1,117 @@
+"""The train step names its parts from inside: every scope of the naming
+rule (docs/observability.md) is in the compiled program's ``op_name``
+metadata, whatever the optimizer wrapper, and flax's module scopes are there
+beside them (this pins ``flax_profile``).  There is no switch to test: a
+scope is metadata of the one program."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+import chainermn_tpu
+from chainermn_tpu.models import TransformerLM
+from chainermn_tpu.optimizers import init_opt_state, make_train_step
+from chainermn_tpu.training.trainer import put_global_batch
+
+OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# optimizer wrapper -> (communicator arguments, create_multi_node_optimizer
+# arguments, the scope the gradient's collective must sit under)
+WRAPPERS = {
+    "plain": ({}, {}, r"chainermn\.plan\.0\.all_reduce"),
+    "double_buffered": ({}, {"double_buffering": True},
+                        r"chainermn\.plan\.0\.all_reduce"),
+    "bf16_wire": ({"allreduce_grad_dtype": "bfloat16"}, {},
+                  r"chainermn\.plan\.0\.all_reduce"),
+    # ZeRO-1 runs no plan: reduce-scatter and gather-back are its exchange
+    "zero1": ({}, {"zero": True}, None),
+}
+
+
+def _compiled_text(comm_args, optimizer_args):
+    comm = chainermn_tpu.create_communicator("xla", **comm_args)
+    model = TransformerLM(vocab=64, d_model=32, n_layers=2, n_heads=2,
+                          max_len=16, attention_impl="xla")
+    tokens = jnp.zeros((comm.size, 16), jnp.int32)
+    params = comm.bcast_data(model.init(jax.random.key(0), tokens[:1]))
+    optimizer = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(0.1, momentum=0.9), comm, **optimizer_args)
+    state = init_opt_state(comm, optimizer, params)
+
+    def loss_fn(p, batch):
+        (t,) = batch
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(p, t)[:, :-1], t[:, 1:]).mean()
+
+    step = make_train_step(comm, loss_fn, optimizer, donate=False)
+    batch = put_global_batch(comm, (tokens,))
+    return step.lower(params, state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("wrapper", sorted(WRAPPERS))
+def test_every_scope_is_in_the_compiled_step(wrapper):
+    comm_args, optimizer_args, stage = WRAPPERS[wrapper]
+    text = _compiled_text(comm_args, optimizer_args)
+    names = set(OP_NAME.findall(text))
+
+    def some(pattern):
+        return any(re.search(pattern, name) for name in names)
+
+    # forward and backward under chainermn.grad, told apart by transpose(
+    assert some(r"/chainermn\.grad/jvp\(")
+    assert some(r"/chainermn\.grad/transpose\(jvp\(")
+    # flax's module scopes, inside the program's
+    assert some(r"/chainermn\.grad/.*\bblock_1/qkv/dot_general")
+    assert some(r"/chainermn\.grad/.*\bhead/")
+    assert some(r"/chainermn\.allreduce_grad/chainermn\.pack/")
+    assert some(r"/chainermn\.allreduce_grad/chainermn\.unpack/")
+    assert some(r"/chainermn\.update/")
+    assert some(r"/chainermn\.report/")
+    # a scope never opens inside another top-level one
+    assert not some(r"chainermn\.(grad|update|report)/.*chainermn\.")
+    # the gradient's collective is named by the exchange it belongs to
+    collectives = [line for line in text.splitlines() if re.search(
+        r"= .*\b(all-reduce|reduce-scatter)(-start)?\(", line)]
+    under = [OP_NAME.search(line).group(1) for line in collectives
+             if OP_NAME.search(line)]
+    assert under, collectives
+    if stage is None:
+        assert some(r"/chainermn\.allreduce_grad/(?!chainermn\.plan)")
+        assert any("/chainermn.allreduce_grad/" in name for name in under)
+    else:
+        assert any(re.search(
+            r"/chainermn\.allreduce_grad/" + stage + "/", name)
+            for name in under), under
+
+
+def test_a_leaf_packed_plan_names_its_stages_too():
+    """The naive flavor reduces leaf by leaf: each leaf's psum still sits
+    under the stage's scope."""
+    comm = chainermn_tpu.create_communicator("naive")
+    grads = {"w": jnp.ones((comm.size, 4)), "b": jnp.ones((comm.size, 2))}
+    text = comm.compiled_hlo(comm.allreduce_grad, grads)
+    assert re.search(
+        r'op_name="[^"]*/chainermn\.allreduce_grad/'
+        r'chainermn\.plan\.0\.all_reduce/', text)
+
+
+def test_no_argument_or_variable_turns_the_scopes_off():
+    """The naming rule has no switch: every ``named_scope`` of the package
+    is opened unconditionally, by a plain ``with`` (or the one helper that
+    names a plan stage)."""
+    import os
+
+    root = os.path.dirname(chainermn_tpu.__file__)
+    opened = []
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name)) as handle:
+                    opened += [line.strip() for line in handle
+                               if "jax.named_scope(" in line]
+    assert len(opened) >= 12
+    assert all(re.match(r"(with|return) jax\.named_scope\($|"
+                        r'with jax\.named_scope\("chainermn\.\w+"\):$', line)
+               for line in opened), opened

@@ -328,6 +328,8 @@ class TestInstrumentedCommunicator:
         # per-rank payload under trace: one (8,) float32 row
         assert reg.get("comm_collective_bytes").value(
             dtype="float32", **labels) == 8 * 4
+        # tracing is not communication: no latency under a collective's name
+        assert reg.get("comm_collective_seconds").count(**labels) == 0
 
     def test_object_plane_and_barrier(self, comm):
         reg = MetricsRegistry()
