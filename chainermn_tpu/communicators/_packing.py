@@ -13,10 +13,19 @@ buffer per dtype) unless a communication dtype is forced, in which case a
 single buffer is used and the cast in/out is fused by XLA (or by the Pallas
 cast+scale kernel, see ``chainermn_tpu/ops/cast_scale.py``).
 
+Who still packs: what shards, stripes or quantizes a flat index space —
+plans with a reduce-scatter/all-gather, striped plans, quantizing plans
+(``planner.compiler.plan_needs_buffer``), ZeRO-1, FSDP and the compressed
+allreduce.  A gradient mean whose every stage is an all-reduce does NOT: the
+compiler reduces its leaves where they lie, because on the chip gathering
+them into one buffer and slicing it apart again cost more than the
+collective between (PERF.md, PR 25).
+
 What ``pack`` and ``unpack`` put into a traced program carries the named
 scopes ``chainermn.pack`` / ``chainermn.unpack`` (wire cast and 1/size scale
 included), so a device trace can tell the copies around a collective from
-the collective (docs/observability.md).
+the collective (docs/observability.md).  The leaf-wise lowering opens the
+same two scopes around its wire cast and its cast-back + scale.
 """
 
 from __future__ import annotations

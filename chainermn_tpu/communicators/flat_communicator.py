@@ -4,9 +4,13 @@ Reference (path unverified, SURVEY.md provenance): ``FlatCommunicator`` in
 〔chainermn/communicators/flat_communicator.py〕 — pack all grads into one
 contiguous GPU buffer, one CUDA-aware ``MPI.Allreduce`` over it, unpack.
 
-Here: concatenate all leaves into flat per-dtype buffers, one ``lax.psum``
-per buffer, split back.  The pack/unpack is traced; XLA owns the memory
-(reference's ``DeviceMemory`` staging disappears by design, SURVEY.md §2.3).
+Here the flavor is the plan "one all-reduce over every data axis, flat
+packing", and the plan compiler lowers such a plan over the LEAVES (one
+``lax.psum`` a leaf, which XLA's combiner merges): a buffer serves nothing
+that only all-reduces (``planner.compiler.plan_needs_buffer``).  The
+reference's literal form — concatenate into flat per-dtype buffers, one
+``lax.psum`` per buffer, split back — is kept below as the parity reference
+the leaf-wise lowering is tested against, equal to the bit.
 """
 
 from jax import lax

@@ -442,8 +442,9 @@ class MeshCommunicator(CommunicatorBase):
         communicator's ``compression=`` / ``allreduce_grad_dtype`` config):
 
         * ``None`` / ``NoCompression()`` — the paths above, unchanged;
-        * ``NoCompression(wire_dtype=...)`` — pack-cast-psum-unpack,
-          bit-for-bit the ``allreduce_grad_dtype`` program;
+        * ``NoCompression(wire_dtype=...)`` — cast, all-reduce, cast back
+          and scale: bit-for-bit the ``allreduce_grad_dtype`` program (it
+          executes the same plan);
         * a quantizer (``"int8"`` / ``"fp8"``) — stateful EF compression:
           pass ``state`` (a :class:`~chainermn_tpu.compression.\
 CompressionState` from :meth:`init_compression_state`) and the call
@@ -551,15 +552,14 @@ CompressionState` from :meth:`init_compression_state`) and the call
         return execute_plan(plan, self, grads, states=states)
 
     def _allreduce_grad_wire(self, grads, wire):
-        """NoCompression(wire_dtype): the exact cast-allreduce-cast
-        program of the ``allreduce_grad_dtype`` knob (xla communicator's
-        non-pallas lowering) — one packed buffer in the wire dtype, one
-        psum, unpack with the 1/size mean folded in."""
-        from chainermn_tpu.communicators import _packing
-        buffers, meta = _packing.pack(grads, comm_dtype=wire)
-        ax = self._axis_arg()
-        buffers = [lax.psum(b, ax) for b in buffers]
-        return _packing.unpack(buffers, meta, scale=1.0 / self.size)
+        """NoCompression(wire_dtype): the cast-allreduce-cast program of
+        the ``allreduce_grad_dtype`` knob — by construction, because it
+        IS the xla flavor's plan at that wire dtype, through the one
+        compiler."""
+        from chainermn_tpu.planner.compiler import execute_plan
+        from chainermn_tpu.planner.plans import flavor_plan
+        return execute_plan(
+            flavor_plan("xla", wire_dtype=np.dtype(wire).name), self, grads)
 
     def _allreduce_grad_compressed(self, grads, comp, state):
         """Quantized exchange: pack to one f32 buffer, EF-encode to wire

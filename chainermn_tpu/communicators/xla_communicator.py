@@ -8,14 +8,20 @@ streams; ``allreduce_grad_dtype='float16'`` casts fp32 grads to an fp16
 buffer (runtime-compiled CUDA cast kernel), allreduces in fp16, casts back —
 the mixed-precision contribution behind the 15-minute ImageNet result.
 
-TPU-native version: one packed flat buffer in the communication dtype
+TPU-native version: each gradient leaf is cast to the communication dtype
 (``allreduce_grad_dtype``; pass ``bfloat16`` for the TPU-natural half type,
-``None`` keeps each leaf's own dtype), a single ``lax.psum`` over
-*all* data axes at once (XLA emits the fused ICI/DCN collective), and a
-cast+scale fused into unpack.  The cast-in / scale+cast-out can optionally
-run through the Pallas kernel in ``chainermn_tpu/ops/cast_scale.py`` (the
-native-kernel parity item, SURVEY.md §2.3) — by default XLA's own fusion is
-used, which profiling shows is already a single fused op.
+``None`` keeps each leaf's own dtype and casts nothing), all-reduced WHERE IT
+LIES with ``lax.psum`` over *all* data axes at once, cast back and then
+scaled by 1/size.  There is no packed buffer: on NCCL one launch over one
+buffer beat a launch a parameter, but XLA's combiner already merges the
+leaves' all-reduces into a few variadic ones (every small vector into one),
+and on the chip the buffer cost two extra passes over the gradients — more
+than the all-reduce it served (PERF.md, PR 25).  The plan compiler decides
+this from the plan's stages (``planner.compiler.plan_needs_buffer``).  The
+cast-in / scale+cast-out can optionally run through the Pallas kernel in
+``chainermn_tpu/ops/cast_scale.py`` over packed buffers (the native-kernel
+parity item, SURVEY.md §2.3; ``use_pallas_cast``) — by default XLA's own
+fusion is used.
 """
 
 from typing import Optional
@@ -43,8 +49,8 @@ class XlaCommunicator(MeshCommunicator):
             # is a kernel-selection knob, not a decomposition (the stage
             # sequence is identical to the plan's single all-reduce).
             return self._pallas_allreduce_grad_traced(grads)
-        # Plan path: flat pack in the wire dtype, one all-reduce, fused
-        # cast-back+scale — the base delegates to the plan compiler.
+        # Plan path: wire cast, an all-reduce a leaf, cast back and scale
+        # — the base delegates to the plan compiler.
         return super()._allreduce_grad_traced(grads)
 
     def _pallas_allreduce_grad_traced(self, grads):

@@ -39,6 +39,7 @@ from chainermn_tpu.planner.compiler import (
     plan_group_lengths,
     plan_link_bytes,
     plan_modeled_time_s,
+    plan_needs_buffer,
     plan_stage_lengths,
     plan_wire_bytes,
     plan_wire_dtypes,
@@ -153,6 +154,7 @@ __all__ = [
     "plan_group_lengths",
     "plan_link_bytes",
     "plan_modeled_time_s",
+    "plan_needs_buffer",
     "plan_stage_lengths",
     "plan_wire_bytes",
     "plan_table_hash",

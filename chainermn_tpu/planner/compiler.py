@@ -7,9 +7,19 @@ against the legacy per-class decompositions via the shared
 ``analysis/hlo.py`` parser).  The conventions the compiler must respect,
 inherited from the code it replaces:
 
-* **packing** — flat plans run over ``_packing.pack`` buffers with the
-  1/size mean fused into ``unpack`` (scale applied AFTER the cast back,
-  see ``_packing.unpack``); leaf plans apply the mean per leaf after the
+* **packing** — a flat plan that NEEDS a buffer (:func:`plan_needs_buffer`:
+  a reduce-scatter/all-gather shards a flat index space, stripe ratios
+  are offsets into it, a quantizer's error-feedback state maps
+  one-to-one onto it) runs over ``_packing.pack`` buffers with the
+  1/size mean fused into ``unpack``.  A flat plan whose every stage is
+  an all-reduce is elementwise in every leaf, so there is nothing for a
+  buffer to do: it is lowered over the LEAVES — each cast to the wire
+  dtype (``chainermn.pack``), all-reduced where it lies, cast back and
+  then scaled (``chainermn.unpack``) — with no reshape, concatenate or
+  split.  XLA's combiner merges the small all-reduces; on a TPU the one
+  big buffer only bought two extra passes over memory (PERF.md, PR 25).
+  Either way the scale is applied AFTER the cast back (see
+  ``_packing.unpack``).  Leaf plans apply the mean per leaf after the
   stage chain, exactly like the naive/hierarchical bodies did.
 * **padding** — a reduce-scatter pads its buffer to a multiple of the
   scope size with ``_packing.pad_to_multiple`` and the matching
@@ -66,6 +76,15 @@ def _with_wire(buf, wire_dtype: Optional[str], fn):
     if wire == orig:
         return fn(buf)
     return fn(buf.astype(wire)).astype(orig)
+
+
+def _all_reduce_wire(st: Stage) -> Optional[str]:
+    """Wire dtype of a non-quantizing all-reduce stage: an identity
+    compressor IS the wire-dtype cast path, so its ``wire_dtype`` reads
+    like the stage's own."""
+    if st.compression is not None:
+        return st.compressor().wire_dtype
+    return st.wire_dtype
 
 
 def _stage_scope(i: int, st: Stage):
@@ -345,14 +364,8 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
                 buf, new_states[key] = _compressed_psum(
                     st, key, axes, world, buf, state, obs)
             elif st.op == "all-reduce":
-                if st.compression is not None:
-                    # identity compressor: exactly the wire-dtype cast path
-                    comp = st.compressor()
-                    buf = _with_wire(buf, comp.wire_dtype,
-                                     lambda b: lax.psum(b, _axis_arg(axes)))
-                else:
-                    buf = _with_wire(buf, st.wire_dtype,
-                                     lambda b: lax.psum(b, _axis_arg(axes)))
+                buf = _with_wire(buf, _all_reduce_wire(st),
+                                 lambda b: lax.psum(b, _axis_arg(axes)))
             elif st.op == "reduce-scatter":
                 if len(axes) != 1:
                     raise PlanError(
@@ -421,7 +434,7 @@ def _leaf_stage_op(plan: Plan, topology: PlanTopology, st: Stage, leaf):
     if not axes:
         return leaf
     if st.op == "all-reduce":
-        return _with_wire(leaf, st.wire_dtype,
+        return _with_wire(leaf, _all_reduce_wire(st),
                           lambda v: lax.psum(v, _axis_arg(axes)))
     if st.op == "multicast":
         idx = lax.axis_index(_axis_arg(axes))
@@ -448,36 +461,86 @@ def _run_stages_leaf(plan: Plan, topology: PlanTopology, leaf):
     return leaf
 
 
-def _run_stages_leaf_traced(plan: Plan, topology: PlanTopology, grads,
-                            n: int, pobs):
-    """Leaf packing with per-stage span hooks.  Runs stage-outer /
-    leaf-inner — per leaf the stage chain is identical to
-    :func:`_run_stages_leaf` (leaves are independent), but the loop
-    order lets one begin/end pair bracket each stage for the WHOLE tree.
-    The callback rides the largest leaf (the stage's dominant cost);
-    ``wire_bytes`` prices every leaf on that stage's wire."""
-    leaves, treedef = jax.tree_util.tree_flatten(grads)
-    sized = [l for l in leaves if getattr(l, "size", 0)]
-    if not sized:
-        return jax.tree.map(
-            lambda g: _run_stages_leaf(plan, topology, g) / n, grads)
-    for i, st in enumerate(plan.stages):
-        if not topology.scope_axes(st.scope):
-            continue
+def _run_stages_leaves(plan: Plan, topology: PlanTopology, leaves: List,
+                       pobs, group: Optional[int] = None) -> List:
+    """Apply one chain of leaf-mode stages (all-reduce/multicast/p2p) to
+    a LIST of leaves, each where it lies.  Runs stage-outer / leaf-inner
+    — per leaf the chain is identical to :func:`_run_stages_leaf` (leaves
+    are independent), but the loop order lets one ``plan_stage``
+    begin/end pair (``pobs``; ``None`` when observability is off)
+    bracket each stage for the WHOLE tree.  The callback rides the
+    largest leaf (the stage's dominant cost); ``wire_bytes`` prices
+    every leaf on that stage's wire.  ``group`` as in
+    :func:`_run_stages_flat`."""
+    stages = plan.stages if group is None else plan.groups[group].stages
+
+    def hook(i, st, edge):
+        sized = [l for l in leaves if getattr(l, "size", 0)]
+        if pobs is None or not sized:
+            return
         wire_bytes = sum(
             _stage_wire_elem_bytes(plan, st, float(l.size),
                                    jnp.dtype(l.dtype).itemsize)
             for l in sized)
-        dep = max(sized, key=lambda l: l.size)
-        _stage_hook(pobs, plan, topology, i, st, dep, "begin",
-                    wire_bytes=wire_bytes)
+        _stage_hook(pobs, plan, topology, i, st,
+                    max(sized, key=lambda l: l.size), edge,
+                    wire_bytes=wire_bytes, group=group)
+
+    for i, st in enumerate(stages):
+        if not topology.scope_axes(st.scope):
+            continue
+        hook(i, st, "begin")
         with _stage_scope(i, st):
             leaves = [_leaf_stage_op(plan, topology, st, l) for l in leaves]
-        sized = [l for l in leaves if getattr(l, "size", 0)]
-        dep = max(sized, key=lambda l: l.size)
-        _stage_hook(pobs, plan, topology, i, st, dep, "end",
-                    wire_bytes=wire_bytes)
-    return jax.tree_util.tree_unflatten(treedef, [l / n for l in leaves])
+        hook(i, st, "end")
+    return leaves
+
+
+def plan_needs_buffer(plan: Plan, topology: PlanTopology) -> bool:
+    """Whether ``plan``'s lowering against ``topology`` builds the packed
+    flat buffer — a pure function of the two, read by
+    :func:`execute_plan` itself (one value a compiled step).
+
+    A buffer is an index space.  Three things use one: a
+    reduce-scatter/all-gather (it shards the space), stripes (ratio
+    boundaries are offsets into it) and a quantizing stage (its
+    error-feedback state maps one-to-one onto it).  A flat plan with
+    none of them — every emitted stage an all-reduce, wire casts and
+    identity codecs included — is elementwise in every leaf and is
+    lowered over the leaves.  Leaf-packed plans never had a buffer."""
+    if plan.packing != "flat":
+        return False
+    groups = plan.stage_groups()
+    if len(groups) > 1 or plan_compressed_hops(plan, topology):
+        return True
+    return any(st.op != "all-reduce" for st in groups[0].stages
+               if topology.scope_axes(st.scope))
+
+
+def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs):
+    """The lowering of a flat plan that needs no buffer: the same cast,
+    reduce, cast back, scale as pack -> stages -> unpack, leaf by leaf.
+    The scopes stay where ``_packing`` has them, so ``chainermn.pack``
+    goes on naming what the exchange costs before the collective (here
+    the wire cast alone) and ``chainermn.unpack`` what it costs after."""
+    leaves, treedef = jax.tree_util.tree_flatten(grads)
+    dtypes = [l.dtype for l in leaves]
+    if plan.wire_dtype is not None:
+        wire = jnp.dtype(plan.wire_dtype)
+        with jax.named_scope("chainermn.pack"):
+            leaves = [l if l.dtype == wire else l.astype(wire)
+                      for l in leaves]
+    leaves = _run_stages_leaves(
+        plan, topology, leaves, pobs,
+        group=None if plan.groups is None else 0)
+    scale = 1.0 / topology.size
+    with jax.named_scope("chainermn.unpack"):
+        # cast back FIRST: the scale multiplies in leaf precision
+        # (``_packing.unpack``'s order)
+        leaves = [(l if l.dtype == dt else l.astype(dt))
+                  * jnp.asarray(scale, dt)
+                  for l, dt in zip(leaves, dtypes)]
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
@@ -488,6 +551,11 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
     ``comm.plan_topology()`` (the shared Topology-derived descriptor —
     one source of truth for group sizes).  Must be called inside an SPMD
     region, like the methods it replaces.
+
+    Three lowerings, chosen by what the plan's own stages say: leaf
+    packing runs the chain per leaf; flat packing builds the packed
+    buffer when :func:`plan_needs_buffer` says something shards, stripes
+    or quantizes it, and otherwise reduces the leaves where they lie.
 
     ``states`` threads per-hop error-feedback state through quantizing
     stages: a ``{stage_index: CompressionState}`` dict from
@@ -501,7 +569,6 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
 
     topology = comm.plan_topology()
     n = topology.size
-    has_quant = bool(plan_compressed_hops(plan, topology))
     from chainermn_tpu.observability import spans as _spans
     pobs = _spans.get_plan_obs(comm)
     if plan.packing == "leaf":
@@ -509,13 +576,17 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
             raise PlanError(
                 f"plan {plan.name!r}: leaf packing carries no per-hop "
                 "compression state")
-        if pobs is not None:
-            return _run_stages_leaf_traced(plan, topology, grads, n, pobs)
-        return jax.tree.map(
-            lambda g: _run_stages_leaf(plan, topology, g) / n, grads)
+        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        leaves = _run_stages_leaves(plan, topology, leaves, pobs)
+        return jax.tree_util.tree_unflatten(
+            treedef, [l / n for l in leaves])
+    if not plan_needs_buffer(plan, topology):
+        result = _mean_over_leaves(plan, topology, grads, pobs)
+        return (result, {}) if states is not None else result
     # Quantizing plans exchange ONE float32 buffer (the quantizer's
     # native dtype; per-stage wires still cast per hop) so EF state maps
     # one-to-one onto the packed buffer.
+    has_quant = bool(plan_compressed_hops(plan, topology))
     comm_dtype = (jnp.dtype(plan.wire_dtype)
                   if plan.wire_dtype is not None else None)
     if has_quant and comm_dtype is None:
@@ -1009,5 +1080,5 @@ __all__ = ["LINK_CLASS", "execute_alltoall", "execute_plan",
            "init_plan_compression_states",
            "plan_census_kinds", "plan_compressed_hops", "plan_dcn_bytes",
            "plan_group_lengths", "plan_link_bytes", "plan_modeled_time_s",
-           "plan_stage_lengths", "plan_wire_bytes", "plan_wire_dtypes",
-           "validate_link_gbps"]
+           "plan_needs_buffer", "plan_stage_lengths", "plan_wire_bytes",
+           "plan_wire_dtypes", "validate_link_gbps"]

@@ -349,15 +349,19 @@ class Plan:
 
     ``packing`` selects the buffer convention the stages run over:
 
-    * ``"flat"`` — gradients pack into flat per-dtype buffers
-      (``_packing.pack``), stages run per buffer, the 1/size mean fuses
-      into unpack.  The flat/xla/two_dimensional convention.
+    * ``"flat"`` — the gradients are ONE index space: where a stage
+      shards it, stripes split it or a quantizer keeps state over it
+      (``compiler.plan_needs_buffer``) they pack into flat per-dtype
+      buffers (``_packing.pack``), stages run per buffer and the 1/size
+      mean fuses into unpack; an all-reduce-only chain reads no index
+      and runs over the leaves where they lie, same values.  The
+      flat/xla/two_dimensional convention.
     * ``"leaf"`` — stages run per gradient leaf (no packing), mean
       applied per leaf.  The naive/hierarchical/single_node convention.
       Only all-reduce/multicast/p2p stages are legal (a reduce-scatter
       shard of an arbitrary-shaped leaf has no defined layout).
 
-    ``wire_dtype`` is the packed-buffer communication dtype (the legacy
+    ``wire_dtype`` is the plan-wide communication dtype (the legacy
     ``allreduce_grad_dtype`` knob as plan data; flat packing only).
 
     ``groups`` makes the plan *striped*: instead of one ``stages``

@@ -12,6 +12,7 @@ does, on the chip).  Whole step programs: ``tools/compile_for_chip.py``.
 """
 
 import os
+import re
 import unittest.mock
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -119,3 +120,58 @@ def test_cast_scale_compiles_inside_four_chip_shard_map(topo):
             in_specs=P("d"), out_specs=P("d"))(g)
 
     assert "tpu_custom_call" in _compile(wire, [grads])
+
+
+def _exchange_program(topo, body_name):
+    """A double-buffered step's exchange, stood in for at small widths: the
+    f32 ``pending`` gradients of four chips through the bf16-wire
+    ``allreduce_grad`` into an SGD-momentum update."""
+    import chainermn_tpu
+    from chainermn_tpu.parallel.topology import init_topology
+
+    comm = chainermn_tpu.create_communicator(
+        "xla", topology=init_topology(devices=list(topo.devices)),
+        allreduce_grad_dtype="bfloat16")
+    assert comm.size == 4
+    stacked = NamedSharding(comm.mesh, P(comm.data_axes))
+
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct((comm.size,) + shape, jnp.float32,
+                                    sharding=stacked)
+
+    tree = {"emb": leaf(4096, 512), "up": leaf(512, 2048),
+            "down": leaf(2048, 512), "qkv": leaf(512, 640),
+            "ln": [leaf(512) for _ in range(6)], "bias": leaf(2048)}
+
+    def step(pending, momentum):
+        mean = getattr(comm, body_name)(pending)
+        return jax.tree.map(lambda m, g: 0.9 * m + g, momentum, mean)
+
+    return comm._spmd_program(step).lower((tree, tree)).compile().as_text()
+
+
+@pytest.mark.parametrize("body,packs", [
+    ("allreduce_grad", False),
+    # the flat reference that stays: shows that the test sees a buffer
+    ("_legacy_allreduce_grad_traced", True)])
+def test_four_chip_exchange_builds_no_buffer(topo, body, packs):
+    """Compiled for four described chips, the gradient mean of an
+    all-reduce-only plan holds no ``dynamic-update-slice`` (what gathering
+    the leaves into one buffer becomes) and no relayout ``copy`` named
+    under ``chainermn.pack``: on the chip those cost more than the
+    all-reduce they served (PERF.md, PR 25)."""
+    text = _exchange_program(topo, body)
+    lines = text.splitlines()
+    gathers = [line for line in lines
+               if re.search(r"= .*\bdynamic-update-slice\(", line)]
+    pack_copies = [line for line in lines
+                   if re.search(r"= .*\bcopy\(", line)
+                   and "chainermn.pack" in line]
+    assert bool(gathers or pack_copies) == packs, (gathers, pack_copies)
+    reduced = [line for line in lines
+               if re.search(r"= .*\ball-reduce(-start)?\(", line)]
+    assert reduced and all("bf16[" in line.split(" all-reduce")[0]
+                           for line in reduced), reduced
+    # the combiner, not a buffer, merges the leaves: fewer operations than
+    # leaves (every small vector rides with a matrix)
+    assert len(reduced) < 11

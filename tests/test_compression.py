@@ -112,7 +112,8 @@ class TestNoCompressionBitExact:
     def test_allreduce_matches_dtype_knob(self):
         """allreduce_grad(compressor=NoCompression(bf16)) on a plain
         communicator is bit-for-bit the allreduce_grad_dtype='bfloat16'
-        program (same pack -> cast -> psum -> unpack lowering)."""
+        program (the same plan through the one compiler: cast, psum a
+        leaf, cast back, scale)."""
         c_knob = chainermn_tpu.create_communicator(
             "xla", intra_size=4, allreduce_grad_dtype="bfloat16")
         c_plain = chainermn_tpu.create_communicator("xla", intra_size=4)

@@ -18,15 +18,20 @@ from chainermn_tpu.training.trainer import put_global_batch
 
 OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 # optimizer wrapper -> (communicator arguments, create_multi_node_optimizer
-# arguments, the scope the gradient's collective must sit under)
+# arguments, the scope the gradient's collective must sit under, whether
+# anything runs under chainermn.pack)
 WRAPPERS = {
-    "plain": ({}, {}, r"chainermn\.plan\.0\.all_reduce"),
+    # an all-reduce-only plan reduces the leaves where they lie: with no
+    # wire dtype there is no cast, so nothing is left of "pack" (PR 25)
+    "plain": ({}, {}, r"chainermn\.plan\.0\.all_reduce", False),
     "double_buffered": ({}, {"double_buffering": True},
-                        r"chainermn\.plan\.0\.all_reduce"),
+                        r"chainermn\.plan\.0\.all_reduce", False),
+    # ... and with one, pack is the wire cast
     "bf16_wire": ({"allreduce_grad_dtype": "bfloat16"}, {},
-                  r"chainermn\.plan\.0\.all_reduce"),
-    # ZeRO-1 runs no plan: reduce-scatter and gather-back are its exchange
-    "zero1": ({}, {"zero": True}, None),
+                  r"chainermn\.plan\.0\.all_reduce", True),
+    # ZeRO-1 runs no plan: reduce-scatter and gather-back are its exchange,
+    # over the packed buffer they shard
+    "zero1": ({}, {"zero": True}, None, True),
 }
 
 
@@ -52,7 +57,7 @@ def _compiled_text(comm_args, optimizer_args):
 
 @pytest.mark.parametrize("wrapper", sorted(WRAPPERS))
 def test_every_scope_is_in_the_compiled_step(wrapper):
-    comm_args, optimizer_args, stage = WRAPPERS[wrapper]
+    comm_args, optimizer_args, stage, packs = WRAPPERS[wrapper]
     text = _compiled_text(comm_args, optimizer_args)
     names = set(OP_NAME.findall(text))
 
@@ -65,7 +70,7 @@ def test_every_scope_is_in_the_compiled_step(wrapper):
     # flax's module scopes, inside the program's
     assert some(r"/chainermn\.grad/.*\bblock_1/qkv/dot_general")
     assert some(r"/chainermn\.grad/.*\bhead/")
-    assert some(r"/chainermn\.allreduce_grad/chainermn\.pack/")
+    assert some(r"/chainermn\.allreduce_grad/chainermn\.pack/") == packs
     assert some(r"/chainermn\.allreduce_grad/chainermn\.unpack/")
     assert some(r"/chainermn\.update/")
     assert some(r"/chainermn\.report/")
