@@ -1,5 +1,6 @@
 from chainermn_tpu.models.alexnet import AlexNet
 from chainermn_tpu.models.googlenet import GoogLeNet, GoogLeNetBN
+from chainermn_tpu.models.lfm2 import LFM2Config, LFM2MoE
 from chainermn_tpu.models.mlp import MLP
 from chainermn_tpu.models.nin import NIN
 from chainermn_tpu.models.resnet import (
@@ -19,6 +20,8 @@ from chainermn_tpu.models.vit import ViT, ViT_B16, ViT_S16
 
 __all__ = [
     "TransformerLM",
+    "LFM2Config",
+    "LFM2MoE",
     "MLP",
     "AlexNet",
     "NIN",

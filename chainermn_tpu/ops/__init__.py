@@ -10,12 +10,14 @@ from chainermn_tpu.ops.fused_norm import (
     fused_norm_traffic_bytes,
     resnet_bn_traffic_bytes,
 )
+from chainermn_tpu.ops.grouped_matmul import grouped_matmul
 
 __all__ = [
     "cast_scale",
     "flash_attention",
     "fused_norm",
     "fused_norm_reference",
+    "grouped_matmul",
     "FusedBatchNormAct",
     "fused_norm_traffic_bytes",
     "resnet_bn_traffic_bytes",

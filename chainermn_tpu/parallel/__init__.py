@@ -33,6 +33,7 @@ from chainermn_tpu.parallel.tensor import (
 )
 from chainermn_tpu.parallel.expert import (
     ExpertParallelMLP,
+    dropless_moe,
     moe_apply,
     moe_plan_topology,
 )
@@ -58,6 +59,7 @@ __all__ = [
     "RowParallelDense",
     "TensorParallelMLP",
     "describe_buckets",
+    "dropless_moe",
     "moe_apply",
     "moe_plan_topology",
     "partition_buckets",

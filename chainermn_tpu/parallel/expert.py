@@ -28,11 +28,22 @@ Training-grade bookkeeping (``return_stats=True`` / ``with_stats=True``):
   dropped by capacity.  A collapsed router shows up here immediately
   instead of silently degrading the layer to identity;
 * ``expert_load`` — [E] global fraction of top-1 traffic per expert.
+
+**The dropless path** (:func:`dropless_moe`, beside the capacity path and
+sharing nothing with it) is for a layer that is TOLD WHICH EXPERTS IT HOLDS:
+it routes over all ``num_experts`` of the model, orders the (token, choice)
+pairs by expert, computes its own ``held`` experts' part of the result with
+a grouped matrix product (:mod:`chainermn_tpu.ops.grouped_matmul`) over
+exactly the rows routed to them — no capacity, no dropped pair, whatever
+the imbalance — and leaves out what the absent experts would have added.
+Three named parts: :func:`dropless_route`, :func:`dropless_dispatch`,
+:func:`dropless_combine`.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import functools
+from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -263,4 +274,175 @@ class ExpertParallelMLP(nn.Module):
         return res.reshape(shape)
 
 
-__all__ = ["ExpertParallelMLP", "moe_apply", "moe_plan_topology"]
+# ---------------------------------------------------------------------------
+# the dropless path
+# ---------------------------------------------------------------------------
+
+class Dispatch(NamedTuple):
+    """How the (token, choice) pairs are ordered for the held experts.
+    ``order[i]`` is the pair (``token * top_k + choice``) at sorted row i,
+    ``inverse`` its inverse permutation; pairs of experts not held sort last.
+    ``group_sizes[e]`` rows belong to held expert e; ``is_held`` [N, K]."""
+
+    order: jax.Array
+    inverse: jax.Array
+    group_sizes: jax.Array
+    is_held: jax.Array
+
+
+def dropless_route(router_logits, expert_bias, top_k: int,
+                   normalize: bool = True, scaling_factor: float = 1.0):
+    """Scores, biased top-k, renormalised weights.
+
+    ``router_logits`` [N, E] over ALL the model's experts.  Scores are
+    ``sigmoid`` in float32; the ``top_k`` experts are chosen by ``score +
+    expert_bias`` (the bias steers the SELECTION only: it has no gradient
+    and does not weigh the result); the weights are the chosen experts' own
+    scores, divided by their sum (+1e-6) when ``normalize``, times
+    ``scaling_factor``.  Returns ``(chosen [N, K] int32, weights [N, K]
+    float32)``."""
+    # (a scope's name with a second dot goes on a line of its own:
+    # tests/test_named_scopes.py reads every line that opens a scope)
+    with jax.named_scope(
+            "chainermn.moe.route"):
+        scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+        biased = scores if expert_bias is None else (
+            scores + expert_bias.astype(jnp.float32))
+        _, chosen = lax.top_k(lax.stop_gradient(biased), top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if normalize:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        return chosen.astype(jnp.int32), weights * scaling_factor
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sorted_rows(x, order, inverse, top_k):
+    """``x[order // top_k]``: every pair's token row, in sorted order.  The
+    derivative is the inverse GATHER and a sum over the choices, where
+    autodiff would scatter-add ``N * K`` rows into ``N``."""
+    return x[order // top_k]
+
+
+def _sorted_rows_fwd(x, order, inverse, top_k):
+    return x[order // top_k], inverse
+
+
+def _sorted_rows_bwd(top_k, inverse, g):
+    by_pair = g[inverse].reshape(-1, top_k, g.shape[-1])
+    return by_pair.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+
+
+_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
+@jax.custom_vjp
+def _pair_rows(y, order, inverse):
+    """``y[inverse]``: sorted rows back in (token, choice) order; the
+    derivative gathers by ``order`` (a permutation has no scatter)."""
+    return y[inverse]
+
+
+def _pair_rows_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _pair_rows_bwd(order, g):
+    return g[order], None, None
+
+
+_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+
+
+def dropless_dispatch(x, chosen, first_expert: int, held_experts: int):
+    """Order the pairs by expert.  ``x`` [N, D], ``chosen`` [N, K] expert
+    ids.  Pairs whose expert lies in ``[first_expert, first_expert +
+    held_experts)`` come first, grouped by expert in token order; the
+    others go last and are never computed.  Returns ``(rows [N * K, D],
+    Dispatch)``: the grouped product reads ``rows`` by
+    ``dispatch.group_sizes`` and skips what lies past their sum."""
+    with jax.named_scope(
+            "chainermn.moe.dispatch"):
+        top_k = chosen.shape[1]
+        local = chosen.reshape(-1) - first_expert
+        is_held = (local >= 0) & (local < held_experts)
+        key = jnp.where(is_held, local, held_experts)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pairs = order.shape[0]
+        inverse = jnp.zeros((pairs,), jnp.int32).at[order].set(
+            jnp.arange(pairs, dtype=jnp.int32))
+        group_sizes = jnp.zeros((held_experts + 1,), jnp.int32).at[key].add(
+            1)[:held_experts]
+        rows = _sorted_rows(x, order, inverse, top_k)
+        return rows, Dispatch(order, inverse, group_sizes,
+                              is_held.reshape(chosen.shape))
+
+
+def dropless_combine(expert_rows, weights, dispatch: Dispatch):
+    """The held experts' weighted sum for every token: ``expert_rows``
+    [N * K, D] in sorted order (zero past the groups) -> [N, D].  A choice
+    whose expert is not held adds nothing."""
+    with jax.named_scope(
+            "chainermn.moe.combine"):
+        n, top_k = weights.shape
+        by_pair = _pair_rows(expert_rows, dispatch.order, dispatch.inverse)
+        by_pair = by_pair.reshape(n, top_k, expert_rows.shape[-1])
+        held = jnp.where(dispatch.is_held, weights, 0.0)
+        return (by_pair.astype(jnp.float32) * held[..., None]).sum(1).astype(
+            expert_rows.dtype)
+
+
+def dropless_counters(dispatch: Dispatch):
+    """What the routing did, for ``make_train_step(has_aux=True)``: all
+    float32, so that the step's report can average them over devices."""
+    sizes = dispatch.group_sizes.astype(jnp.float32)
+    routed_here = dispatch.is_held.sum().astype(jnp.float32)
+    return {
+        "tokens_per_held_expert": sizes,
+        "held_share": sizes.sum() / dispatch.is_held.size,
+        "load_max_over_mean": sizes.max() / jnp.maximum(sizes.mean(), 1.0),
+        # pairs routed to a held expert that no group's rows cover
+        "dropped_pairs": routed_here - sizes.sum(),
+    }
+
+
+def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
+                 num_experts: int, top_k: int, first_expert: int = 0,
+                 held_experts: Optional[int] = None, axis_name=None,
+                 normalize: bool = True, scaling_factor: float = 1.0):
+    """The held experts' part of a top-k mixture of experts, droplessly.
+
+    ``x`` [N, D]; ``router_logits`` [N, num_experts]; ``expert_fn(rows [N *
+    K, D], group_sizes [held]) -> [N * K, D]`` applies held expert e to the
+    e-th group of rows (a grouped matrix product) and must return zeros past
+    the groups.  Returns ``(y [N, D], counters)``.
+
+    With ``axis_name=None`` the layer runs on one device and exchanges
+    nothing: it computes what ITS experts give and nothing stands in for
+    the experts held elsewhere.  The exchange of rows between the devices
+    of an ``ep`` axis (a ragged all-to-all by group sizes) is not written
+    yet (ROADMAP.md)."""
+    if axis_name is not None:
+        raise NotImplementedError(
+            "dropless_moe exchanges no rows between devices yet: the ragged "
+            "all-to-all over an expert-parallel axis is ROADMAP.md's; pass "
+            "axis_name=None and the range of experts this device holds")
+    held_experts = num_experts if held_experts is None else held_experts
+    if router_logits.shape[-1] != num_experts:
+        raise ValueError(f"router_logits has {router_logits.shape[-1]} "
+                         f"experts but num_experts={num_experts}")
+    if not (0 <= first_expert and held_experts >= 1
+            and first_expert + held_experts <= num_experts):
+        raise ValueError(
+            f"held experts [{first_expert}, {first_expert + held_experts}) "
+            f"must lie within the model's {num_experts}")
+    chosen, weights = dropless_route(router_logits, expert_bias, top_k,
+                                     normalize, scaling_factor)
+    rows, dispatch = dropless_dispatch(x, chosen, first_expert, held_experts)
+    expert_rows = expert_fn(rows, dispatch.group_sizes)
+    y = dropless_combine(expert_rows, weights, dispatch)
+    return y, dropless_counters(dispatch)
+
+
+__all__ = ["Dispatch", "ExpertParallelMLP", "dropless_combine",
+           "dropless_counters", "dropless_dispatch", "dropless_moe",
+           "dropless_route", "moe_apply", "moe_plan_topology"]

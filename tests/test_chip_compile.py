@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 from chainermn_tpu.ops.cast_scale import cast_scale  # noqa: E402
 from chainermn_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from chainermn_tpu.ops.fused_norm import fused_norm  # noqa: E402
+from chainermn_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 
 # ResNet-50's packed float32 gradient buffer: 25.5M elements
 RESNET50_PARAMS = 25_557_032
@@ -60,6 +61,23 @@ def _norm_grad(x, scale, bias):
 _Q = ((1, 8192, 16, 128), jnp.bfloat16)     # the LM's [B, T, H, D]
 _KV_GQA = ((1, 8192, 4, 128), jnp.bfloat16)
 _SEG = ((1, 8192), jnp.int32)
+# LFM2-8B-A1B: 32 query / 8 kv heads of 64, three rows
+_Q64 = ((3, 8192, 32, 64), jnp.bfloat16)
+_KV64 = ((3, 8192, 8, 64), jnp.bfloat16)
+# its expert layer: tokens x top-4 rows through 8 held experts of 1792
+_ROWS = ((3 * 8192 * 4, 2048), jnp.bfloat16)
+_EXPERTS_UP = ((8, 2048, 1792), jnp.bfloat16)
+_EXPERTS_DOWN = ((8, 1792, 2048), jnp.bfloat16)
+_GROUPS = ((8,), jnp.int32)
+
+
+def _expert_grad(rows, up, down, sizes):
+    """Two grouped products and their VJPs: forward, dlhs and drhs each."""
+    return jax.grad(
+        lambda r, u, d: grouped_matmul(
+            grouped_matmul(r, u, sizes), d, sizes).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))(rows, up, down)
+
 _GRADS = ((RESNET50_PARAMS,), jnp.float32)
 
 
@@ -75,6 +93,11 @@ CASES = {
     "flash_fwd_bwd": (_flash_grad, [_Q, _Q, _Q], 3),
     "flash_gqa_fwd_bwd": (_flash_grad, [_Q, _KV_GQA, _KV_GQA], 3),
     "flash_segment_ids_fwd_bwd": (_flash_grad, [_Q, _Q, _Q, _SEG], 3),
+    "flash_gqa_head_dim_64_fwd_bwd": (_flash_grad, [_Q64, _KV64, _KV64], 3),
+    "grouped_matmul_fwd": (grouped_matmul, [_ROWS, _EXPERTS_UP, _GROUPS], 1),
+    "grouped_matmul_fwd_bwd": (
+        # the second forward is dead under a sum: 1 + 2 x (dlhs, drhs)
+        _expert_grad, [_ROWS, _EXPERTS_UP, _EXPERTS_DOWN, _GROUPS], 5),
     "cast_scale_resnet50_grads": (
         lambda g: cast_scale(g, jnp.bfloat16, 0.25), [_GRADS], 1),
     "fused_norm_fwd_stage1": (
@@ -120,6 +143,25 @@ def test_cast_scale_compiles_inside_four_chip_shard_map(topo):
             in_specs=P("d"), out_specs=P("d"))(g)
 
     assert "tpu_custom_call" in _compile(wire, [grads])
+
+
+def test_grouped_matmul_compiles_inside_shard_map(topo):
+    """Where the train step runs it: inside ``shard_map`` with varying
+    axes checked, on device-varying rows, weights and group sizes (the
+    kernels type their results as their operands are)."""
+    mesh = Mesh(topo.devices[:1], ("d",))
+    shapes = [jax.ShapeDtypeStruct((1,) + shape, dtype,
+                                   sharding=NamedSharding(mesh, P("d")))
+              for shape, dtype in (_ROWS, _EXPERTS_UP, _EXPERTS_DOWN,
+                                   _GROUPS)]
+
+    def step(*stacked):
+        return jax.shard_map(
+            lambda *a: jax.tree.map(
+                lambda g: g[None], _expert_grad(*(x[0] for x in a))),
+            mesh=mesh, in_specs=P("d"), out_specs=P("d"))(*stacked)
+
+    assert _compile(step, shapes).count("tpu_custom_call") >= 5
 
 
 def _exchange_program(topo, body_name):
