@@ -35,6 +35,7 @@ module, so the chip's trace names them after it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -206,6 +207,38 @@ class DenseFFN(nn.Module):
         return _dense(u.shape[-1], dtype, "w2")(nn.silu(gate) * up)
 
 
+def _swiglu_experts(rows, group_sizes, w1, w3, w2, *, impl,
+                    gate_and_up_as_one=False):
+    """``W2_e(silu(W1_e u) * W3_e u)`` for every group e of ``rows``."""
+    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+
+    # the grouped products stay directly under the calling module (the trace
+    # names their kernels after it); the scope holds what XLA computes
+    # between them
+    product = lambda a, w: grouped_matmul(a, w, group_sizes, impl)
+    if gate_and_up_as_one:
+        gate, up = jnp.split(
+            product(rows, jnp.concatenate([w1, w3], axis=-1)), 2, axis=-1)
+    else:
+        gate, up = product(rows, w1), product(rows, w3)
+    with jax.named_scope(
+            "chainermn.moe.experts"):
+        hidden = nn.silu(gate) * up
+    return product(hidden, w2)
+
+
+# one callable an implementation, the SAME object for every layer: the expert
+# layer's remainder is traced once for all the layers that pass it
+_EXPERTS = {impl: functools.partial(_swiglu_experts, impl=impl)
+            for impl in ("pallas", "ragged_dot")}
+# ... and for that guarded remainder XLA's own grouped product, ``W1`` and
+# ``W3`` as one.  Every program carries the remainder, traced, differentiated
+# and compiled, and almost no step runs it: what counts there is what it adds
+# to set-up, and that goes by the number of kernels (PERF.md, PR 27)
+_REMAINDER_EXPERTS = functools.partial(
+    _swiglu_experts, impl="ragged_dot", gate_and_up_as_one=True)
+
+
 class SparseMoE(nn.Module):
     """The held experts' part of the mixture: ``sum over the chosen experts
     held here of w_e * W2_e(silu(W1_e u) * W3_e u)``.  Parameters: the
@@ -218,7 +251,6 @@ class SparseMoE(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from chainermn_tpu.ops.grouped_matmul import grouped_matmul
         from chainermn_tpu.parallel.expert import dropless_moe
 
         cfg = self.config
@@ -241,24 +273,18 @@ class SparseMoE(nn.Module):
         w3 = self.param("w3", init, (held, d, width), jnp.float32)
         w2 = self.param("w2", init, (held, width, d), jnp.float32)
 
-        def experts(rows, group_sizes):
-            # the three grouped products stay directly under this module
-            # (the trace names their kernels after it); the scope holds
-            # what XLA computes between them
-            product = lambda a, w: grouped_matmul(
-                a, w.astype(cfg.dtype), group_sizes, cfg.moe_matmul_impl)
-            gate, up = product(rows, w1), product(rows, w3)
-            with jax.named_scope(
-                    "chainermn.moe.experts"):
-                hidden = nn.silu(gate) * up
-            return product(hidden, w2)
-
+        # cast once, out here: the remainder's gradient for a weight cast
+        # inside it would be a float32 stack of zeros a step
+        stacks = tuple(w.astype(cfg.dtype) for w in (w1, w3, w2))
         y, counters = dropless_moe(
-            flat, logits, bias, experts,
+            flat, logits, bias, _EXPERTS[cfg.moe_matmul_impl],
+            expert_args=stacks,
             num_experts=routed, top_k=cfg.num_experts_per_tok,
             first_expert=cfg.first_expert, held_experts=held,
             normalize=cfg.norm_topk_prob,
-            scaling_factor=cfg.routed_scaling_factor)
+            scaling_factor=cfg.routed_scaling_factor,
+            # rows past the layer's bound, on the rare step that has any
+            remainder_fn=_REMAINDER_EXPERTS)
         return y.reshape(u.shape), counters
 
 

@@ -37,7 +37,10 @@ a grouped matrix product (:mod:`chainermn_tpu.ops.grouped_matmul`) over
 exactly the rows routed to them — no capacity, no dropped pair, whatever
 the imbalance — and leaves out what the absent experts would have added.
 Three named parts: :func:`dropless_route`, :func:`dropless_dispatch`,
-:func:`dropless_combine`.
+:func:`dropless_combine`.  Only the sort sees all ``N * K`` pairs: what
+carries a feature dimension is bounded by what the held experts get
+(:func:`dropless_rows_bound`), with a guarded second pass for a routing
+that exceeds it.
 """
 
 from __future__ import annotations
@@ -282,12 +285,28 @@ class Dispatch(NamedTuple):
     """How the (token, choice) pairs are ordered for the held experts.
     ``order[i]`` is the pair (``token * top_k + choice``) at sorted row i,
     ``inverse`` its inverse permutation; pairs of experts not held sort last.
-    ``group_sizes[e]`` rows belong to held expert e; ``is_held`` [N, K]."""
+    ``group_sizes[e]`` rows belong to held expert e; ``is_held`` [N, K].
+
+    The layer's passes each take a WINDOW ``(start, stop)`` of the sorted
+    rows: only the rows of a window are ever gathered, computed or summed."""
 
     order: jax.Array
     inverse: jax.Array
     group_sizes: jax.Array
     is_held: jax.Array
+
+
+_ROW_TILE = 512     # grouped_matmul's row tile: the bound is whole tiles
+
+
+def dropless_rows_bound(pairs: int, held_experts: int,
+                        num_experts: int) -> int:
+    """How many sorted rows the layer's main pass materialises: what an even
+    routing sends to ``held_experts`` of ``num_experts``, half as much
+    again, in whole row tiles, and never more than the ``pairs`` there are.
+    A function of shapes alone: with every expert held it is ``pairs``."""
+    even_and_a_half = -(-(pairs * held_experts * 3) // (num_experts * 2))
+    return min(pairs, -(-even_and_a_half // _ROW_TILE) * _ROW_TILE)
 
 
 def dropless_route(router_logits, expert_bias, top_k: int,
@@ -315,54 +334,118 @@ def dropless_route(router_logits, expert_bias, top_k: int,
         return chosen.astype(jnp.int32), weights * scaling_factor
 
 
+def _window_sizes(group_sizes, window):
+    """The part of every group that lies in sorted rows [start, stop)."""
+    ends = jnp.cumsum(group_sizes)
+    clip = lambda offsets: jnp.clip(offsets, *window)
+    return clip(ends) - clip(ends - group_sizes)
+
+
+def _window_tokens(dispatch: Dispatch, window):
+    """The token of every sorted row of the window."""
+    return dispatch.order[slice(*window)] // dispatch.is_held.shape[1]
+
+
+def _window_weights(weights, dispatch: Dispatch, window):
+    """[rows] float32: the weight of every window row's pair (``weights``
+    [N, K]; None: one), and zero for a row outside the held experts' groups
+    (held pairs sort first: those are the rows from the groups' sum on)."""
+    start, stop = window
+    in_groups = jnp.arange(start, stop) < dispatch.group_sizes.sum()
+    if weights is None:
+        return in_groups.astype(jnp.float32)
+    return jnp.where(in_groups,
+                     weights.reshape(-1)[dispatch.order[start:stop]], 0.0)
+
+
+def _token_sums(rows, weights, dispatch: Dispatch, window):
+    """Rows -> tokens: ``out[t] = sum over t's pairs in the window of
+    weights[pair] * rows[row of pair]``, in float32.  ``rows`` [stop -
+    start, D]; ``weights`` [N, K] or None (ones); returns [N, D].  A
+    scatter-add of the window's rows into their tokens (on the chip it beat
+    gathering every pair's row out of the window: PERF.md, PR 27).  Rows
+    outside the groups count nothing, whatever they hold."""
+    weighted = rows.astype(jnp.float32) * _window_weights(
+        weights, dispatch, window)[:, None]
+    return jnp.zeros((dispatch.is_held.shape[0], rows.shape[1]),
+                     jnp.float32).at[_window_tokens(dispatch, window)].add(
+                         weighted).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _token_rows(x, dispatch, window):
+    """Tokens -> rows: every window row's token row, ``x[token of row]``.
+    The derivative is :func:`_token_sums`, where autodiff would scatter-add
+    every pair's row, the pairs not held among them."""
+    return x[_window_tokens(dispatch, window)]
+
+
+def _token_rows_fwd(x, dispatch, window):
+    return _token_rows(x, dispatch, window), dispatch
+
+
+def _token_rows_bwd(window, dispatch, g):
+    return _token_sums(g, None, dispatch, window), None
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sorted_rows(x, order, inverse, top_k):
-    """``x[order // top_k]``: every pair's token row, in sorted order.  The
-    derivative is the inverse GATHER and a sum over the choices, where
-    autodiff would scatter-add ``N * K`` rows into ``N``."""
-    return x[order // top_k]
+def _weighted_token_sums(expert_rows, weights, dispatch, window):
+    """:func:`_token_sums` as the layer differentiates it: the rows'
+    gradient is the token's, gathered and weighted in one pass over the
+    window; the weights' gradient one dot product a window row, then a
+    gather of scalars into (token, choice) order."""
+    return _token_sums(expert_rows, weights, dispatch, window)
 
 
-def _sorted_rows_fwd(x, order, inverse, top_k):
-    return x[order // top_k], inverse
+def _weighted_token_sums_fwd(expert_rows, weights, dispatch, window):
+    return (_token_sums(expert_rows, weights, dispatch, window),
+            (expert_rows, weights, dispatch))
 
 
-def _sorted_rows_bwd(top_k, inverse, g):
-    by_pair = g[inverse].reshape(-1, top_k, g.shape[-1])
-    return by_pair.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+def _weighted_token_sums_bwd(window, residual, g):
+    expert_rows, weights, dispatch = residual
+    g_rows = g[_window_tokens(dispatch, window)].astype(jnp.float32)
+    d_rows = (g_rows * _window_weights(weights, dispatch, window)[:, None]
+              ).astype(expert_rows.dtype)
+    d_sorted = (expert_rows.astype(jnp.float32) * g_rows).sum(-1)
+    # scalars back to (token, choice) order: a pair's row of the window,
+    # if its expert is held and the window holds it
+    start, stop = window
+    slot = dispatch.inverse - start
+    inside = (slot >= 0) & (slot < stop - start) & dispatch.is_held.reshape(
+        -1)
+    d_weights = jnp.where(
+        inside, d_sorted[jnp.clip(slot, 0, stop - start - 1)], 0.0)
+    d_weights = d_weights.reshape(weights.shape)
+    return d_rows, d_weights.astype(weights.dtype), None
 
 
-_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+_weighted_token_sums.defvjp(_weighted_token_sums_fwd,
+                            _weighted_token_sums_bwd)
 
 
-@jax.custom_vjp
-def _pair_rows(y, order, inverse):
-    """``y[inverse]``: sorted rows back in (token, choice) order; the
-    derivative gathers by ``order`` (a permutation has no scatter)."""
-    return y[inverse]
+def dropless_rows(x, dispatch: Dispatch, start: int, stop: int):
+    """Sorted rows ``[start, stop)``: each one's token row of ``x`` [N, D]."""
+    with jax.named_scope(
+            "chainermn.moe.dispatch"):
+        return _token_rows(x, dispatch, (start, stop))
 
 
-def _pair_rows_fwd(y, order, inverse):
-    return y[inverse], order
-
-
-def _pair_rows_bwd(order, g):
-    return g[order], None, None
-
-
-_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
-
-
-def dropless_dispatch(x, chosen, first_expert: int, held_experts: int):
+def dropless_dispatch(x, chosen, first_expert: int, held_experts: int,
+                      bound: Optional[int] = None):
     """Order the pairs by expert.  ``x`` [N, D], ``chosen`` [N, K] expert
     ids.  Pairs whose expert lies in ``[first_expert, first_expert +
     held_experts)`` come first, grouped by expert in token order; the
-    others go last and are never computed.  Returns ``(rows [N * K, D],
-    Dispatch)``: the grouped product reads ``rows`` by
-    ``dispatch.group_sizes`` and skips what lies past their sum."""
+    others go last and are never computed.  Returns ``(rows [bound, D],
+    Dispatch)``, ``bound`` defaulting to all ``N * K``: the first ``bound``
+    sorted rows (:func:`dropless_rows` gives any other window).  The
+    grouped product reads ``rows`` by the part of ``dispatch.group_sizes``
+    below ``bound`` and skips what lies past their sum."""
     with jax.named_scope(
             "chainermn.moe.dispatch"):
-        top_k = chosen.shape[1]
         local = chosen.reshape(-1) - first_expert
         is_held = (local >= 0) & (local < held_experts)
         key = jnp.where(is_held, local, held_experts)
@@ -372,49 +455,105 @@ def dropless_dispatch(x, chosen, first_expert: int, held_experts: int):
             jnp.arange(pairs, dtype=jnp.int32))
         group_sizes = jnp.zeros((held_experts + 1,), jnp.int32).at[key].add(
             1)[:held_experts]
-        rows = _sorted_rows(x, order, inverse, top_k)
-        return rows, Dispatch(order, inverse, group_sizes,
-                              is_held.reshape(chosen.shape))
+    dispatch = Dispatch(order, inverse, group_sizes,
+                        is_held.reshape(chosen.shape))
+    return dropless_rows(x, dispatch, 0,
+                         pairs if bound is None else bound), dispatch
 
 
-def dropless_combine(expert_rows, weights, dispatch: Dispatch):
+def dropless_combine(expert_rows, weights, dispatch: Dispatch,
+                     start: int = 0):
     """The held experts' weighted sum for every token: ``expert_rows``
-    [N * K, D] in sorted order (zero past the groups) -> [N, D].  A choice
-    whose expert is not held adds nothing."""
+    [rows, D], the sorted rows from ``start`` on (zero past the groups) ->
+    [N, D], summed in float32 over a token's choices.  A choice whose
+    expert is not held, or whose row lies outside the window, adds
+    nothing."""
     with jax.named_scope(
             "chainermn.moe.combine"):
-        n, top_k = weights.shape
-        by_pair = _pair_rows(expert_rows, dispatch.order, dispatch.inverse)
-        by_pair = by_pair.reshape(n, top_k, expert_rows.shape[-1])
-        held = jnp.where(dispatch.is_held, weights, 0.0)
-        return (by_pair.astype(jnp.float32) * held[..., None]).sum(1).astype(
-            expert_rows.dtype)
+        return _weighted_token_sums(
+            expert_rows, weights, dispatch,
+            (start, start + expert_rows.shape[0]))
 
 
-def dropless_counters(dispatch: Dispatch):
+def dropless_counters(dispatch: Dispatch, bound: Optional[int] = None):
     """What the routing did, for ``make_train_step(has_aux=True)``: all
-    float32, so that the step's report can average them over devices."""
+    float32, so that the step's report can average them over devices.
+    ``bound`` is the main pass's rows (default: all the pairs)."""
     sizes = dispatch.group_sizes.astype(jnp.float32)
     routed_here = dispatch.is_held.sum().astype(jnp.float32)
+    bound = dispatch.is_held.size if bound is None else bound
     return {
         "tokens_per_held_expert": sizes,
         "held_share": sizes.sum() / dispatch.is_held.size,
         "load_max_over_mean": sizes.max() / jnp.maximum(sizes.mean(), 1.0),
         # pairs routed to a held expert that no group's rows cover
         "dropped_pairs": routed_here - sizes.sum(),
+        # the main pass's rows, and the rows the guarded remainder computed:
+        # 0 on every step it did not run
+        "rows_bound": jnp.full((), bound, jnp.float32),
+        "rows_past_bound": jnp.maximum(sizes.sum() - bound, 0.0),
     }
+
+
+def _held_part(expert_fn, rows, weights, dispatch, expert_args, start):
+    """One pass over the sorted rows from ``start`` on: the experts, then
+    the weighted sum into the tokens."""
+    window = (start, start + rows.shape[0])
+    expert_rows = expert_fn(rows, _window_sizes(dispatch.group_sizes, window),
+                            *expert_args)
+    return dropless_combine(expert_rows, weights, dispatch, start)
+
+
+@functools.partial(jax.jit, static_argnames=("expert_fn", "bound"))
+def _remainder(x, weights, dispatch, expert_args, *, expert_fn, bound):
+    """The held experts' part of the result over the sorted rows from
+    ``bound`` on: the main pass again, on the other rows.  It keeps its
+    inputs alone and recomputes in the backward.  Jitted so that layers
+    with one ``expert_fn`` and equal shapes share its trace and its
+    derivative's."""
+    def rest(x, weights, dispatch, expert_args):
+        rows = dropless_rows(x, dispatch, bound, dispatch.order.shape[0])
+        return _held_part(expert_fn, rows, weights, dispatch, expert_args,
+                          bound)
+
+    return jax.checkpoint(rest)(x, weights, dispatch, expert_args)
 
 
 def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
                  num_experts: int, top_k: int, first_expert: int = 0,
                  held_experts: Optional[int] = None, axis_name=None,
-                 normalize: bool = True, scaling_factor: float = 1.0):
+                 normalize: bool = True, scaling_factor: float = 1.0,
+                 expert_args=(), remainder_fn: Optional[Callable] = None):
     """The held experts' part of a top-k mixture of experts, droplessly.
 
-    ``x`` [N, D]; ``router_logits`` [N, num_experts]; ``expert_fn(rows [N *
-    K, D], group_sizes [held]) -> [N * K, D]`` applies held expert e to the
-    e-th group of rows (a grouped matrix product) and must return zeros past
-    the groups.  Returns ``(y [N, D], counters)``.
+    ``x`` [N, D]; ``router_logits`` [N, num_experts]; ``expert_fn(rows [R,
+    D], group_sizes [held], *expert_args) -> [R, D]`` applies held expert e
+    to the e-th group of rows (a grouped matrix product) and must return
+    zeros past the groups.  Returns ``(y [N, D], counters)``.
+
+    **Rows.**  Of the ``N * K`` sorted rows the main pass materialises the
+    first ``R`` (:func:`dropless_rows_bound`: what an even routing gives
+    the held experts, half as much again) — the gathers, ``expert_fn`` and
+    the sums back are all ``R`` rows.  What an uneven routing puts past
+    ``R`` goes through the same pass over rows ``[R, N * K)`` under a
+    ``lax.cond`` that a normal step does not take (``rows_past_bound``
+    counts its rows); it keeps its inputs alone and recomputes in the
+    backward, so that it costs a normal step no memory.  With every expert
+    held ``R = N * K`` and no second pass is traced.
+
+    **What the remainder costs every program** is its TRACING,
+    differentiated under the ``cond``, though almost no step runs it; on
+    the benchmark's cell an ``expert_fn`` of Pallas kernels made that 16 s
+    of warm set-up (PERF.md, PR 27).  Two things keep it small.
+    ``remainder_fn`` (default ``expert_fn``) is what the remainder calls in
+    ``expert_fn``'s place: the same function of ``(rows, group_sizes,
+    *expert_args)``, which may take XLA's own grouped product
+    (``grouped_matmul(impl="ragged_dot")``).  And the remainder is ONE
+    jitted function of ``(x, weights, dispatch, expert_args)``: layers that
+    pass the same callable (the same object: a module-level function, not a
+    closure made per layer) and equal shapes share one trace of it, its
+    derivative included.  A closure over the weights still works, with
+    ``expert_args=()``, and shares nothing.
 
     With ``axis_name=None`` the layer runs on one device and exchanges
     nothing: it computes what ITS experts give and nothing stands in for
@@ -437,12 +576,25 @@ def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
             f"must lie within the model's {num_experts}")
     chosen, weights = dropless_route(router_logits, expert_bias, top_k,
                                      normalize, scaling_factor)
-    rows, dispatch = dropless_dispatch(x, chosen, first_expert, held_experts)
-    expert_rows = expert_fn(rows, dispatch.group_sizes)
-    y = dropless_combine(expert_rows, weights, dispatch)
-    return y, dropless_counters(dispatch)
+    pairs = chosen.size
+    bound = dropless_rows_bound(pairs, held_experts, num_experts)
+    rows, dispatch = dropless_dispatch(x, chosen, first_expert, held_experts,
+                                       bound)
+
+    # the main pass: unconditional and straight-line (its kernels keep the
+    # names the trace's readers know: docs/observability.md)
+    y = _held_part(expert_fn, rows, weights, dispatch, expert_args, 0)
+    if bound < pairs:
+        y = y + lax.cond(
+            dispatch.group_sizes.sum() > bound,
+            functools.partial(_remainder, expert_fn=remainder_fn or expert_fn,
+                              bound=bound),
+            lambda x, weights, dispatch, expert_args: jnp.zeros_like(x),
+            x, weights, dispatch, expert_args)
+    return y, dropless_counters(dispatch, bound)
 
 
 __all__ = ["Dispatch", "ExpertParallelMLP", "dropless_combine",
            "dropless_counters", "dropless_dispatch", "dropless_moe",
-           "dropless_route", "moe_apply", "moe_plan_topology"]
+           "dropless_route", "dropless_rows", "dropless_rows_bound",
+           "moe_apply", "moe_plan_topology"]

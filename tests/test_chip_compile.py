@@ -217,3 +217,58 @@ def test_four_chip_exchange_builds_no_buffer(topo, body, packs):
     # the combiner, not a buffer, merges the leaves: fewer operations than
     # leaves (every small vector rides with a matrix)
     assert len(reduced) < 11
+
+
+def test_moe_layer_main_pass_kernels_keep_their_names(topo):
+    """The expert layer at the benchmark cell's sizes, forward and backward,
+    alone.  The trace's readers find the grouped-matmul kernels by name
+    (``moe.<k>``: ``chipbench/layer_metrics/moe_gmm_ms.py::is_gmm``), and a
+    Pallas call is named after the innermost entry of its name stack: the
+    main pass's nine must stay directly under the module, whatever the
+    guarded remainder (under its ``cond`` and ``checkpoint``) runs."""
+    import flax.linen as nn
+
+    from chainermn_tpu.models.lfm2 import LFM2Config, SparseMoE
+    from chipbench import reduce_trace, spec
+    from chipbench.layer_metrics.moe_gmm_ms import is_gmm
+
+    sizes = spec.resolve("lfm2-8b-a1b-ep4share-t8192").sizes
+    config = LFM2Config.from_dict(
+        sizes, num_experts_routed=sizes["num_experts_published"],
+        dtype=jnp.dtype(sizes["compute_dtype"]))
+    assert config.moe_matmul_impl == "pallas"
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            return SparseMoE(config, name="moe")(u)[0]
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                             sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"], config.hidden_size),
+        config.dtype)
+    params = jax.eval_shape(
+        Layer().init, jax.random.key(0),
+        jax.ShapeDtypeStruct((1, 128, config.hidden_size), config.dtype))
+    grad = jax.value_and_grad(lambda p, u: Layer().apply(p, u).astype(
+        jnp.float32).sum(), argnums=(0, 1))
+    text = _compile(grad, jax.tree.map(on_chip, (params, tokens)))
+    kernels = [reduce_trace.short_name(line.strip().removeprefix("ROOT "))
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    main_pass = [name for name in kernels if is_gmm(name)]
+    # gate, up and down, each forward, dlhs and drhs, over the bound's rows
+    pairs = tokens.shape[0] * tokens.shape[1] * config.num_experts_per_tok
+    assert pairs == 98304 and len(main_pass) == 9, kernels
+    assert sum("[36864," in name for name in main_pass) == 6, main_pass
+    # the remainder takes XLA's own grouped product, ``W1`` and ``W3`` as
+    # one (``SparseMoE``'s ``remainder_fn``: no Pallas kernel to trace, and
+    # as few kernels to load as the mathematics allows): two in the forward's
+    # branch, two recomputed and four derivatives in the backward's, kernels
+    # the compiler makes and names itself
+    remainder = [name for name in kernels if not is_gmm(name)]
+    assert all(name.startswith("ragged-dot") for name in remainder), kernels
+    assert sum(name.startswith("ragged-dot-none")
+               for name in remainder) == 8, remainder
