@@ -6,15 +6,13 @@ VERDICT round-1 "next #1").  Times variants of the b=256 ResNet-50 step
 that surgically remove one cost at a time, so each feature's price is a
 measured subtraction, not a guess from trace categories:
 
-  full        — the bench.py step (fwd+bwd+allreduce+update, bf16)
+  full        — the resnet50-b256 cell's step (fwd+bwd+allreduce+update, bf16)
   nostats     — BatchNorm normalizes with CONSTANT mean/var (stat
                 reductions + their backward vanish; everything else,
                 including the normalize/scale elementwise math, stays)
   nonorm      — BatchNorm replaced by identity (all BN work vanishes)
   fwdonly     — forward pass only (no grad)
   fwdbwd      — fwd+bwd only (no allreduce/update)
-  s2d         — full step with the space-to-depth stem (round-4
-                countermeasure #1; measured a wash — see performance.md)
   remat       — full step with every residual block rematerialized
                 (nn.remat): prices whether trading HBM activation traffic
                 for recompute moves the memory-bound stages
@@ -252,7 +250,7 @@ def main():
     batch = put_global_batch(comm, (x, y))
 
     known_variants = {"full", "nostats", "nonorm", "fwdonly", "fwdbwd",
-                      "s2d", "remat", "fusednorm"}
+                      "remat", "fusednorm"}
     wanted = args.variants.split(",")
     unknown = set(wanted) - known_variants
     if unknown:
@@ -268,8 +266,6 @@ def main():
         kw = dict(num_classes=n_classes, dtype=jnp.bfloat16)
         if norm_cls is not None:
             kw["norm_cls"] = norm_cls
-        if variant == "s2d":
-            kw["stem"] = "s2d"
         if variant == "remat":
             from chainermn_tpu.models.resnet import BottleneckBlock
             kw["block_cls"] = nn.remat(BottleneckBlock)

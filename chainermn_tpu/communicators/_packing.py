@@ -10,8 +10,7 @@ back.
 TPU-native version: the "buffer" is a flat jnp array built inside the traced
 allreduce; XLA owns the actual memory.  Leaves are grouped by dtype (one flat
 buffer per dtype) unless a communication dtype is forced, in which case a
-single buffer is used and the cast in/out is fused by XLA (or by the Pallas
-cast+scale kernel, see ``chainermn_tpu/ops/cast_scale.py``).
+single buffer is used and the cast in/out is fused by XLA.
 
 Who still packs: what shards, stripes or quantizes a flat index space —
 plans with a reduce-scatter/all-gather, striped plans, quantizing plans

@@ -57,20 +57,6 @@ def _norm_relu(norm: ModuleDef, x, **kwargs):
     return nn.relu(norm(**kwargs)(x))
 
 
-def space_to_depth(x, block: int = 2):
-    """NHWC space-to-depth: (N, H, W, C) -> (N, H/b, W/b, b*b*C).
-
-    Pure data movement (a reshape/transpose pair); XLA lowers it to a
-    layout change, not a gather.
-    """
-    n, h, w, c = x.shape
-    if h % block or w % block:
-        raise ValueError(f"spatial dims {(h, w)} not divisible by {block}")
-    x = x.reshape(n, h // block, block, w // block, block, c)
-    x = x.transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(n, h // block, w // block, block * block * c)
-
-
 class BottleneckBlock(nn.Module):
     """1x1 -> 3x3 -> 1x1 bottleneck with projection shortcut on shape change."""
 
@@ -135,7 +121,6 @@ class ResNet(nn.Module):
     dtype: Any = jnp.float32
     momentum: float = 0.9
     norm_cls: Any = None  # default nn.BatchNorm; swap for perf probes/variants
-    stem: str = "conv7"  # "conv7" (reference) | "s2d" (space-to-depth, TPU)
     remat_policy: str = "none"  # "none" | "block" (full nn.remat) | "norm"
     #  ("norm" saves only the checkpoint_name'd conv outputs at norm
     #   boundaries and recomputes the normalize/ReLU tail in backward —
@@ -150,21 +135,7 @@ class ResNet(nn.Module):
                        momentum=self.momentum, epsilon=1e-5,
                        dtype=self.dtype, param_dtype=jnp.float32)
         x = x.astype(self.dtype)
-        if self.stem == "s2d":
-            # Space-to-depth stem — the standard TPU MLPerf ResNet input
-            # transform: the 7x7/s2 conv over (H, W, 3) is re-expressed as a
-            # 4x4/s1 conv over the (H/2, W/2, 12) space-to-depth view.  Any
-            # 7x7/s2 stem zero-padded to 8x8 maps exactly onto these 4x4x12
-            # weights, so (trained from scratch) this parameterizes a
-            # superset of the reference stem while feeding the MXU 12 input
-            # lanes instead of 3.  The rest of the network is unchanged.
-            x = space_to_depth(x, 2)
-            x = conv(self.num_filters, (4, 4), name="conv_init")(x)
-        elif self.stem == "conv7":
-            x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
-        else:
-            raise ValueError(
-                f"unknown stem {self.stem!r}: expected 'conv7' or 's2d'")
+        x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
         x = _norm_relu(norm, _tag(x), name="bn_init")
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         if self.remat_policy == "none":

@@ -347,7 +347,7 @@ def attribution_report(events_by_rank: Dict[int, List[dict]],
 def span_summary(events: List[dict], rank: int = 0, k: int = 3) -> dict:
     """Top-``k`` critical-path spans aggregated over every step in an
     event stream — the compact per-run attribution the benchmark
-    artifacts embed (``bench.py --metrics`` / ``bench_serving.py``)."""
+    artifacts embed (``bench_serving.py``)."""
     trees = build_step_trees(events, rank=rank)
     agg: Dict[Tuple[str, str], List[float]] = {}
     for tree in trees:

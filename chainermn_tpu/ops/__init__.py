@@ -1,7 +1,6 @@
 """Native/fused TPU kernels (Pallas) — the reference's CUDA-kernel role
 (SURVEY.md §2.3)."""
 
-from chainermn_tpu.ops.cast_scale import cast_scale
 from chainermn_tpu.ops.flash_attention import flash_attention
 from chainermn_tpu.ops.fused_norm import (
     FusedBatchNormAct,
@@ -13,7 +12,6 @@ from chainermn_tpu.ops.fused_norm import (
 from chainermn_tpu.ops.grouped_matmul import grouped_matmul
 
 __all__ = [
-    "cast_scale",
     "flash_attention",
     "fused_norm",
     "fused_norm_reference",

@@ -327,10 +327,10 @@ def fused_norm(x, scale, bias, mean=None, var=None, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if interpret and jax.typeof(x).vma:
-        # Same rule as ops/cast_scale.py: jax's Pallas interpreter is not
-        # vma-aware, so a kernel cannot be interpreted inside a shard_map
-        # body (the CPU mesh).  There the oracle's identical math stands
-        # in; on the chip the kernels are compiled and this never runs.
+        # jax's Pallas interpreter is not vma-aware, so a kernel cannot
+        # be interpreted inside a shard_map body (the CPU mesh).  There
+        # the oracle's identical math stands in; on the chip the kernels
+        # are compiled and this never runs.
         return fused_norm_reference(
             x, scale, bias, mean, var,
             use_running_average=use_running_average, epsilon=epsilon,

@@ -160,27 +160,6 @@ class _DoubleBufferingOptimizer:
 _VARYING = "__varying__"
 
 
-def _deprecate_raw_wire_knob(communicator, compression):
-    """One-release shim (satellite of the compression subsystem): a
-    communicator carrying a RAW ``allreduce_grad_dtype`` — i.e. the dtype
-    knob was passed directly rather than spelled as a compression codec —
-    still works unchanged, but points users at the replacement."""
-    if compression is not None:
-        return
-    dt = getattr(communicator, "allreduce_grad_dtype", None)
-    if dt is not None and getattr(communicator, "compression", None) is None:
-        import warnings
-        warnings.warn(
-            f"allreduce_grad_dtype={str(dt)!r} without an explicit "
-            "compression codec is deprecated; pass "
-            f"compression=NoCompression(wire_dtype={str(dt)!r}) (or just "
-            f"compression={str(dt)!r}) to create_communicator / "
-            "create_multi_node_optimizer instead — it lowers to the "
-            "identical cast-allreduce-cast program, and the raw dtype "
-            "knob will be removed in the release after next",
-            DeprecationWarning, stacklevel=3)
-
-
 class _ZeroState(NamedTuple):
     inner: Any  # inner optax state over THIS device's flat shard (varying)
 
@@ -354,7 +333,6 @@ def create_multi_node_optimizer(
         return _CompressedOptimizer(actual_optimizer, communicator,
                                     compression)
     compression = _cbase.resolve_compressor(compression)
-    _deprecate_raw_wire_knob(communicator, compression)
     if zero and double_buffering:
         raise ValueError("zero=True and double_buffering=True are mutually "
                          "exclusive (the pending full-size gradient buffer "
@@ -407,7 +385,6 @@ def make_train_step(
     has_aux: bool = False,
     donate: bool = True,
     with_model_state: bool = False,
-    scan_steps: int = 1,
     accum_steps: int = 1,
 ):
     """Build the canonical jitted SPMD train step (the hot loop of SURVEY.md
@@ -419,15 +396,6 @@ def make_train_step(
     ``step(params, opt_state, batch) -> (params, opt_state, loss[, aux])``
     where ``batch`` leaves are sharded on their leading axis across the
     communicator's data axes.
-
-    ``scan_steps=K`` (K > 1) runs K consecutive optimizer steps on the same
-    batch argument inside ONE XLA program via ``lax.scan`` and returns the
-    last step's loss/aux.  Each scan iteration is the full step (backward,
-    allreduce, update) — identical numerics to calling the step K times —
-    but the host dispatches once per K steps, which matters when per-call
-    dispatch overhead is comparable to the step itself.  Meant for
-    benchmarking / synthetic-data loops; real input pipelines feed a fresh
-    batch per step and use ``scan_steps=1``.
 
     ``accum_steps=K`` (K > 1) — gradient accumulation: each device splits
     its local batch shard into K equal microbatches, runs forward/backward
@@ -560,23 +528,6 @@ def make_train_step(
     if not with_model_state:
         def inner(params, opt_state, batch):  # noqa: F811
             return step(params, None, opt_state, batch)
-    if scan_steps > 1:
-        n_state = 3 if with_model_state else 2
-        base = inner
-
-        def inner(*args):  # noqa: F811
-            state, batch = args[:n_state], args[n_state]
-
-            def body(carry, _):
-                outs = base(*carry, batch)
-                return outs[:n_state], outs[n_state:]
-
-            state, tail = jax.lax.scan(
-                body, tuple(state), None, length=scan_steps)
-            # Report the LAST step's loss/aux: it depends (through the
-            # parameter chain) on every preceding step, so reading it to
-            # host is a fence over the whole scan.
-            return (*state, *jax.tree.map(lambda a: a[-1], tail))
     mapped = jax.shard_map(
         inner,
         mesh=comm.mesh,

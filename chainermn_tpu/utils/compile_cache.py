@@ -9,7 +9,7 @@ this module then sets nothing — or one fixed path inside the checkout.
 Never a temporary, pid- or time-stamped directory.
 
 Entry points that jit call :func:`place_compile_cache` before their
-first compile (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``).
+first compile (``chip_smoke.py``, ``benchmarks/*.py``).
 The library itself never calls it: importing ``chainermn_tpu`` writes
 nothing to disk, and ``tests/`` do not write there.
 """

@@ -1,7 +1,7 @@
 """TPU device metadata shared by the benchmarks.
 
 One table so every bench computes MFU against the same peak; a number
-corrected here propagates to bench.py, bench_vit.py and any future MFU
+corrected here propagates to bench_vit.py and any future MFU
 report at once (they used to carry private copies that could drift).
 """
 

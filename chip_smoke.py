@@ -31,8 +31,8 @@ import time
 import numpy as np
 
 # Full-width configurations.  ResNet-50: the source paper's flagship, all 50
-# layers, as bench.py runs it.  LM: benchmarks/bench_lm.py's widths with the
-# depth cut from 8 layers to 2 to keep the phase short.
+# layers, at the batch of the resnet50-b256 cell.  LM: a 2048-wide, 16-head
+# flash-attention model at T=8192, two layers deep to keep the phase short.
 FULL = {
     "resnet": {"depth": 50, "batch": 256, "image": 224, "classes": 1000,
                "warmup": 2, "steps": 5},
@@ -44,10 +44,10 @@ FULL = {
 }
 # --rehearse: same control flow, toy shapes, Pallas interpreted.  The LM takes
 # the unfused attention there: JAX's Pallas interpreter is not vma-aware, so a
-# kernel cannot be interpreted inside the step's shard_map (cast_scale gives
-# way to XLA there for the same reason).  The flash kernels are rehearsed by
-# the parity check, outside shard_map, and compiled for the described chip by
-# tools/compile_for_chip.py and tests/test_chip_compile.py.
+# kernel cannot be interpreted inside the step's shard_map.  The flash
+# kernels are rehearsed by the parity check, outside shard_map, and compiled
+# for the described chip by tools/compile_for_chip.py and
+# tests/test_chip_compile.py.
 TOY = {
     "resnet": {"depth": 6, "batch": 8, "image": 32, "classes": 10,
                "warmup": 2, "steps": 5},
@@ -60,8 +60,6 @@ TOY = {
 
 # flash forward + the two backward kernels, per layer
 FLASH_KERNELS_PER_LAYER = 3
-# cast_scale into the wire dtype and back, per packed dtype group
-CAST_KERNELS = 2
 # bf16 keeps 8 significant bits; outputs, probabilities and score gradients
 # are each rounded to it once, so a few 2^-8 steps relative to the largest
 # reference value is the expected gap to the float32 oracle.
@@ -134,16 +132,14 @@ def pallas_interpret_flags(jaxpr):
 
 
 def make_comm(devices=None):
-    """The flagship communicator: XLA collectives, bf16 gradient wire, the
-    Pallas cast+scale kernel on both sides of the all-reduce (so a kernel
-    compiled inside ``shard_map`` is in every step program)."""
+    """The flagship communicator, as every benchmark cell builds it: XLA
+    collectives, bf16 gradient wire."""
     import chainermn_tpu
     from chainermn_tpu.parallel.topology import init_topology
 
     topology = None if devices is None else init_topology(devices=devices)
     return chainermn_tpu.create_communicator(
-        "xla", topology=topology, allreduce_grad_dtype="bfloat16",
-        use_pallas_cast=True)
+        "xla", topology=topology, allreduce_grad_dtype="bfloat16")
 
 
 def build_resnet(comm, cfg, state_comm=None):
@@ -245,7 +241,7 @@ def _leaves_changed(before, after):
                                          jax.tree.leaves(b))]))(before, after))
 
 
-def compile_and_step(name, step, state, batch, *, warmup, steps, min_kernels,
+def compile_and_step(name, step, state, batch, *, warmup, steps, kernels,
                      on_chip, cache):
     """Compile ``step`` twice ahead of time (timed, the second time to see
     the persistent cache serve it; program text checked), then take
@@ -269,13 +265,13 @@ def compile_and_step(name, step, state, batch, *, warmup, steps, min_kernels,
         compiles.append((time.perf_counter() - t0, cache.verdict(mark)))
     (compile_s, first_compile), (second_s, second_compile) = compiles
     interpreted = pallas_interpret_flags(traced.jaxpr)
-    kernels = compiled.as_text().count("tpu_custom_call")
+    found = compiled.as_text().count("tpu_custom_call")
     if on_chip:
-        check(kernels >= min_kernels,
-              f"{name}: {kernels} tpu_custom_call in the compiled step, "
-              f"expected at least {min_kernels} — a Pallas kernel gave way "
-              "to XLA or to the interpreter")
-        check(interpreted and not any(interpreted),
+        check(found == kernels,
+              f"{name}: {found} tpu_custom_call in the compiled step, "
+              f"expected {kernels} — a Pallas kernel gave way to XLA or to "
+              "the interpreter, or one came that the step should not hold")
+        check(not any(interpreted),
               f"{name}: {sum(interpreted)} of {len(interpreted)} Pallas "
               "calls were traced in interpret mode")
     memory = compiled.memory_analysis()
@@ -315,7 +311,7 @@ def compile_and_step(name, step, state, batch, *, warmup, steps, min_kernels,
     return {
         "losses": [round(l, 5) for l in losses],
         "param_leaves_changed": f"{changed.sum()}/{changed.size}",
-        "tpu_custom_calls": kernels,
+        "tpu_custom_calls": found,
         "pallas_calls_interpreted": f"{sum(interpreted)}/{len(interpreted)}",
         "compile_s": round(compile_s, 2),
         "first_compile_cache": first_compile,
@@ -368,13 +364,11 @@ def phase_resnet50(sizes, on_chip, cache):
     step, state, batch = build_resnet(comm, cfg)
     record = compile_and_step(
         "resnet50", step, state, batch, warmup=cfg["warmup"],
-        steps=cfg["steps"], min_kernels=CAST_KERNELS, on_chip=on_chip,
-        cache=cache)
+        steps=cfg["steps"], kernels=0, on_chip=on_chip, cache=cache)
     emit(phase="resnet50",
          config=f"ResNet-{cfg['depth']} b={cfg['batch']} "
                 f"{cfg['image']}x{cfg['image']} bf16, xla communicator, bf16 "
-                f"gradient wire through the Pallas cast_scale kernel, "
-                f"double-buffered SGD",
+                f"gradient wire, double-buffered SGD",
          **record)
 
 
@@ -385,13 +379,12 @@ def phase_lm_flash(sizes, on_chip, cache):
     record = compile_and_step(
         "lm_flash", step, state, batch, warmup=cfg["warmup"],
         steps=cfg["steps"],
-        min_kernels=FLASH_KERNELS_PER_LAYER * cfg["n_layers"] + CAST_KERNELS,
+        kernels=FLASH_KERNELS_PER_LAYER * cfg["n_layers"],
         on_chip=on_chip, cache=cache)
     emit(phase="lm_flash",
          config=f"TransformerLM vocab={cfg['vocab']} d={cfg['d_model']} "
                 f"heads={cfg['n_heads']} T={cfg['seq']} b=1 bf16 "
-                f"attention={cfg['attention']}; "
-                f"depth cut to {cfg['n_layers']} layers (bench_lm.py runs 8)",
+                f"attention={cfg['attention']}, {cfg['n_layers']} layers",
          **record)
     emit(phase="flash_parity", **flash_parity(cfg, sizes["parity_seq"]))
 
@@ -535,10 +528,10 @@ def check_placement(comm, cfg, step, state, batch, on_chip):
           f"compiled step (group widths seen: {widths})")
     kernels = text.count("tpu_custom_call")
     if on_chip:
-        want = FLASH_KERNELS_PER_LAYER * cfg["n_layers"] + CAST_KERNELS
-        check(kernels >= want,
+        want = FLASH_KERNELS_PER_LAYER * cfg["n_layers"]
+        check(kernels == want,
               f"{kernels} tpu_custom_call in the {n}-chip step, expected "
-              f"at least {want}")
+              f"{want}")
     return {"batch_shard_devices": n, "params_replicated_on": n,
             "reduce_group_widths": widths, "reduces": len(reduces),
             "tpu_custom_calls": kernels}

@@ -23,13 +23,9 @@ import pytest  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from chainermn_tpu.ops.cast_scale import cast_scale  # noqa: E402
 from chainermn_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from chainermn_tpu.ops.fused_norm import fused_norm  # noqa: E402
 from chainermn_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
-
-# ResNet-50's packed float32 gradient buffer: 25.5M elements
-RESNET50_PARAMS = 25_557_032
 
 
 @pytest.fixture(scope="module")
@@ -78,8 +74,6 @@ def _expert_grad(rows, up, down, sizes):
             grouped_matmul(r, u, sizes), d, sizes).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))(rows, up, down)
 
-_GRADS = ((RESNET50_PARAMS,), jnp.float32)
-
 
 def _norm_args(shape):
     return [(shape, jnp.bfloat16), ((shape[-1],), jnp.float32),
@@ -98,8 +92,6 @@ CASES = {
     "grouped_matmul_fwd_bwd": (
         # the second forward is dead under a sum: 1 + 2 x (dlhs, drhs)
         _expert_grad, [_ROWS, _EXPERTS_UP, _EXPERTS_DOWN, _GROUPS], 5),
-    "cast_scale_resnet50_grads": (
-        lambda g: cast_scale(g, jnp.bfloat16, 0.25), [_GRADS], 1),
     "fused_norm_fwd_stage1": (
         lambda *a: fused_norm(*a)[0], _norm_args((256, 112, 112, 64)), 2),
     "fused_norm_fwd_bwd_stage1": (
@@ -129,22 +121,6 @@ def test_kernel_compiles_for_described_v5e(topo, name):
         f"compiled program, expected at least {want}")
 
 
-def test_cast_scale_compiles_inside_four_chip_shard_map(topo):
-    """The gradient-wire kernel where the train step runs it: inside
-    ``shard_map`` over four chips, on device-varying buffers."""
-    mesh = Mesh(topo.devices, ("d",))
-    sharded = NamedSharding(mesh, P("d"))
-    grads = jax.ShapeDtypeStruct((4 * RESNET50_PARAMS,), jnp.float32,
-                                 sharding=sharded)
-
-    def wire(g):
-        return jax.shard_map(
-            lambda v: cast_scale(v, jnp.bfloat16, 0.25), mesh=mesh,
-            in_specs=P("d"), out_specs=P("d"))(g)
-
-    assert "tpu_custom_call" in _compile(wire, [grads])
-
-
 def test_grouped_matmul_compiles_inside_shard_map(topo):
     """Where the train step runs it: inside ``shard_map`` with varying
     axes checked, on device-varying rows, weights and group sizes (the
@@ -164,32 +140,62 @@ def test_grouped_matmul_compiles_inside_shard_map(topo):
     assert _compile(step, shapes).count("tpu_custom_call") >= 5
 
 
-def _exchange_program(topo, body_name):
-    """A double-buffered step's exchange, stood in for at small widths: the
-    f32 ``pending`` gradients of four chips through the bf16-wire
-    ``allreduce_grad`` into an SGD-momentum update."""
+def _small_tree(leaf):
+    return {"emb": leaf(4096, 512), "up": leaf(512, 2048),
+            "down": leaf(2048, 512), "qkv": leaf(512, 640),
+            "ln": [leaf(512) for _ in range(6)], "bias": leaf(2048)}
+
+
+def _resnet50_tree(leaf):
+    """ResNet-50's real gradient tree: 161 leaves, 25.6 M elements."""
+    from chainermn_tpu.models import ResNet50
+
+    params = jax.eval_shape(
+        ResNet50().init, jax.random.key(0),
+        jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32))["params"]
+    tree = jax.tree.map(lambda p: leaf(*p.shape), params)
+    sizes = [p.size for p in jax.tree.leaves(params)]
+    assert len(sizes) == 161 and sum(sizes) == 25_557_032
+    return tree
+
+
+def _exchange_program(topo, body_name, make_tree=_small_tree, chips=4):
+    """A double-buffered step's exchange: the f32 ``pending`` gradients of
+    ``chips`` chips through the bf16-wire ``allreduce_grad`` into an
+    SGD-momentum update."""
     import chainermn_tpu
     from chainermn_tpu.parallel.topology import init_topology
 
     comm = chainermn_tpu.create_communicator(
-        "xla", topology=init_topology(devices=list(topo.devices)),
+        "xla", topology=init_topology(devices=list(topo.devices)[:chips]),
         allreduce_grad_dtype="bfloat16")
-    assert comm.size == 4
+    assert comm.size == chips
     stacked = NamedSharding(comm.mesh, P(comm.data_axes))
 
     def leaf(*shape):
         return jax.ShapeDtypeStruct((comm.size,) + shape, jnp.float32,
                                     sharding=stacked)
 
-    tree = {"emb": leaf(4096, 512), "up": leaf(512, 2048),
-            "down": leaf(2048, 512), "qkv": leaf(512, 640),
-            "ln": [leaf(512) for _ in range(6)], "bias": leaf(2048)}
+    tree = make_tree(leaf)
 
     def step(pending, momentum):
         mean = getattr(comm, body_name)(pending)
         return jax.tree.map(lambda m, g: 0.9 * m + g, momentum, mean)
 
     return comm._spmd_program(step).lower((tree, tree)).compile().as_text()
+
+
+def _instructions(text, *ops):
+    pattern = re.compile(r"= .*\b(%s)\(" % "|".join(map(re.escape, ops)))
+    return [line for line in text.splitlines() if pattern.search(line)]
+
+
+def _wire_all_reduces(text):
+    """The all-reduces of a compiled exchange, each over the bf16 wire."""
+    reduced = _instructions(text, "all-reduce", "all-reduce-start")
+    assert all("bf16[" in line.split(" all-reduce")[0]
+               for line in reduced), reduced
+    return reduced
 
 
 @pytest.mark.parametrize("body,packs", [
@@ -203,20 +209,33 @@ def test_four_chip_exchange_builds_no_buffer(topo, body, packs):
     under ``chainermn.pack``: on the chip those cost more than the
     all-reduce they served (PERF.md, PR 25)."""
     text = _exchange_program(topo, body)
-    lines = text.splitlines()
-    gathers = [line for line in lines
-               if re.search(r"= .*\bdynamic-update-slice\(", line)]
-    pack_copies = [line for line in lines
-                   if re.search(r"= .*\bcopy\(", line)
-                   and "chainermn.pack" in line]
+    gathers = _instructions(text, "dynamic-update-slice")
+    pack_copies = [line for line in _instructions(text, "copy")
+                   if "chainermn.pack" in line]
     assert bool(gathers or pack_copies) == packs, (gathers, pack_copies)
-    reduced = [line for line in lines
-               if re.search(r"= .*\ball-reduce(-start)?\(", line)]
-    assert reduced and all("bf16[" in line.split(" all-reduce")[0]
-                           for line in reduced), reduced
+    assert "tpu_custom_call" not in text
     # the combiner, not a buffer, merges the leaves: fewer operations than
     # leaves (every small vector rides with a matrix)
-    assert len(reduced) < 11
+    assert 0 < len(_wire_all_reduces(text)) < 11
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_resnet50_exchange_is_xla_alone(topo, chips):
+    """The exchange of the ``resnet50-b256`` cell's model over its real
+    gradient tree.  Four described chips: the casts are XLA's fusions (no
+    ``tpu_custom_call``), no leaf is gathered into a buffer, and the
+    combiner leaves fewer all-reduces than leaves.  One described chip,
+    the cell itself: no collective at all."""
+    text = _exchange_program(topo, "allreduce_grad", _resnet50_tree, chips)
+    assert "tpu_custom_call" not in text
+    assert not _instructions(text, "dynamic-update-slice")
+    reduced = _wire_all_reduces(text)
+    if chips == 1:
+        assert not reduced and not _instructions(
+            text, "all-gather", "reduce-scatter", "collective-permute",
+            "all-to-all"), reduced
+    else:
+        assert 0 < len(reduced) < 161, len(reduced)
 
 
 def test_moe_layer_main_pass_kernels_keep_their_names(topo):

@@ -72,6 +72,17 @@ class TestFactory:
         # reference name maps onto the TPU data plane
         assert isinstance(make_comm("pure_nccl"), XlaCommunicator)
 
+    def test_fixed_plan_flavors_share_one_lowering(self):
+        """Every fixed-plan flavor reaches the device through the base's
+        ``execute_plan(self.plan(), ...)``: none carries a lowering of its
+        own, and ``xla`` is its class attributes and the parity reference."""
+        for cls in (NaiveCommunicator, FlatCommunicator,
+                    HierarchicalCommunicator, TwoDimensionalCommunicator,
+                    SingleNodeCommunicator, NonCudaAwareCommunicator,
+                    XlaCommunicator):
+            assert "_allreduce_grad_traced" not in vars(cls), cls
+        assert "__init__" not in vars(XlaCommunicator)
+
     def test_unknown_name(self):
         with pytest.raises(ValueError, match="unknown communicator"):
             create_communicator("bogus")
@@ -139,6 +150,37 @@ class TestAllreduceGrad:
         for leaf in jax.tree.leaves(out):
             assert leaf.dtype == jnp.float32  # dtype restored
             np.testing.assert_allclose(np.asarray(leaf), 3.5, rtol=2e-2)
+
+    @pytest.mark.parametrize("shape,leaf_dtype,wire", [
+        # under, at and over a lane, a ragged length, more than one tile
+        ((1,), "float32", "bfloat16"),
+        ((127,), "float32", "bfloat16"),
+        ((128,), "float32", "bfloat16"),
+        ((1000,), "float32", "bfloat16"),
+        ((33000,), "float32", "bfloat16"),
+        ((13, 17), "float32", "bfloat16"),
+        # no wire dtype: nothing is cast and the mean is exact
+        ((37,), "float32", None),
+        # the cast-back leg: a half leaf comes back half, scaled by 1/size
+        ((256,), "bfloat16", "bfloat16"),
+    ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v)
+    def test_wire_cast_values(self, shape, leaf_dtype, wire):
+        """The wire cast of the path every benchmark cell runs (``xla``,
+        XLA's own fusions on both sides of the all-reduce): values, shape
+        and dtype of one leaf through ``allreduce_grad``."""
+        c = make_comm("xla", allreduce_grad_dtype=wire)
+        n = int(np.prod(shape))
+        x = np.linspace(-3, 3, n, dtype=np.float32).reshape(shape)
+        ranks = np.arange(1, c.size + 1, dtype=np.float32).reshape(
+            (c.size,) + (1,) * len(shape))
+        grads = {"w": jnp.asarray(ranks * x, leaf_dtype)}
+        out = c.run_spmd(lambda g: c.allreduce_grad(g), grads)["w"]
+        assert out.shape == (c.size,) + shape
+        assert out.dtype == jnp.dtype(leaf_dtype)
+        want = np.broadcast_to(x * (c.size + 1) / 2.0, out.shape)
+        tol = 1e-6 if wire is None else 2e-2
+        np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                                   rtol=tol, atol=tol)
 
     def test_eager_is_identity_for_global_grads(self):
         # Single-controller eager mode: grads are already globally averaged.

@@ -1,8 +1,8 @@
 """Gradient-compression subsystem tests — resolver/spec round-trips,
 NoCompression bit-exactness against the raw wire-dtype paths (allreduce
 and bucketed FSDP), int8/fp8 error-feedback convergence, the optimizer
-seam (deprecation shim, rejected combinations, per-hop compressed
-plans), checkpoint config guards (incl. the per-hop ``hops`` sidecar),
+seam (the raw wire dtype's two spellings, rejected combinations, per-hop
+compressed plans), checkpoint config guards (incl. the per-hop ``hops`` sidecar),
 the compression_* observability family, and the bench census as a
 subprocess (chainermn_tpu/compression/ + the three seams)."""
 
@@ -10,6 +10,7 @@ import json
 import os
 import subprocess
 import sys
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -280,7 +281,9 @@ class TestOptimizerSeam:
         c_knob = chainermn_tpu.create_communicator(
             "xla", intra_size=4, allreduce_grad_dtype="bfloat16")
         c_plain = chainermn_tpu.create_communicator("xla", intra_size=4)
-        with pytest.deprecated_call():
+        with warnings.catch_warnings():
+            # the reference's own spelling is not deprecated
+            warnings.simplefilter("error")
             opt_knob = chainermn_tpu.create_multi_node_optimizer(
                 optax.adam(1e-2), c_knob)
         opt_comp = chainermn_tpu.create_multi_node_optimizer(
@@ -292,11 +295,30 @@ class TestOptimizerSeam:
         for a, b in zip(jax.tree.leaves(p_knob), jax.tree.leaves(p_comp)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_raw_dtype_knob_deprecation_names_replacement(self):
-        c = chainermn_tpu.create_communicator(
+    def test_raw_dtype_knob_lowers_to_the_codec_program(self):
+        """``allreduce_grad_dtype`` on the communicator (the reference's
+        spelling, what every benchmark cell passes) warns of nothing and
+        lowers to the program ``NoCompression(wire_dtype=...)`` lowers
+        to."""
+        c_knob = chainermn_tpu.create_communicator(
             "xla", intra_size=4, allreduce_grad_dtype="bfloat16")
-        with pytest.warns(DeprecationWarning, match="NoCompression"):
-            chainermn_tpu.create_multi_node_optimizer(optax.adam(1e-3), c)
+        c_plain = chainermn_tpu.create_communicator("xla", intra_size=4)
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            opt_knob = chainermn_tpu.create_multi_node_optimizer(
+                optax.sgd(1e-2, momentum=0.9), c_knob)
+        opt_comp = chainermn_tpu.create_multi_node_optimizer(
+            optax.sgd(1e-2, momentum=0.9), c_plain,
+            compression=NoCompression(wire_dtype="bfloat16"))
+        texts = []
+        for c, opt in ((c_knob, opt_knob), (c_plain, opt_comp)):
+            params, loss_fn, data = _mlp_problem(c)
+            step = make_train_step(c, loss_fn, opt)
+            texts.append(step.lower(
+                params, init_opt_state(c, opt, params),
+                put_global_batch(c, data)).as_text())
+        assert "xbf16>" in texts[0]
+        assert texts[0] == texts[1]
 
     def test_int8_trains_close_to_uncompressed(self, comm):
         l_base, _ = self._train(

@@ -235,16 +235,6 @@ def test_long_context_fsdp_matches_replicated():
 
 
 @pytest.mark.slow
-def test_bench_lm_contract():
-    """bench_lm.py emits its one-JSON-line contract on any backend."""
-    import json
-
-    stdout = _run("bench_lm.py", base="benchmarks")
-    out = json.loads(stdout.strip().splitlines()[-1])
-    assert out["unit"] == "tokens/sec/chip" and out["value"] > 0
-
-
-@pytest.mark.slow
 def test_imagenet_fsdp_matches_plain_dp(tmp_path):
     """--fsdp (ZeRO-3 through the stock Trainer stack, FsdpUpdater)
     reproduces the plain-DP run: same seed, same final metrics."""

@@ -60,53 +60,6 @@ class TestForwardShapes:
         n_params = sum(x.size for x in jax.tree.leaves(variables["params"]))
         assert 14e6 < n_params < 16e6, f"VGG-16/CIFAR ~15M params, got {n_params}"
 
-    def test_s2d_stem_equivalence_class(self):
-        """The s2d stem is the standard TPU MLPerf input transform: a
-        4x4/s1 conv over the space-to-depth view.  Its weight space contains
-        every zero-padded-to-8x8 7x7/s2 stem exactly: loading such weights
-        must reproduce the conv7 stem's output bit-for-bit."""
-        from chainermn_tpu.models.resnet import space_to_depth
-        import flax.linen as nn
-
-        x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
-        conv7 = nn.Conv(8, (7, 7), (2, 2), padding="SAME", use_bias=False)
-        v7 = conv7.init(jax.random.key(2), x)
-        y7 = conv7.apply(v7, x)
-
-        # Re-express those weights as 4x4x12 s2d weights.  conv7 SAME pad
-        # for k=7,s=2 on 32 -> pad (2, 3); the s2d 4x4/s1 SAME pad on 16 is
-        # (1, 2) s2d pixels = (2, 4) original pixels, so embed the 7-tap
-        # kernel at offset 0 of an 8-tap zero-padded kernel.
-        w7 = v7["params"]["kernel"]  # (7, 7, 3, 8)
-        w8 = jnp.zeros((8, 8, 3, 8)).at[:7, :7].set(w7)
-        # (8,8,3,O) -> s2d taps: tap (i,j) of the 4x4 kernel sees original
-        # pixels (2i+di, 2j+dj), channel layout of space_to_depth is
-        # (di, dj, c) flattened.
-        w_s2d = w8.reshape(4, 2, 4, 2, 3, 8).transpose(0, 2, 1, 3, 4, 5)
-        w_s2d = w_s2d.reshape(4, 4, 12, 8)
-        conv4 = nn.Conv(8, (4, 4), padding="SAME", use_bias=False)
-        y4 = conv4.apply({"params": {"kernel": w_s2d}}, space_to_depth(x, 2))
-        np.testing.assert_allclose(np.asarray(y4), np.asarray(y7),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_s2d_stem_trains(self, comm):
-        model = TinyResNet(stem="s2d")
-        variables = model.init(jax.random.key(0), jnp.zeros((2, 32, 32, 3)))
-        assert variables["params"]["conv_init"]["kernel"].shape == (4, 4, 12, 8)
-        logits, _ = model.apply(variables, jnp.ones((2, 32, 32, 3)),
-                                train=True, mutable=["batch_stats"])
-        assert logits.shape == (2, 5)
-        # trains a step through the full multi-node path
-        params, model_state, opt_state, step = build_state_training(
-            comm, model, (32, 32, 3))
-        x = jax.random.normal(jax.random.key(3), (comm.size * 2, 32, 32, 3))
-        y = jnp.zeros((comm.size * 2,), jnp.int32)
-        from chainermn_tpu.training import put_global_batch
-        batch = put_global_batch(comm, (np.asarray(x), np.asarray(y)))
-        params, model_state, opt_state, loss = step(
-            params, model_state, opt_state, batch)
-        assert np.isfinite(float(loss))
-
     def test_bf16_compute_fp32_params(self):
         model = TinyResNet(dtype=jnp.bfloat16)
         variables = model.init(jax.random.key(0), jnp.zeros((2, 32, 32, 3)))

@@ -161,56 +161,19 @@ class TestDoubleBuffering:
         assert not leaf.sharding.is_fully_replicated
 
 
-class TestScanSteps:
-    """``scan_steps=K`` fuses K steps into one dispatch with identical
-    numerics to K sequential calls (the bench.py dispatch-amortization
-    path)."""
-
-    @pytest.mark.parametrize("double_buffering", [False, True])
-    def test_scan_matches_sequential(self, comm, double_buffering):
-        def make(scan_steps):
-            opt = chainermn_tpu.create_multi_node_optimizer(
-                optax.adam(0.05), comm, double_buffering=double_buffering)
-            params = {"w": jnp.zeros((3,))}
-            state = init_opt_state(comm, opt, params)
-            step = make_train_step(comm, quad_loss, opt, donate=False,
-                                   scan_steps=scan_steps)
-            return params, state, step
-
-        targets = jnp.arange(comm.size, dtype=jnp.float32).reshape(
-            comm.size, 1) * jnp.ones((comm.size, 3))
-        batch = (targets,)
-
-        params_a, state_a, step_a = make(1)
-        for _ in range(4):
-            params_a, state_a, loss_a = step_a(params_a, state_a, batch)
-
-        params_b, state_b, step_b = make(4)
-        params_b, state_b, loss_b = step_b(params_b, state_b, batch)
-
-        np.testing.assert_allclose(np.asarray(params_b["w"]),
-                                   np.asarray(params_a["w"]), rtol=1e-6)
-        # loss reported is the LAST scan iteration's (computed on the
-        # params entering step 4) — identical to the sequential 4th loss.
-        np.testing.assert_allclose(float(loss_b), float(loss_a), rtol=1e-6)
-
-    def test_scan_with_model_state(self, comm):
-        """model_state (local-BN analogue) is carried through the scan."""
-        def loss_fn(params, state, batch):
-            (x,) = batch
-            loss = 0.5 * jnp.sum((params["w"] - x.mean(axis=0)) ** 2)
-            return loss, {"count": state["count"] + 1}
-
-        opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(0.1), comm)
-        params = {"w": jnp.zeros((2,))}
-        from chainermn_tpu.optimizers import init_model_state
-        mstate = init_model_state(comm, {"count": jnp.zeros(())})
-        state = init_opt_state(comm, opt, params)
-        step = make_train_step(comm, loss_fn, opt, donate=False,
-                               with_model_state=True, scan_steps=3)
-        batch = (jnp.ones((comm.size, 2)),)
-        params, mstate, state, loss = step(params, mstate, state, batch)
-        np.testing.assert_allclose(np.asarray(mstate["count"]), 3.0)
+@pytest.mark.parametrize("build", [
+    lambda comm, opt: chainermn_tpu.create_communicator(
+        "xla", intra_size=4, use_pallas_cast=True),
+    lambda comm, opt: make_train_step(comm, quad_loss, opt, scan_steps=2),
+    lambda comm, opt: chainermn_tpu.models.ResNet50(stem="s2d"),
+], ids=["use_pallas_cast", "scan_steps", "stem"])
+def test_removed_options_are_rejected(comm, build):
+    """The exchange has one lowering, the step one loop and ResNet one
+    stem (PERF.md, PR 28): the options that selected another are gone,
+    not ignored."""
+    opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(0.1), comm)
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        build(comm, opt)
 
 
 class TestConvergence:
@@ -433,26 +396,6 @@ class TestAccumSteps:
         params, mstate, state, loss = step(params, mstate, state,
                                            self._batch(comm))
         np.testing.assert_allclose(np.asarray(mstate["count"]), 4.0)
-
-    def test_accum_composes_with_scan(self, comm):
-        """scan_steps=J outer x accum_steps=K inner — both knobs at once."""
-        def make(scan_steps, accum_steps):
-            opt = chainermn_tpu.create_multi_node_optimizer(
-                optax.adam(0.05), comm)
-            params = {"w": jnp.zeros((3,))}
-            state = init_opt_state(comm, opt, params)
-            return params, state, make_train_step(
-                comm, sample_mean_loss, opt, donate=False,
-                scan_steps=scan_steps, accum_steps=accum_steps)
-
-        batch = self._batch(comm)
-        pa, sa, step_a = make(1, 1)
-        for _ in range(2):
-            pa, sa, loss_a = step_a(pa, sa, batch)
-        pb, sb, step_b = make(2, 2)
-        pb, sb, loss_b = step_b(pb, sb, batch)
-        np.testing.assert_allclose(np.asarray(pb["w"]), np.asarray(pa["w"]),
-                                   rtol=1e-6, atol=1e-7)
 
     def test_bad_accum_rejected(self, comm):
         opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(0.1), comm)

@@ -110,17 +110,9 @@ run 1 "$OUT/CONVERGENCE_$ROUND.json" \
     "convergence ledger ON THE CHIP (bf16 numerics are the point)" -- \
     $PY_TPU tools/convergence_ledger.py --out "$OUT/CONVERGENCE_$ROUND.json"
 
-run 1 "$OUT/BENCH_$ROUND.json" \
-    "headline ResNet-50 bench (driver-official format)" -- \
-    bash -c "$PY_TPU bench.py > '$OUT/BENCH_$ROUND.json'"
-
 run 1 "$OUT/VIT_BENCH_$ROUND.json" \
-    "ViT-B/16 bench (the MXU compute-ceiling companion to the ResNet headline)" -- \
+    "ViT-B/16 bench (a dense-matmul model beside the benchmark's cells: python3 -m chipbench.run)" -- \
     bash -c "$PY_TPU benchmarks/bench_vit.py > '$OUT/VIT_BENCH_$ROUND.json'"
-
-run 1 "$OUT/LM_BENCH_$ROUND.json" \
-    "Transformer-LM bench (554M params, T=8192, flash kernels - the 52% MFU panel)" -- \
-    bash -c "$PY_TPU benchmarks/bench_lm.py > '$OUT/LM_BENCH_$ROUND.json'"
 
 # ---- serving: continuous-batching inference engine --------------------
 # Hardware-free (forced CPU mesh) so the serving stack is exercised on

@@ -2,9 +2,8 @@
 """Render an observability metrics JSONL into human-readable tables.
 
 Reads the one-record-per-line file the runtime sinks write — the
-``MetricsReport`` extension (``<out>/metrics.jsonl``), ``bench.py
---metrics`` and ``benchmarks/bench_allreduce.py --metrics`` all share the
-schema — and prints:
+``MetricsReport`` extension (``<out>/metrics.jsonl``) and
+``benchmarks/bench_allreduce.py --metrics`` share the schema — and prints:
 
 * per-collective summary   (calls / payload bytes / host latency, from
                             ``comm_collective_*`` metric lines);

@@ -12,7 +12,6 @@ is the wider, slower sweep around it:
   * grouped-query + rectangular (Tq=2048 / Tkv=8192, 8q/2kv heads)
     fwd+bwd parity;
   * flash fwd throughput at T=32768 (device-time TFLOP/s);
-  * the Pallas cast_scale kernel vs astype*scale;
   * the full bf16 double-buffered train step per communicator flavor.
 
 A check that fails is recorded and the suite goes on to the next (it is a
@@ -210,23 +209,6 @@ def check_flash_train_T64k(T=65536):
             "master_dtype": "float32"}
 
 
-def check_cast_scale():
-    import jax
-    import jax.numpy as jnp
-
-    from chainermn_tpu.ops.cast_scale import cast_scale
-
-    rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(1 << 20) * 100, jnp.float32)
-    out = jax.jit(lambda a: cast_scale(a, jnp.bfloat16, 0.125))(x)
-    ref = (x * 0.125).astype(jnp.bfloat16)
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
-                                - ref.astype(jnp.float32))))
-    assert out.dtype == jnp.bfloat16
-    assert err <= 2e-2, f"cast_scale mismatch {err}"
-    return {"n": int(x.size), "max_err": err}
-
-
 def check_train_step_flavors():
     import jax
     import jax.numpy as jnp
@@ -406,7 +388,6 @@ CHECKS = [
     ("flash_bwd_T32k", check_flash_bwd_throughput),
     ("flash_train_T64k", check_flash_train_T64k),
     ("flash_train_T256k", check_flash_train_T256k),
-    ("cast_scale", check_cast_scale),
     ("train_step_flavors", check_train_step_flavors),
     ("fsdp_vit_step", check_fsdp_vit_step),
 ]
