@@ -27,6 +27,7 @@ from chainermn_tpu.analysis.captured import (
 from chainermn_tpu.analysis.hlo import (
     HloCollective,
     HloParse,
+    all_reduce_overlap_census,
     collective_census,
     parse_hlo_collectives,
 )
@@ -64,8 +65,9 @@ __all__ = [
     "COLLECTIVE_PRIMITIVES", "CallSite", "CapturedConstantError",
     "CollectiveOp", "CollectiveSchedule", "DEFAULT_MAX_BYTES",
     "Finding", "HloCollective", "HloParse",
-    "LintContext", "LintError", "LintReport", "ProtocolModel", "all_rules",
-    "allreduce_hlo", "assert_no_captured_constants", "build_grad_probe",
+    "LintContext", "LintError", "LintReport", "ProtocolModel",
+    "all_reduce_overlap_census", "all_rules", "allreduce_hlo",
+    "assert_no_captured_constants", "build_grad_probe",
     "collective_census", "expected_kinds", "extract_protocol",
     "extract_schedule", "find_captured_constants", "get_rule",
     "lint_step", "load_events_by_rank", "parse_hlo_collectives",

@@ -23,6 +23,13 @@ Two HLO renderings the naive one-regex-per-line approach missed:
   ``async-pair`` lint rule turns those into error findings, because an
   unmatched start in a schedule is exactly the shape of program the
   runtime hang watchdog ends up diagnosing on-mesh.
+* **asynchronous-collective fusions** — the TPU compiler's own
+  asynchronous form (``xla_tpu_enable_async_collective_fusion``) prints
+  ONE collective several times: in the computation that an
+  ``async-collective-start.N`` instruction calls, in that of every
+  ``async_collective_fusion.N`` step that carries it beside other work,
+  and in that of its ``async-collective-done.N``.  It is counted once, at
+  the start, and marked asynchronous.
 """
 
 from __future__ import annotations
@@ -48,10 +55,12 @@ COLLECTIVE_KINDS = (
 _ASYNC_START = tuple(k + "-start" for k in COLLECTIVE_KINDS)
 _ASYNC_DONE = tuple(k + "-done" for k in COLLECTIVE_KINDS)
 
-# name = shape op(...) — the shape is either a tuple (...) or one token
+# name = shape op(...) — the shape is either a tuple (...) or one token; a
+# TPU layout holds parentheses of its own (``{1,0:T(8,128)(2,1)}``), so a
+# tuple ends where the op begins
 _OP_RE = re.compile(
     r"(?P<name>%[\w.\-]+|[\w.\-]+)\s*=\s*"
-    r"(?P<shape>\([^)]*\)|\S+)\s+"
+    r"(?P<shape>\(.*?\)|\S+)\s+"
     r"(?P<op>" + "|".join(
         re.escape(k) + "(?:-start|-done)?" for k in COLLECTIVE_KINDS)
     + r")\(")
@@ -60,7 +69,7 @@ _OP_RE = re.compile(
 # renders bindings with a SPACED " = " while instruction attributes
 # (replica_groups=..., to_apply=...) use an unspaced "=" — that spacing
 # is what separates a wrapped attribute line from a fresh binding
-_BINDING_RE = re.compile(r"^\s*(?:ROOT\s+)?(?:%[\w.\-]+|[\w.\-]+)\s+=\s")
+_BINDING_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s+=\s")
 # computation headers / module lines never continue an instruction
 _HEADER_RE = re.compile(
     r"^\s*(?:HloModule\b|ENTRY\b|%?[\w.\-]+\s*(?:\([^)]*\))?\s*->|\}|\{)")
@@ -146,6 +155,24 @@ def _first_operand(line: str) -> Optional[str]:
     return m.group(1).lstrip("%") if m else None
 
 
+_COMPUTATION_RE = re.compile(
+    r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _callers(lines: List[str]) -> Dict[str, str]:
+    """``{computation: the instruction that calls it}`` over a program's
+    fusions (``calls=``); a fused computation has one caller."""
+    callers: Dict[str, str] = {}
+    for line in lines:
+        if "calls=" not in line:
+            continue
+        caller = _BINDING_RE.match(line)
+        for computation in _CALLS_RE.findall(line):
+            callers[computation] = caller.group(1) if caller else ""
+    return callers
+
+
 def parse_hlo_collectives(hlo_text: str) -> HloParse:
     """Parse every collective out of optimized HLO text.
 
@@ -158,11 +185,25 @@ def parse_hlo_collectives(hlo_text: str) -> HloParse:
     parse = HloParse()
     pending_starts: Dict[str, HloCollective] = {}
     pending_order: List[str] = []
-    for i, line in enumerate(_logical_lines(hlo_text)):
+    lines = _logical_lines(hlo_text)
+    callers = _callers(lines)
+    owner = ""
+    for i, line in enumerate(lines):
+        header = _COMPUTATION_RE.match(line)
+        if header:
+            owner = header.group(1)
+            continue
         m = _OP_RE.search(line)
         if not m:
             continue
         opname = m.group("op")
+        # a collective inside an asynchronous-collective fusion chain is
+        # printed in every link: count it at the start's
+        caller = callers.get(owner, "")
+        if (owner.startswith("async_collective_fusion")
+                or caller.startswith("async-collective-done")):
+            continue
+        in_chain = caller.startswith("async-collective-start")
         name = m.group("name").lstrip("%")
         nbytes, dtype = _shape_payload(m.group("shape"))
         gm = _GROUPS_RE.search(line)
@@ -196,7 +237,7 @@ def parse_hlo_collectives(hlo_text: str) -> HloParse:
             continue
         parse.ops.append(HloCollective(
             op=opname, nbytes=nbytes, dtype=dtype, groups=groups,
-            name=name, line=i))
+            name=name, is_async=in_chain, line=i))
     for name in pending_order:
         rec = pending_starts[name]
         parse.problems.append({"kind": "unmatched-async-start",
@@ -212,5 +253,27 @@ def collective_census(hlo_text: str) -> List[dict]:
     return [o.as_census_dict() for o in parse_hlo_collectives(hlo_text).ops]
 
 
+def all_reduce_overlap_census(hlo_text: str) -> dict:
+    """How many of a compiled program's all-reduces block the device and
+    how many run beside other work, with the bytes each kind reduces — the
+    engagement counter of ``MeshCommunicator.exchange_compiler_options``
+    (0 % asynchronous under TPU XLA's defaults).  Asynchronous is a
+    start/done pair or an asynchronous-collective fusion chain; any other
+    all-reduce, a variadic one included, blocks.  Bytes are the result
+    shape's, which for an all-reduce is what crosses the wire once."""
+    census = {"synchronous": 0, "asynchronous": 0,
+              "synchronous_bytes": 0, "asynchronous_bytes": 0}
+    for op in parse_hlo_collectives(hlo_text).ops:
+        if op.op == "all-reduce":
+            kind = "asynchronous" if op.is_async else "synchronous"
+            census[kind] += 1
+            census[kind + "_bytes"] += op.nbytes
+    total = census["synchronous_bytes"] + census["asynchronous_bytes"]
+    census["asynchronous_byte_share"] = (
+        census["asynchronous_bytes"] / total if total else 0.0)
+    return census
+
+
 __all__ = ["HLO_DTYPE_BYTES", "COLLECTIVE_KINDS", "HloCollective",
-           "HloParse", "parse_hlo_collectives", "collective_census"]
+           "HloParse", "parse_hlo_collectives", "collective_census",
+           "all_reduce_overlap_census"]

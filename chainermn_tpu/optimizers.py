@@ -15,11 +15,11 @@ TPU-native design: the wrapped object is an **optax GradientTransformation**
 allreduces the gradients stored from the previous step and stashes the fresh
 local gradients for the next one.  Inside the jitted train step the psum of
 the stale gradients has no data dependency on the current forward/backward,
-so XLA's latency-hiding scheduler is free to overlap the collective with
-compute — the very overlap the reference engineered with a side stream, here
-obtained from the compiler.  The 1-step-staleness semantics (first update
-applies zero gradients) are preserved exactly, because they are what changes
-convergence (SURVEY.md §3.4).
+so the compiler is free to run the collective beside compute.  TPU XLA does
+so only when told: on a TPU mesh of several devices the step is compiled with
+the communicator's ``exchange_compiler_options`` (PERF.md, PR 29: what that
+hides and what it does not).  The 1-step-staleness semantics (first update
+applies zero gradients) are what changes convergence, and are kept exactly.
 """
 
 from __future__ import annotations
@@ -124,8 +124,8 @@ class _DoubleBufferingOptimizer:
     Semantics (reference 〔optimizers.py〕, SURVEY.md §3.4): update at step t
     applies the allreduced gradients of step t-1 (1-step staleness); step 0
     applies zero gradients (buffers start zero-filled).  The allreduce of the
-    pending buffer is independent of step t's forward/backward, which is what
-    lets the collective overlap compute under XLA's scheduler.
+    pending buffer depends on nothing step t computes; whether anything runs
+    beside it is the compiler's: ``comm.exchange_compiler_options()`` (TPU).
     """
 
     def __init__(self, actual_optimizer: optax.GradientTransformation, comm):
@@ -535,7 +535,13 @@ def make_train_step(
         out_specs=out_specs,
     )
     donate_argnums = ((0, 1, 2) if with_model_state else (0, 1)) if donate else ()
-    return jax.jit(mapped, donate_argnums=donate_argnums)
+    # An optimizer that exchanges gradients has the communicator say how the
+    # step is compiled (asynchronous all-reduces on a TPU mesh of several
+    # devices; None, XLA's defaults, everywhere else).
+    options = (comm.exchange_compiler_options()
+               if hasattr(optimizer, "communicator") else None)
+    return jax.jit(mapped, donate_argnums=donate_argnums,
+                   compiler_options=options)
 
 
 class PerStageOptimizer:

@@ -69,6 +69,10 @@ PARITY_TOL = 3e-2
 # in a different order.  On the chip the losses agreed to 6e-6 relative, and
 # one SGD step moves them by 1e-3.
 DP_LOSS_RTOL = 2e-4
+# the share of a four-chip step's all-reduced bytes that the compiler made
+# asynchronous (``comm.exchange_compiler_options``): every matrix goes alone
+# and is taken, the small vectors ride together in one that blocks
+ASYNC_BYTE_SHARE_MIN = 0.75
 
 FLAVORS = ("naive", "flat", "hierarchical", "two_dimensional", "single_node",
            "non_cuda_aware", "xla")
@@ -504,7 +508,8 @@ def check_placement(comm, cfg, step, state, batch, on_chip):
     program exchanges."""
     import jax
 
-    from chainermn_tpu.analysis.hlo import parse_hlo_collectives
+    from chainermn_tpu.analysis.hlo import (all_reduce_overlap_census,
+                                            parse_hlo_collectives)
 
     n = comm.size
     for leaf in jax.tree.leaves(batch):
@@ -527,14 +532,20 @@ def check_placement(comm, cfg, step, state, batch, on_chip):
           f"no all-reduce or reduce-scatter over a group of {n} in the "
           f"compiled step (group widths seen: {widths})")
     kernels = text.count("tpu_custom_call")
+    # the engagement counter of the asynchronous gradient exchange
+    # (``comm.exchange_compiler_options``: None on the CPU rehearsal)
+    census = all_reduce_overlap_census(text)
     if on_chip:
         want = FLASH_KERNELS_PER_LAYER * cfg["n_layers"]
         check(kernels == want,
               f"{kernels} tpu_custom_call in the {n}-chip step, expected "
               f"{want}")
+        check(census["asynchronous_byte_share"] > ASYNC_BYTE_SHARE_MIN,
+              f"the {n}-chip step reduces {census} - under "
+              f"{ASYNC_BYTE_SHARE_MIN:.0%} of the wire bytes asynchronously")
     return {"batch_shard_devices": n, "params_replicated_on": n,
             "reduce_group_widths": widths, "reduces": len(reduces),
-            "tpu_custom_calls": kernels}
+            "tpu_custom_calls": kernels, "all_reduce_census": census}
 
 
 def _group_width(groups, world):

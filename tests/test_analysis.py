@@ -28,6 +28,7 @@ import chainermn_tpu
 from chainermn_tpu.analysis import (
     CollectiveSchedule,
     LintError,
+    all_reduce_overlap_census,
     extract_schedule,
     get_rule,
     lint_step,
@@ -131,6 +132,82 @@ def test_hlo_parser_flags_unmatched_async_halves():
 
     p2 = parse_hlo_collectives(UNMATCHED_DONE_HLO)
     assert [pr["kind"] for pr in p2.problems] == ["unmatched-async-done"]
+
+
+# The TPU compiler's own asynchronous form (libtpu 0.0.34, as compiled for a
+# described v5e:2x2): ONE all-reduce printed in the computation of its
+# ``async-collective-start``, of every step fusion that carries it beside
+# other work and of its ``async-collective-done``.  Layouts hold parentheses;
+# the variadic all-reduce of the small vectors and the scalar loss block.
+ASYNC_FUSION_HLO = """
+HloModule jit_inner, is_scheduled=true
+
+%fused_computation.1985 (param_0.1: bf16[2048,2048]) -> (bf16[2048,2048], u32[]) {
+  %param_0.1 = bf16[2048,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.842 = bf16[2048,2048]{1,0:T(8,128)(2,1)} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_0.1, backend_config={"async_collective_fusion_config":{"flag_start":"-1","flag_end":"-1"}}
+  ROOT %custom-call.15 = (bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) custom-call(%param_0.1, %all-reduce.842), custom_call_target="AllReduceStart"
+}
+
+%async_collective_fusion.1246 (param_0.2: bf16[2048,2048], param_1.2: f32[8192,2048]) -> (bf16[2048,2048], f32[8192,2048]) {
+  %param_0.2 = bf16[2048,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = f32[8192,2048]{1,0:T(8,128)} parameter(1)
+  %all-reduce.844 = bf16[2048,2048]{1,0:T(8,128)(2,1)} all-reduce(%param_0.2), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_0.1, backend_config={"async_collective_fusion_config":{"flag_start":"2","flag_end":"17"}}
+  %multiply.7 = f32[8192,2048]{1,0:T(8,128)} multiply(%param_1.2, %param_1.2)
+  ROOT %tuple.443 = (bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)}, f32[8192,2048]{1,0:T(8,128)}) tuple(%all-reduce.844, %multiply.7)
+}
+
+%fused_computation.1987 (param_0.3: bf16[2048,2048]) -> bf16[2048,2048] {
+  %param_0.3 = bf16[2048,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.846 = bf16[2048,2048]{1,0:T(8,128)(2,1)} all-reduce(%param_0.3), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_0.1, backend_config={"async_collective_fusion_config":{"flag_start":"2","flag_end":"18"}}
+  ROOT %custom-call.17 = bf16[2048,2048]{1,0:T(8,128)(2,1)} custom-call(%param_0.3, %all-reduce.846), custom_call_target="AllReduceDone"
+}
+
+ENTRY %main.332_spmd (param.1: bf16[2048,2048], param.2: bf16[2048], param.3: bf16[8192], param.4: f32[8192,2048], param.5: f32[]) -> (bf16[2048,2048], f32[]) {
+  %param.1 = bf16[2048,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %param.2 = bf16[2048]{0:T(1024)(128)(2,1)} parameter(1)
+  %param.3 = bf16[8192]{0:T(1024)(128)(2,1)} parameter(2)
+  %param.4 = f32[8192,2048]{1,0:T(8,128)} parameter(3)
+  %param.5 = f32[]{:T(128)} parameter(4)
+  %all-reduce.712 = (bf16[2048]{0:T(1024)(128)(2,1)S(1)}, bf16[8192]{0:T(1024)(128)(2,1)}) all-reduce(%param.2, %param.3), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_0.1
+  %async-collective-start.51 = (bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) fusion(%param.1), kind=kCustom, calls=%fused_computation.1985
+  %fusion.1246 = (bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)}, f32[8192,2048]{1,0:T(8,128)}) fusion(%param.1, %param.4), kind=kLoop, calls=%async_collective_fusion.1246
+  %async-collective-done.51 = bf16[2048,2048]{1,0:T(8,128)(2,1)} fusion(%param.1), kind=kCustom, calls=%fused_computation.1987
+  %psum_invariant.1015 = f32[]{:T(128)} all-reduce(%param.5), channel_id=2, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_1.2
+  ROOT %tuple.1 = (bf16[2048,2048]{1,0:T(8,128)(2,1)}, f32[]{:T(128)}) tuple(%async-collective-done.51, %psum_invariant.1015)
+}
+"""
+
+
+@pytest.mark.parametrize("text,want", [
+    (SYNC_HLO, {"synchronous": 1, "asynchronous": 0,
+                "synchronous_bytes": 1024, "asynchronous_bytes": 0,
+                "asynchronous_byte_share": 0.0}),
+    (ASYNC_HLO, {"synchronous": 0, "asynchronous": 1,
+                 "synchronous_bytes": 0, "asynchronous_bytes": 4096,
+                 "asynchronous_byte_share": 1.0}),
+    (ASYNC_FUSION_HLO, {
+        "synchronous": 2, "asynchronous": 1,
+        "synchronous_bytes": 2 * (2048 + 8192) + 4,
+        "asynchronous_bytes": 2 * 2048 * 2048,
+        "asynchronous_byte_share":
+            2 * 2048 * 2048 / (2 * 2048 * 2048 + 2 * (2048 + 8192) + 4)}),
+], ids=["blocking", "start_done_pair", "async_collective_fusion"])
+def test_all_reduce_overlap_census(text, want):
+    """The engagement counter of the asynchronous gradient exchange: an
+    all-reduce counts once however often the compiler prints it, blocking
+    unless it is a start/done pair or a chain of asynchronous-collective
+    fusions, with the bytes its result holds."""
+    assert all_reduce_overlap_census(text) == want
+
+
+def test_hlo_parser_reads_a_variadic_all_reduce_through_tpu_layouts():
+    """A TPU layout holds parentheses (``T(8,128)(2,1)``): the tuple shape
+    of a combined all-reduce ends where the op begins, not at the first
+    ``)``."""
+    p = parse_hlo_collectives(ASYNC_FUSION_HLO)
+    entry = [o for o in p.ops if o.name in ("all-reduce.712",
+                                            "psum_invariant.1015")]
+    assert [o.nbytes for o in entry] == [2 * (2048 + 8192), 4]
 
 
 # ---------------------------------------------------------------------------

@@ -191,11 +191,18 @@ def _instructions(text, *ops):
 
 
 def _wire_all_reduces(text):
-    """The all-reduces of a compiled exchange, each over the bf16 wire."""
-    reduced = _instructions(text, "all-reduce", "all-reduce-start")
+    """How many all-reduces a compiled gradient exchange holds, each over
+    the bf16 wire, as ``all_reduce_overlap_census`` counts them: a blocking
+    one once, and an asynchronous one once however many computations of its
+    start - steps - done chain print it."""
+    from chainermn_tpu.analysis.hlo import all_reduce_overlap_census
+
+    reduced = [line for line in _instructions(
+        text, "all-reduce", "all-reduce-start", "all-reduce-done")
+        if "chainermn.report" not in line]      # the loss, a float32 scalar
     assert all("bf16[" in line.split(" all-reduce")[0]
                for line in reduced), reduced
-    return reduced
+    return all_reduce_overlap_census(text)
 
 
 @pytest.mark.parametrize("body,packs", [
@@ -215,8 +222,10 @@ def test_four_chip_exchange_builds_no_buffer(topo, body, packs):
     assert bool(gathers or pack_copies) == packs, (gathers, pack_copies)
     assert "tpu_custom_call" not in text
     # the combiner, not a buffer, merges the leaves: fewer operations than
-    # leaves (every small vector rides with a matrix)
-    assert 0 < len(_wire_all_reduces(text)) < 11
+    # leaves (every small vector rides with a matrix), and with XLA's own
+    # options (``_spmd_program`` is not a train step) every one blocks
+    census = _wire_all_reduces(text)
+    assert 0 < census["synchronous"] < 11 and not census["asynchronous"]
 
 
 @pytest.mark.parametrize("chips", [4, 1])
@@ -229,13 +238,96 @@ def test_resnet50_exchange_is_xla_alone(topo, chips):
     text = _exchange_program(topo, "allreduce_grad", _resnet50_tree, chips)
     assert "tpu_custom_call" not in text
     assert not _instructions(text, "dynamic-update-slice")
-    reduced = _wire_all_reduces(text)
+    census = _wire_all_reduces(text)
+    reduced = census["synchronous"] + census["asynchronous"]
     if chips == 1:
         assert not reduced and not _instructions(
             text, "all-gather", "reduce-scatter", "collective-permute",
-            "all-to-all"), reduced
+            "all-to-all"), census
     else:
-        assert 0 < len(reduced) < 161, len(reduced)
+        assert 0 < reduced < 161, census
+
+
+def _double_buffered_step(topo, chips):
+    """``make_train_step`` over the double-buffered optimizer and the bf16
+    wire, as every benchmark cell builds it, on ``chips`` described chips: a
+    three-matrix model whose matrices (8 and 16 MB on the wire) lie over the
+    combiner threshold of ``exchange_compiler_options`` and whose two
+    vectors lie under it.  Returns the communicator, the keywords ``jax.jit``
+    was called with and the compiled text."""
+    import optax
+
+    import chainermn_tpu
+    from chainermn_tpu.optimizers import _DoubleBufferState, make_train_step
+    from chainermn_tpu.parallel.topology import init_topology
+
+    comm = chainermn_tpu.create_communicator(
+        "xla", topology=init_topology(devices=list(topo.devices)[:chips]),
+        allreduce_grad_dtype="bfloat16")
+    optimizer = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(0.1, momentum=0.9), comm, double_buffering=True)
+
+    def loss_fn(p, batch):
+        (x,) = batch
+        hidden = jnp.tanh(x @ p["in"] + p["bias"])
+        return jnp.mean((hidden @ p["up"] @ p["down"] * p["gain"]) ** 2)
+
+    replicated = NamedSharding(comm.mesh, P())
+    stacked = NamedSharding(comm.mesh, P(comm.data_axes))
+    shapes = {"in": (2048, 2048), "up": (2048, 4096), "down": (4096, 2048),
+              "bias": (2048,), "gain": (2048,)}
+    params = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=replicated)
+              for k, v in shapes.items()}
+    inner = jax.eval_shape(optax.sgd(0.1, momentum=0.9).init, params)
+    opt_state = _DoubleBufferState(
+        inner=jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=replicated), inner),
+        pending={k: jax.ShapeDtypeStruct((chips,) + v, jnp.float32,
+                                         sharding=stacked)
+                 for k, v in shapes.items()},
+        step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated))
+    batch = (jax.ShapeDtypeStruct((8 * chips, 2048), jnp.float32,
+                                  sharding=stacked),)
+    with unittest.mock.patch.object(jax, "jit", wraps=jax.jit) as jit:
+        step = make_train_step(comm, loss_fn, optimizer)
+    (_, keywords), = jit.call_args_list
+    return comm, keywords, step.lower(
+        params, opt_state, batch).compile().as_text()
+
+
+def test_four_chip_double_buffered_exchange_compiles_asynchronous(topo):
+    """On four described chips the step is jitted with the communicator's
+    options and the exchange of ``pending`` (which nothing the step computes
+    feeds) compiles to asynchronous all-reduces: each matrix alone, as a
+    start - steps - done chain of fusions that the scheduler spreads over
+    the step; the two vectors ride together in one blocking all-reduce of
+    8 KB and the loss in another.  On the dp4 cell's real step the same
+    options give 43 asynchronous all-reduces carrying 99.96 % of the wire
+    bytes (PERF.md, PR 29)."""
+    comm, keywords, text = _double_buffered_step(topo, 4)
+    assert keywords["compiler_options"] == comm.exchange_compiler_options()
+    assert keywords["compiler_options"]["xla_enable_async_all_reduce"]
+    census = _wire_all_reduces(text)
+    assert census["asynchronous"] == 3, census
+    assert census["synchronous"] == 2, census
+    assert census["asynchronous_bytes"] == 2 * (2048 * 2048 + 2 * 2048 * 4096)
+    assert census["asynchronous_byte_share"] > 0.999, census
+    assert text.count("calls=%async_collective_fusion") >= 3
+
+
+def test_one_chip_double_buffered_step_keeps_xla_defaults(topo):
+    """The same step on ONE described chip, as four of the five benchmark
+    cells run it: nothing to exchange, so ``jax.jit`` gets no compile
+    options (the compiled program, and its key in the persistent cache, stay
+    what they were before PR 29) and the text holds no collective in either
+    form."""
+    comm, keywords, text = _double_buffered_step(topo, 1)
+    assert comm.exchange_compiler_options() is None
+    assert keywords["compiler_options"] is None
+    census = _wire_all_reduces(text)
+    assert not census["synchronous"] and not census["asynchronous"], census
+    assert "async_collective_fusion" not in text
+    assert "async-collective-start" not in text
 
 
 def test_moe_layer_main_pass_kernels_keep_their_names(topo):

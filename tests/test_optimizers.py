@@ -111,6 +111,41 @@ def test_train_step_compiles_on_one_device_world(flavor):
     np.testing.assert_allclose(np.asarray(params2["w"]), 1.0, rtol=1e-6)
 
 
+@pytest.mark.parametrize("double_buffering", [True, False])
+def test_cpu_mesh_step_is_jitted_with_no_compile_options(double_buffering):
+    """``exchange_compiler_options`` names options of the TPU compiler, and
+    the CPU compiler refuses a name it does not know; so on a CPU mesh of
+    four devices the communicator gives none, ``make_train_step`` hands
+    ``jax.jit`` none, and the step compiles and reduces as it always did
+    (every multi-device test of this suite stands on that)."""
+    import unittest.mock
+
+    from chainermn_tpu.parallel.topology import init_topology
+
+    comm = chainermn_tpu.create_communicator(
+        "xla", topology=init_topology(devices=jax.devices()[:4]),
+        allreduce_grad_dtype="bfloat16")
+    assert comm.size == 4 and comm.exchange_compiler_options() is None
+    with pytest.raises(Exception, match="No such compile option"):
+        jax.jit(lambda x: x + 1, compiler_options={
+            "xla_tpu_enable_async_collective_fusion": True,
+        }).lower(jnp.zeros(3)).compile()
+    opt = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(1.0), comm, double_buffering=double_buffering)
+    with unittest.mock.patch.object(jax, "jit", wraps=jax.jit) as jit:
+        step = make_train_step(comm, quad_loss, opt, donate=False)
+    (_, keywords), = jit.call_args_list
+    assert keywords["compiler_options"] is None
+    params = {"w": jnp.zeros((3,))}
+    opt_state = init_opt_state(comm, opt, params)
+    batch = (jnp.arange(4.0)[:, None] * jnp.ones((4, 3)),)
+    for _ in range(2 if double_buffering else 1):
+        params, opt_state, _ = step(params, opt_state, batch)
+    # mean gradient of ranks 0..3 at w = 0 is -1.5 (one step late under
+    # the double buffer)
+    np.testing.assert_allclose(np.asarray(params["w"]), 1.5, rtol=1e-6)
+
+
 class TestDoubleBuffering:
     def test_one_step_staleness_exact(self, comm):
         """The fork's signature semantics (SURVEY.md §3.4): update t applies
@@ -165,7 +200,8 @@ class TestDoubleBuffering:
     lambda comm, opt: chainermn_tpu.create_communicator(
         "xla", intra_size=4, use_pallas_cast=True),
     lambda comm, opt: make_train_step(comm, quad_loss, opt, scan_steps=2),
-    lambda comm, opt: chainermn_tpu.models.ResNet50(stem="s2d"),
+    lambda comm, opt: __import__("chainermn_tpu.models").models.ResNet50(
+        stem="s2d"),
 ], ids=["use_pallas_cast", "scan_steps", "stem"])
 def test_removed_options_are_rejected(comm, build):
     """The exchange has one lowering, the step one loop and ResNet one

@@ -44,7 +44,8 @@ def compile_program(name, topo):
     from jax.sharding import NamedSharding
 
     import chip_smoke
-    from chainermn_tpu.analysis.hlo import parse_hlo_collectives
+    from chainermn_tpu.analysis.hlo import (all_reduce_overlap_census,
+                                            parse_hlo_collectives)
 
     chips, builder, extra = PROGRAMS[name]
     cpus = jax.devices()
@@ -80,6 +81,7 @@ def compile_program(name, topo):
         "compile_s": round(seconds, 1),
         "tpu_custom_calls": text.count("tpu_custom_call"),
         "collectives": parse_hlo_collectives(text).count_by_kind(),
+        "all_reduce_census": all_reduce_overlap_census(text),
         "per_device_GiB": {
             "arguments": round(mem.argument_size_in_bytes / gib, 3),
             "outputs": round(mem.output_size_in_bytes / gib, 3),
