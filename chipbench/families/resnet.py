@@ -1,7 +1,7 @@
 """Family ``resnet``: the program's ``ResNet`` (BatchNorm statistics local
 to each device) through ``create_communicator`` -> ``bcast_data`` ->
-``create_multi_node_optimizer`` -> ``make_train_step(with_model_state=True)``,
-as ``bench.py`` runs the source paper's flagship."""
+``create_multi_node_optimizer`` -> ``make_train_step(with_model_state=True)``:
+the source paper's flagship, trained as the paper trains it."""
 
 from __future__ import annotations
 

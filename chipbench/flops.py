@@ -10,9 +10,10 @@ operations (flash attention's second score matmul, a remat policy) are not
 counted, and nor are elementwise passes (normalization, activation, the
 optimizer): those cost bandwidth, and show as a lower share of the peak.
 
-``bench.py``'s ``TRAIN_GFLOP_PER_IMAGE = 12.3`` is 3 x 4.1 G multiply-adds,
-a multiply-add counted once: half of what this module counts, and so were
-the "14.7 % MFU ceiling" figures derived from it.
+The 12.3 GFLOP an image that the repo's older records quote for ResNet-50
+(``docs/performance.md``; the script that held it went at PR 28) is 3 x
+4.1 G multiply-adds, a multiply-add counted once: half of what this module
+counts, and so were the "14.7 % MFU ceiling" figures derived from it.
 """
 
 from __future__ import annotations
@@ -80,12 +81,11 @@ def resnet_train_flop_per_image(sizes) -> float:
 
 def lm_train_flop_per_token(seq_len, d, layers, vocab, n_heads,
                             n_kv_heads=None, d_inner=None) -> float:
-    """Matmul operations of one training token at sequence length T (copied
-    from ``benchmarks/bench_lm.py::lm_train_gflop_per_token``, whose
-    arithmetic is sound: per matmul 2*M*N*K, attention counts the causal half
-    for the score and the value matmul, grouped kv heads shrink only the kv
-    projection, train = 3 x forward, recompute not counted).  The embedding
-    lookups are gathers and count nothing."""
+    """Matmul operations of one training token at sequence length T: per
+    matmul 2*M*N*K, attention counts the causal half for the score and the
+    value matmul, grouped kv heads shrink only the kv projection, train =
+    3 x forward, recompute not counted.  The embedding lookups are gathers
+    and count nothing."""
     t = seq_len
     n_kv = n_kv_heads or n_heads
     d_kv = n_kv * (d // n_heads)

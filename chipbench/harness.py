@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from chipbench import generator, spec, weights
+from chipbench import generator, scopes, spec, weights
 
 CACHE_DIR_NAME = ".jax_cache"
 STEPS_AHEAD = 2
@@ -120,6 +120,7 @@ class Run:
         self.comm = cell.family.make_comm(cell.sizes, devices)
         self.compiled = None
         self.compile_info = None
+        self.instruction_scopes, self.mixed_fusions = {}, []
         self.steps_taken = 0
         self._norms = self._change = None
 
@@ -145,17 +146,32 @@ class Run:
         self.steps_taken = 0
 
     # -- the one compile ---------------------------------------------------
-    def compile(self):
+    def compile(self, read_scopes=False):
         """Trace and compile the step ONCE, from this one call site (a
         Pallas kernel's cache key carries its callers' line numbers), and
-        read what the compiled program says of itself."""
+        read what the compiled program says of itself.  ``read_scopes`` (a
+        traced run) also keeps the compiled text's ``{instruction:
+        op_name}`` and its mixed fusions for ``scopes.event_scopes``, and
+        keys this one compile on the names: JAX's persistent cache keys a
+        program with its debug info stripped, so it would serve an
+        executable compiled before a scope was added, whose ``as_text()``
+        holds the OLD names (PR 24's first ResNet run read the parent's).
+        Without it the compile, its cache key and ``setup_s`` are as they
+        were."""
         traced = self.step.trace(*self.state, self.ring[0])
-        compiled = traced.lower().compile()
+        with (_cache_keyed_on_names() if read_scopes
+              else contextlib.nullcontext()):
+            compiled = traced.lower().compile()
         memory = compiled.memory_analysis()
         interpreted = _pallas_interpret_flags(traced.jaxpr)
         self.compiled = compiled
+        text = compiled.as_text()
+        if read_scopes:
+            parsed = scopes.parse(text)
+            self.instruction_scopes = scopes.instruction_scopes(parsed)
+            self.mixed_fusions = scopes.mixed_fusions(parsed)
         self.compile_info = {
-            "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
+            "tpu_custom_calls": text.count("tpu_custom_call"),
             "pallas_calls": len(interpreted),
             "pallas_calls_interpreted": int(sum(interpreted)),
             "argument_bytes": int(memory.argument_size_in_bytes),
@@ -299,6 +315,21 @@ class Run:
         if not keep_compiled:
             self.compiled = None
         return kept
+
+
+@contextlib.contextmanager
+def _cache_keyed_on_names():
+    """For the length of one compile, make the persistent cache's key hold
+    the program's metadata (the scope names among it)."""
+    import jax
+
+    option = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, option)
+    jax.config.update(option, True)
+    try:
+        yield
+    finally:
+        jax.config.update(option, before)
 
 
 def _pallas_interpret_flags(jaxpr):

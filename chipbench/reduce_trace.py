@@ -20,10 +20,11 @@ clock.  Every function below works on such a document, in nanoseconds.
 Operations nest (a loop or a call contains its body), so time by name is
 SELF time: an event's duration less that of the events it contains.  Busy
 time is the union of the intervals of the LEAF events (those that contain
-no other).  A collective is any operation whose
-name starts with one of ``COLLECTIVE_PREFIXES``; an asynchronous one
-("...-start" / "...-done") spans from its start's beginning to its done's
-end, and its EXPOSED part is what no other operation of that device covers.
+no other).  What an exchange between chips costs is read by scope
+(``scopes.spans_where`` / ``exposed``: an asynchronous pair spans from its
+start's beginning to its done's end, and its EXPOSED part is what no
+operation outside the scope covers) and, by instruction name, as the time
+the device only waits (``layer_metrics/collective_wait_ms.py``).
 """
 
 from __future__ import annotations
@@ -32,13 +33,9 @@ import glob
 import gzip
 import json
 import os
-import re
 
 HOST_SPANS = ("dispatch", "read_loss")
 OP_LINE = "XLA Ops"
-COLLECTIVE_PREFIXES = ("all-reduce", "all-gather", "reduce-scatter",
-                       "collective-permute", "all-to-all")
-_ASYNC = re.compile(r"^(?P<kind>[a-z-]+?)-(?P<edge>start|done)\b")
 
 
 # ---- reading -------------------------------------------------------------
@@ -223,42 +220,6 @@ def self_times(ops):
 def time_of(ops, matches):
     """Summed self time of the operations whose name ``matches``."""
     return sum(ns for name, ns in self_times(ops).items() if matches(name))
-
-
-# ---- collectives -----------------------------------------------------------
-
-def is_collective(name):
-    return name.lower().startswith(COLLECTIVE_PREFIXES)
-
-
-def collective_intervals(ops):
-    """One ``[start, end]`` per collective: a synchronous operation's own
-    span, or from an asynchronous start's beginning to the end of the done
-    that answers it (the k-th done of a kind answers the k-th start)."""
-    out, open_starts = [], {}
-    for name, start, duration in sorted(ops, key=lambda e: e[1]):
-        if not is_collective(name):
-            continue
-        edge = _ASYNC.match(name.lower())
-        if edge and edge.group("edge") == "start":
-            open_starts.setdefault(edge.group("kind"), []).append(start)
-            out.append([start, start + duration])
-        elif edge and open_starts.get(edge.group("kind")):
-            began = open_starts[edge.group("kind")].pop(0)
-            out.append([began, start + duration])
-        else:
-            out.append([start, start + duration])
-    return out
-
-
-def collective_ns(ops):
-    return length(union(collective_intervals(ops)))
-
-
-def collective_exposed_ns(ops):
-    others = union(_spans([e for e in leaf_ops(ops)
-                           if not is_collective(e[0])]))
-    return length(subtract(union(collective_intervals(ops)), others))
 
 
 # ---- gaps ------------------------------------------------------------------

@@ -9,7 +9,9 @@ the cell's step through the program's public entry points, trace and compile
 it once, take the check's first three steps and the warm-up steps.  Then the
 window: ``--trace 0`` drives the step for ``--seconds`` with no profiler and
 reports the cell's end-to-end metrics; ``--trace 1`` profiles a short steady
-window and reports its per-layer metrics, with a breakdown.  After the
+window, names every device event by the scope it ran under (``scopes.py``;
+one ``"phase": "scopes"`` line says how far the names reach) and reports the
+cell's per-layer metrics, with a breakdown.  After the
 window the program's state is freed and the plain reference follows the
 same first three steps; every number compared is printed beside its limit.
 The last line of standard output is the result object.
@@ -91,10 +93,11 @@ def main(argv=None):
                              "chipbench/ (default: this checkout)")
     parser.add_argument("--keep-trace", default=None, metavar="FILE",
                         help="with --trace 1, also write the trace's events "
-                             "as gzipped JSON (a fixture for the tests)")
+                             "and their scopes as gzipped JSON (a recording "
+                             "for the tests)")
     args = parser.parse_args(argv)
 
-    from chipbench import check, harness, reduce_trace, spec
+    from chipbench import check, harness, reduce_trace, scopes, spec
 
     root = os.path.abspath(args.root) if args.root else spec.CHECKOUT
     try:
@@ -138,6 +141,10 @@ def main(argv=None):
                    for m in cell.end_to_end if m["name"] in values}
     elif args.trace and driven["enqueued"]:
         reduced = reduce_trace.reduce_directory(trace_dir)
+        reduced["scopes"] = scopes.event_scopes(
+            reduced, run.instruction_scopes)
+        emit(phase="scopes", **scopes.describe(
+            reduced, run.instruction_scopes, run.mixed_fusions))
         if args.keep_trace:
             reduce_trace.dump_events(reduced, args.keep_trace)
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -190,7 +197,7 @@ def set_up(run, args, events):
     timeline["weights_state_ring"] = time.perf_counter() - t0
     mark = events.mark()
     t0 = time.perf_counter()
-    info = run.compile()
+    info = run.compile(read_scopes=bool(args.trace))
     timeline["trace_and_compile"] = time.perf_counter() - t0
     emit(phase="compile", seconds=timeline["trace_and_compile"],
          **events.since(mark), **info)

@@ -21,9 +21,11 @@ compiled program's own text: ``parse`` reads ``compiled.as_text()``,
 
     "scopes": {event name as in "devices": op_name, ...}
 
-and everything below reads such a document.  Without the key (the two
-fixtures of PR 23) ``of`` returns None and every reader built on it returns
-None.
+and everything below reads such a document: ``python3 -m chipbench.run
+--trace 1`` adds the key itself (``harness.Run.compile(read_scopes=True)``
+keeps the table, ``run.main`` keys it by the trace's names).  Without the
+key (the fixtures of PR 23) ``of`` returns None and every reader built on it
+returns None.
 
 **An instruction the compiler made has no op_name** (a prefetch
 ``copy-start`` / ``copy-done``, a relayout ``copy``, the
@@ -79,8 +81,11 @@ def segments(op_name):
     """The names along an ``op_name``, transforms unwrapped:
     ``a/transpose(jvp(M))/b/mul`` gives ``[a, transpose, jvp, M, b, mul]``.
     A scope's name holds no ``/`` and no parenthesis (the naming rule), so
-    nothing is lost."""
-    return [part for part in _SEPARATORS.split(op_name) if part]
+    nothing is lost.  The mark of an inherited name is not part of it (the
+    chains of PR 29 carry op_names that START with the scope, no ``jit(``
+    before it)."""
+    return [part for part
+            in _SEPARATORS.split(op_name.removeprefix(INHERITED)) if part]
 
 
 def under(op_name, *patterns):
@@ -248,8 +253,8 @@ def spans_where(events, matches):
     """Merged ``[start, end]`` intervals in which an event whose op_name
     ``matches`` is in flight on the first device.  An asynchronous pair
     (``...-start`` / ``...-done``) spans from its start's beginning to its
-    done's end, as in ``reduce_trace.collective_intervals`` — where the
-    program asked for it.  A pair named by its consumer is the compiler's
+    done's end (the k-th done of a kind answers the k-th start) — where
+    the program asked for it.  A pair named by its consumer is the compiler's
     own prefetch (``copy-start``, ``slice-start``): issued early on purpose,
     it costs the core its two ends and no more."""
     scopes = of(events)
@@ -299,3 +304,29 @@ def ms_per_step(events, host, matches):
     if not readable(events):
         return None
     return time_where(events, matches) / 1e6 / host["steps"]
+
+
+def describe(events, table, mixed):
+    """How far the names reach, for a run's ``"phase": "scopes"`` line:
+    instructions with an op_name (``table``), the first device's self time
+    by top-level scope (milliseconds over the traced window), and how much
+    of it lies in fusions that mix top-level scopes (``mixed``; a fusion
+    carries one op_name, its root's) or in compiler-made instructions named
+    after their consumer."""
+    named = of(events)
+    line = {"instructions_with_op_name": len(table),
+            "event_names": len(named),
+            "event_names_without_scope": sum(not v for v in named.values()),
+            "mixed_fusions": len(mixed)}
+    if events["devices"]:
+        mixed = set(mixed)
+        own = reduce_trace.self_times(reduce_trace.first_device(events))
+        line["window_ms_by_top_level"] = {
+            scope: ns / 1e6 for scope, ns in by_top_level(events).items()}
+        line["window_ms_in_mixed_fusions"] = sum(
+            ns for name, ns in own.items()
+            if instruction_of(name) in mixed) / 1e6
+        line["window_ms_named_by_consumer"] = sum(
+            ns for name, ns in own.items()
+            if named[name].startswith(INHERITED)) / 1e6
+    return line

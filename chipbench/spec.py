@@ -9,9 +9,12 @@ under the benchmark's directory:
     limits/<cell>.json              the limit of each number compared
     layer_metrics/<metric>.py       one reader per per-layer metric
     peaks.json                      peaks per ``device_kind``
+    fixtures_scoped/<cell>.two-steps.json.gz   two traced steps of a chip run
+                                    (``--keep-trace``), which the tests read
 
 A later PR adds a cell, configuration, family or metric by adding files and
-``BENCHMARK.json`` entries; nothing here or in ``run.py`` names one.
+``BENCHMARK.json`` entries, and a cell's name to the ``workloads`` lists of
+the metrics that read it; nothing here or in ``run.py`` names one.
 """
 
 from __future__ import annotations
@@ -84,7 +87,9 @@ def load_peaks(device_kind, root=CHECKOUT):
     return table["kinds"][device_kind]
 
 
-def _applies(metric, cell_name):
+def applies(metric, cell_name):
+    """Whether ``cell_name`` reports the metric: it is in the entry's
+    ``workloads`` list, or the entry has none (then every cell does)."""
     return "workloads" not in metric or cell_name in metric["workloads"]
 
 
@@ -108,10 +113,10 @@ def resolve(cell_name, root=CHECKOUT, rehearse=False) -> Cell:
     if rehearse:
         sizes.update(config.get("toy", {}))
         sizes.update(traffic.get("toy", {}))
-    end_to_end = [m for m in bench["end_to_end"] if _applies(m, cell_name)]
+    end_to_end = [m for m in bench["end_to_end"] if applies(m, cell_name)]
     reported = {m["name"] for m in end_to_end}
     per_layer = [m for m in bench["per_layer"]
-                 if _applies(m, cell_name) and m["moves"] in reported]
+                 if applies(m, cell_name) and m["moves"] in reported]
     limits = _load_json(os.path.join(
         root, "chipbench", "limits", cell_name + ".json"))
     if rehearse:

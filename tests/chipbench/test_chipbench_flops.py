@@ -44,7 +44,7 @@ def test_resnet50_training_counts_two_operations_a_multiply_add():
     # into the image
     assert flops.resnet_train_flop_per_image(config) == 2 * (
         3 * forward - stem)
-    # bench.py's 12.3 GFLOP/image counted a multiply-add once
+    # the older records' 12.3 GFLOP/image counted a multiply-add once
     assert 24.0e9 < flops.resnet_train_flop_per_image(config) < 24.6e9
 
 
