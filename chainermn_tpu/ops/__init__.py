@@ -1,7 +1,10 @@
 """Native/fused TPU kernels (Pallas) — the reference's CUDA-kernel role
 (SURVEY.md §2.3)."""
 
-from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_tile_census,
+)
 from chainermn_tpu.ops.fused_norm import (
     FusedBatchNormAct,
     fused_norm,
@@ -13,6 +16,7 @@ from chainermn_tpu.ops.grouped_matmul import grouped_matmul
 
 __all__ = [
     "flash_attention",
+    "flash_tile_census",
     "fused_norm",
     "fused_norm_reference",
     "grouped_matmul",

@@ -24,6 +24,28 @@ A pure-XLA blockwise backward with identical math is kept
 (``bwd_impl="blockwise"``) as the cross-check oracle for the
 gradient-parity tests.
 
+Tile classes (PR 33).  Where the mask follows from shapes and the grid
+indices alone — ``causal``, positions from 0 on both sides (no offsets), no
+segment ids, no dropout, ``Tq == Tkv`` and ``block_q == block_k`` — the three
+kernels know each visited tile's class from ``rel = q tile - k tile``
+(:func:`_tile_plan`) and do the work of its class:
+
+* INTERIOR (every pair visible): the tile's body with no mask, no iota and
+  no select.
+* EDGE (the causal diagonal, or a window's far edge, crosses the tile): the
+  tile is walked as sub-tiles of ``_SUB x _SUB`` pairs; a sub-tile with no
+  visible pair does nothing, whole sub-tiles side by side are one product,
+  and only a sub-tile an edge crosses is masked.  The forward advances the
+  online softmax once a sub-tile row, ``dq`` adds by sub-tile row and
+  ``dk, dv`` by sub-tile column.  Which sub-tiles run is static per class.
+* OUTSIDE (no visible pair): no work (``pl.when`` is false).
+
+Anywhere else a tile's class is not static (offsets are runtime values, a
+segment or dropout mask touches every tile) and every visited tile takes the
+GENERIC body: the whole tile under a mask built from global positions.
+:func:`flash_tile_census` counts, from the same arithmetic, what the classes
+take away.
+
 Masking and dropout:
 
 * ``causal`` — lower-triangular mask; fully-masked K/V tiles are
@@ -33,7 +55,8 @@ Masking and dropout:
   work on EITHER side of the band; without position offsets (which neither
   the grid nor an index map can see) the streamed grid dimension walks the
   band's tiles alone (:func:`_band`), so the others are neither fetched nor
-  stepped over.  ``window=None`` traces the kernels exactly as they were.
+  stepped over.  ``window=None``, and without offsets a window as long as
+  the queries, is plain causal attention and traced as such.
 * ``q_segment_ids``/``kv_segment_ids`` ([B, T] int32) — attention is
   allowed only where the ids match, which expresses packed-sequence and
   padding masks (give padding a sentinel id that matches nothing).
@@ -44,12 +67,12 @@ Masking and dropout:
   computed identically in forward, backward, and the blockwise oracle —
   nothing random is stored, so the recompute-based backward stays exact.
 
-Scope: per-shard sequence lengths where K/V fit VMEM (T*D*2B each —
-thousands of positions at D=64..128), which is exactly the per-device
-block regime of :func:`chainermn_tpu.parallel.sequence.ring_attention` /
-``ulysses_attention`` (pass ``attn_fn=flash_attention``).  Off-TPU the
-kernels run in Pallas interpret mode so the CPU test mesh exercises the
-same code path.
+Callers: the LM families' attention layers (single shard, the benchmark's
+cells), :func:`chainermn_tpu.parallel.sequence.ring_attention` /
+``ulysses_attention`` (pass ``attn_fn=flash_attention``; ring attention
+passes offsets) and the serving engine's decode over a paged cache (per-row
+offsets, ``Tq != Tkv``).  Off-TPU the kernels run in Pallas interpret mode
+so the CPU test mesh exercises the same code path.
 """
 
 from __future__ import annotations
@@ -59,6 +82,8 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 try:  # pltpu only imports on TPU-capable installs; interpret mode needs it not
@@ -68,8 +93,13 @@ except Exception:  # pragma: no cover
     pltpu = None
     _VMEM = None
 
-_BLOCK_Q = 1024  # measured optimum on v5e (benchmarks: 81 TFLOP/s fwd at
-_BLOCK_K = 1024  # T=8k vs 24 at 256/256 — per-grid-step overhead amortizes)
+_BLOCK_Q = 1024  # what a grid step costs is paid once a tile: PERF.md section 5
+_BLOCK_K = 1024  # has the cells' kernel times at this size, section 7 what is open
+# An EDGE tile (the causal diagonal or a window's far edge crosses it) is
+# walked as sub-tiles of _SUB x _SUB pairs, and a sub-tile that holds no
+# visible pair does nothing.  One value for the three kernels and every
+# caller, chosen from a sweep on the chip (PERF.md section 6, PR 33).
+_SUB = 512
 _NEG_INF = -1e30
 _LSE_SENTINEL = 1e30  # lse for fully-masked rows: exp(s - sentinel) == 0
 
@@ -147,6 +177,140 @@ def _tile_runs(causal, window, q_first, bq, k_first, bk, always):
     return run
 
 
+def _visible(gap_min, gap_max, window):
+    """What a block of pairs whose ``row - column`` runs from ``gap_min`` to
+    ``gap_max`` holds under ``0 <= row - column < window``: ``None`` where no
+    pair is visible, else ``(causal, far)``: whether the diagonal crosses
+    the block (some gap is negative) and whether the window's far edge does
+    (some gap reaches ``window``).  ``(False, False)``: every pair visible.
+    The arithmetic of :func:`_tile_runs`, on Python integers."""
+    if gap_max < 0 or (window is not None and gap_min >= window):
+        return None
+    return gap_min < 0, window is not None and gap_max >= window
+
+
+class _Piece(NamedTuple):
+    """Part of a strip of an edge tile: the elements ``start ... stop - 1``
+    along the strip, visible where ``lo <= row - column < hi`` in the piece's
+    own coordinates (``None``: that side needs no test).  Whole sub-tiles
+    that lie side by side are one piece with no test; a sub-tile an edge
+    crosses is a piece of its own."""
+
+    start: int
+    stop: int
+    lo: Optional[int] = None
+    hi: Optional[int] = None
+
+    @property
+    def every_row_sees(self):
+        """Whether the piece's own diagonal (``row == column``) is visible:
+        then every row of it sees a pair, whatever it saw before."""
+        return ((self.lo is None or self.lo <= 0)
+                and (self.hi is None or self.hi > 0))
+
+
+def _strip_pieces(gaps, sub, window):
+    """The pieces of one strip: ``gaps[i]`` is ``row - column`` between the
+    first row and the first column of the strip's i-th sub-tile."""
+    pieces = []
+    for i, gap in enumerate(gaps):
+        seen = _visible(gap - (sub - 1), gap + sub - 1, window)
+        if seen is None:
+            continue
+        causal, far = seen
+        piece = _Piece(i * sub, (i + 1) * sub, -gap if causal else None,
+                       window - gap if far else None)
+        whole = piece.lo is None and piece.hi is None
+        if (whole and pieces and pieces[-1].stop == piece.start
+                and pieces[-1].lo is None and pieces[-1].hi is None):
+            piece = _Piece(pieces.pop().start, piece.stop)
+        pieces.append(piece)
+    return tuple(pieces)
+
+
+class _TilePlan(NamedTuple):
+    """The classes of a call's tiles by ``rel = q tile - k tile`` (both
+    sides tiled alike from position 0): INTERIOR for ``interior[0] <= rel <=
+    interior[1]`` (every pair visible: the body with no mask), EDGE for the
+    keys of ``rows`` / ``cols`` (for each sub-tile row, or column, the
+    pieces of its strip that hold a visible pair), OUTSIDE otherwise."""
+
+    sub: int
+    interior: tuple
+    rows: dict
+    cols: dict
+
+
+def _sub_of(block, sub):
+    """The sub-tile a tile of ``block`` is walked in: ``sub`` where it
+    divides the tile, else the whole tile."""
+    return sub if block % sub == 0 else block
+
+
+def _tile_plan(causal, window, has_offsets, has_seg, dropout_rate, tq, tk,
+               bq, bk, sub) -> Optional[_TilePlan]:
+    """A tile's class is static only where the mask follows from shapes and
+    the grid indices alone: causal, positions from 0 on both sides (no
+    offsets: runtime values), no segment ids and no dropout (they touch every
+    tile), both sides tiled alike.  Elsewhere ``None``: every visited tile
+    takes the generic masked body."""
+    if (not causal or has_offsets or has_seg or dropout_rate > 0.0
+            or tq != tk or bq != bk):
+        return None
+    sub = _sub_of(bq, sub)
+    n_sub = bq // sub
+    interior, rows, cols = [], {}, {}
+    for rel in range(tq // bq):
+        seen = _visible(rel * bq - (bk - 1), rel * bq + bq - 1, window)
+        if seen is None:
+            continue
+        if seen == (False, False):
+            interior.append(rel)
+            continue
+        gap = lambda a, c: rel * bq + (a - c) * sub
+        rows[rel] = tuple(
+            _strip_pieces([gap(a, c) for c in range(n_sub)], sub, window)
+            for a in range(n_sub))
+        # a column's strip runs along the rows: the same test, with the
+        # piece's own row - column measured from the piece's first row
+        cols[rel] = tuple(
+            _strip_pieces([gap(a, c) for a in range(n_sub)], sub, window)
+            for c in range(n_sub))
+    span = (interior[0], interior[-1]) if interior else (1, 0)
+    return _TilePlan(sub, span, rows, cols)
+
+
+def _piece_mask(piece, gap):
+    """The allow-mask of a piece an edge crosses (``gap`` = the piece's own
+    ``row - column``), or ``None`` where every pair is visible."""
+    mask = None
+    if piece.lo is not None:
+        mask = lax.ge(gap, np.int32(piece.lo))
+    if piece.hi is not None:
+        far = lax.lt(gap, np.int32(piece.hi))
+        mask = far if mask is None else lax.bitwise_and(mask, far)
+    return mask
+
+
+def _where(mask, x, otherwise):
+    """``jnp.where(mask, x, otherwise)`` for a scalar ``otherwise``."""
+    return lax.select(mask, x, lax.full_like(x, otherwise))
+
+
+def _row_reduce(reduce, x):
+    """``reduce`` over each row of ``x``, kept as a column ``[rows, 1]``."""
+    return lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], 1), (0,))
+
+
+def _effective_window(window, has_offsets, tq):
+    """With positions from 0 on both sides no row is ``tq`` or more past a
+    column, so a window that long bounds nothing: the call IS plain causal
+    attention and is traced as such."""
+    if window is not None and not has_offsets and window >= tq:
+        return None
+    return window
+
+
 def _first_k_tile(j, bq, bk, window):
     """The first k tile that holds a pair visible to query tile ``j``
     (positions from 0 on both sides); ``j`` a Python or a traced integer."""
@@ -174,9 +338,12 @@ class _Band(NamedTuple):
     q_kwargs: dict
 
 
-def _band(window, has_offsets, bq, bk, n_q, n_k) -> _Band:
+def _band(window, has_offsets, bq, bk, n_q, n_k, classified) -> _Band:
     """With no window every step holds its own tile, all ``n_k`` (``n_q``)
-    of them, and the kernels get no keyword they did not always have.  Under
+    of them, and the kernels get no keyword they did not always have; where
+    the call is ``classified`` (:func:`_tile_plan`: the tiles past the
+    diagonal are known to do nothing) the index maps hold the diagonal's tile
+    through those steps, and an index that repeats fetches nothing.  Under
     a window they get ``window``, mask by it and skip a tile with no
     visible pair.  Where positions start at 0 on both sides (no offsets:
     neither the grid nor an index map can see them) the grid is BANDED
@@ -188,6 +355,9 @@ def _band(window, has_offsets, bq, bk, n_q, n_k) -> _Band:
     own = lambda j, step: step
     if window is None or has_offsets:
         kwargs = {} if window is None else {"window": window}
+        if classified:
+            return _Band(n_k, lambda j, step: jnp.minimum(step, j), kwargs,
+                         n_q, lambda j, step: jnp.maximum(step, j), kwargs)
         return _Band(n_k, own, kwargs, n_q, own, kwargs)
     k_steps = max(min((j * bq + bq - 1) // bk, n_k - 1)
                   - _first_k_tile(j, bq, bk, window) + 1 for j in range(n_q))
@@ -204,13 +374,55 @@ def _band(window, has_offsets, bq, bk, n_q, n_k) -> _Band:
         dict(kwargs, n_tiles=n_q))
 
 
+def _generic_masks(masked, causal, window, has_seg, dropout_rate, qseg_ref,
+                   kseg_ref, seed, bh_idx, q_first, bq, k_first, bk):
+    """The whole tile's allow-mask and (keep-mask, 1 / keep probability) by
+    global position — the generic body's, for any call; ``(None, None)``
+    where the tile is not ``masked`` (interior) or nothing masks."""
+    if not (masked and (causal or has_seg or dropout_rate > 0.0)):
+        return None, None
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    seg_q = qseg_ref[0, 0] if has_seg else None
+    seg_k = kseg_ref[0, 0] if has_seg else None
+    mask = _mask_tile(causal, q_pos, k_pos, seg_q, seg_k, window)
+    keep_inv = None
+    if dropout_rate > 0.0:
+        keep_inv = (_keep_mask(seed, bh_idx, q_pos, k_pos, dropout_rate),
+                    1.0 / (1.0 - dropout_rate))
+    return mask, keep_inv
+
+
+def _sub_gap(sub):
+    """``row - column`` inside a sub-tile: what an edge's mask tests."""
+    return lax.sub(lax.broadcasted_iota(jnp.int32, (sub, sub), 0),
+                   lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
+
+
+def _when_classified(classes, run, inside, rel, tile, edge):
+    """Run the body of this step's class.  ``classes`` is ``None`` (no plan:
+    ``tile(True)``, the whole tile under the generic mask, wherever ``run``
+    says the tile holds a visible pair) or a plan's ``(interior, edges)``:
+    ``tile(False)`` on an interior tile and ``edge(strips)`` on an edge tile,
+    by ``rel``, where the step's tile lies ``inside`` the sequence."""
+    if classes is None:
+        pl.when(run & inside)(lambda: tile(True))
+        return
+    (first, last), edges = classes
+    if first <= last:
+        pl.when((rel >= first) & (rel <= last) & inside)(
+            lambda: tile(False))
+    for edge_rel, strips in edges.items():
+        pl.when((rel == edge_rel) & inside)(functools.partial(edge, strips))
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
                 has_seg, dropout_rate, has_offsets, window=None,
-                banded=False, n_tiles=None):
+                banded=False, n_tiles=None, plan=None):
     # Streaming grid (bh, q-tile, k-tile): q_ref [1, BQ, D] (fixed per
     # (bh, j)); k_ref/v_ref [1, BK, D] = THIS grid step's tile; optional
     # qseg [1, 1, BQ], kseg [1, 1, BK], seed [1, 1], offs [1, 2]; outputs
@@ -220,11 +432,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         rest, has_seg, dropout_rate, has_offsets)
     o_ref, lse_ref, acc_s, m_s, l_s = rest
 
-    q = q_ref[0]                                         # [BQ, D]
-    k = k_ref[0]                                         # [BK, D]
-    v = v_ref[0]
-    bq, d = q.shape
-    bk = k.shape[0]
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
     kk = pl.program_id(2)
     n_k = pl.num_programs(2)
     q_off = pl.program_id(1) * bq
@@ -244,48 +453,85 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
+    # (the bodies below bind lax primitives themselves: an edge tile unrolls
+    # them a dozen times a kernel, a step traces thirty kernels and more,
+    # and a jnp call costs several times the tracing of the primitive it
+    # binds, which is set-up time)
+    def scores(q, k):
+        # scale after the matmul — same op order as the unfused reference,
+        # so results match it to tight tolerance
+        return lax.mul(lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                       preferred_element_type=jnp.float32),
+                       sm_scale)
+
+    def advance(rows, parts):
+        """One online-softmax step of the query rows ``rows`` over ``parts``:
+        (scores, allow-mask or None, whether a row of it may see nothing so
+        far, (keep-mask, 1 / keep probability) or None, the value rows)."""
+        m = m_s[rows]
+        m_new = m
+        for s, *_ in parts:
+            m_new = lax.max(m_new, _row_reduce(lax.reduce_max, s))
+        alpha = lax.exp(lax.sub(m, m_new))
+        l = lax.mul(l_s[rows], alpha)
+        acc = lax.mul(acc_s[rows], alpha)
+        for s, mask, may_be_empty, keep_inv, v in parts:
+            p = lax.exp(lax.sub(s, m_new))
+            if mask is not None and may_be_empty:
+                # when a whole row is masked so far, s - m_new == 0 and exp
+                # would give 1 — zero the masked entries explicitly
+                p = _where(mask, p, 0.0)
+            l = lax.add(l, _row_reduce(lax.reduce_sum, p))
+            if keep_inv is not None:
+                p = _where(keep_inv[0], lax.mul(p, keep_inv[1]), 0.0)
+            acc = lax.add(acc, lax.dot_general(
+                lax.convert_element_type(p, v.dtype), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        l_s[rows] = l
+        m_s[rows] = m_new
+        acc_s[rows] = acc
+
+    def _tile(masked):
+        # the whole tile in one step; ``masked``: under the generic mask
+        # (causal and window by global position, segment ids) and dropout
+        mask, keep_inv = _generic_masks(
+            masked, causal, window, has_seg, dropout_rate, qseg_ref, kseg_ref,
+            seed, bh_idx, goff_q + q_off, bq, goff_k + k_off, bk)
+        s = scores(q_ref[0], k_ref[0])
+        if mask is not None:
+            s = _where(mask, s, _NEG_INF)
+        advance(slice(None), [(s, mask, True, keep_inv, v_ref[0])])
+
+    def _edge(strips):
+        # each sub-tile row advances once, over the pieces of its strip
+        # that hold a visible pair; only a piece an edge crosses is masked
+        gap = _sub_gap(plan.sub)
+        for a, pieces in enumerate(strips):
+            if not pieces:
+                continue
+            rows = slice(a * plan.sub, (a + 1) * plan.sub)
+            q = q_ref[0, rows, :]
+            parts = []
+            for piece in pieces:
+                cols = slice(piece.start, piece.stop)
+                s = scores(q, k_ref[0, cols, :])
+                mask = _piece_mask(piece, gap)
+                if mask is not None:
+                    s = _where(mask, s, _NEG_INF)
+                parts.append((s, mask, not piece.every_row_sees, None,
+                              v_ref[0, cols, :]))
+            advance(rows, parts)
+
     # full-tile skip: the tile contributes only if some q row can see its
     # first k row (the fetch still pipelines; the MXU work is skipped), and
     # under a window only if its last k row is near enough to the first q
     run = _tile_runs(causal, window, goff_q + q_off, bq, goff_k + k_off, bk,
                      kk >= 0)
-    if banded:      # a band that runs off the end of the sequence
-        run = run & (k_tile < n_tiles)
-
-    @pl.when(run)
-    def _tile():
-        q_pos = goff_q + q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        k_pos = goff_k + k_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
-        # scale after the matmul — same op order as the unfused reference,
-        # so results match it to tight tolerance
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        seg_q = qseg_ref[0, 0] if has_seg else None
-        seg_k = kseg_ref[0, 0] if has_seg else None
-        mask = _mask_tile(causal, q_pos, k_pos, seg_q, seg_k, window)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG_INF)
-        m = m_s[...]
-        l = l_s[...]
-        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if mask is not None:
-            # when a whole row of the tile is masked, s - m_new == 0 and
-            # exp would give 1 — zero the masked entries explicitly
-            p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_s[...] = l * alpha + p.sum(axis=1, keepdims=True)
-        m_s[...] = m_new
-        if dropout_rate > 0.0:
-            keep = _keep_mask(seed, bh_idx, q_pos, k_pos, dropout_rate)
-            p_use = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-        else:
-            p_use = p
-        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # a band that runs off the end of the sequence holds no tile there
+    inside = k_tile < n_tiles if banded else True
+    _when_classified(plan and (plan.interior, plan.rows), run, inside,
+                     pl.program_id(1) - k_tile, _tile, _edge)
 
     @pl.when(kk == n_k - 1)
     def _finish():
@@ -332,13 +578,18 @@ def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
     kv_row = lambda i: (i // h) * hk + (i % h) // grp
     has_seg = qseg is not None
     has_offsets = offs is not None
+    window = _effective_window(window, has_offsets, tq)
 
     # the k tiles a q tile's steps hold: all of them, or a window's band
-    band = _band(window, has_offsets, bq, bk, tq // bq, tk // bk)
+    plan = _tile_plan(causal, window, has_offsets, has_seg, dropout_rate,
+                      tq, tk, bq, bk, _SUB)
+    band = _band(window, has_offsets, bq, bk, tq // bq, tk // bk,
+                 plan is not None)
     k_steps, k_of = band.k_steps, band.k_of
     kern = functools.partial(_fwd_kernel, sm_scale=scale, causal=causal,
                              has_seg=has_seg, dropout_rate=dropout_rate,
-                             has_offsets=has_offsets, **band.k_kwargs)
+                             has_offsets=has_offsets, plan=plan,
+                             **band.k_kwargs)
     kw = {} if _VMEM is None else {"memory_space": _VMEM}
     ins = [qf, kf, vf]
     in_specs = [
@@ -392,10 +643,39 @@ def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
 # backward kernels
 # ---------------------------------------------------------------------------
 
+def _score_grads(q, g, k, v, lse, delta, glse, sm_scale, mask, keep_inv):
+    """What both backward kernels recompute for a block of pairs: the
+    normalized probabilities ``a`` (dropped where ``keep_inv`` = (keep-mask,
+    1 / keep probability) says: ``a_drop``) and the scores' gradient ``ds``.
+    ``lse`` / ``delta`` / ``glse`` are the rows' vectors."""
+    # (lax primitives, not jnp calls: see the forward kernel's note)
+    column = lambda vec: lax.broadcast_in_dim(vec, (vec.shape[0], 1), (0,))
+    s = lax.mul(lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32), sm_scale)
+    a = lax.exp(lax.sub(s, column(lse)))              # normalized probs
+    if mask is not None:
+        a = _where(mask, a, 0.0)
+    dp = lax.dot_general(g, v, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    if keep_inv is not None:
+        keep, inv = keep_inv
+        a_drop = _where(keep, lax.mul(a, inv), 0.0)
+        da = _where(keep, lax.mul(dp, inv), 0.0)
+    else:
+        a_drop = a
+        da = dp
+    ds = lax.mul(lax.mul(a, lax.sub(da, column(delta))), sm_scale)
+    if glse is not None:
+        # cotangent flowing into the logsumexp output: d lse_i / d s_ij
+        # = a_ij (same a as above), in scaled-score space
+        ds = lax.add(ds, lax.mul(lax.mul(a, column(glse)), sm_scale))
+    return a_drop, ds
+
+
 def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
                 sm_scale, causal, has_seg, dropout_rate,
                 has_offsets, with_lse, window=None, banded=False,
-                n_tiles=None):
+                n_tiles=None, plan=None):
     # Streaming grid (bh, k-tile, q-tile): k_ref/v_ref [1, BK, D] fixed
     # per (bh, kk); q_ref/g_ref [1, BQ, D] = this step's q tile;
     # lse_ref/delta_ref [1, 1, BQ] tiles; optional glse [1, 1, BQ];
@@ -409,12 +689,8 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
         glse_ref = None
         dk_ref, dv_ref, dk_s, dv_s = outs
 
-    k = k_ref[0]                                          # [BK, D]
-    v = v_ref[0]
-    q = q_ref[0]                                          # [BQ, D]
-    g = g_ref[0]
-    bk = k.shape[0]
-    bq = q.shape[0]
+    bk = k_ref.shape[1]
+    bq = q_ref.shape[1]
     qq = pl.program_id(2)
     n_q = pl.num_programs(2)
     k_off = pl.program_id(1) * bk
@@ -431,51 +707,44 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
+    def accumulate(cols, rows, mask, keep_inv):
+        """Add the pairs of query rows ``rows`` and key rows ``cols``."""
+        q = q_ref[0, rows, :]
+        g = g_ref[0, rows, :]
+        a_drop, ds = _score_grads(
+            q, g, k_ref[0, cols, :], v_ref[0, cols, :], lse_ref[0, 0, rows],
+            delta_ref[0, 0, rows], glse_ref[0, 0, rows] if with_lse else None,
+            sm_scale, mask, keep_inv)
+        dv_s[cols] = lax.add(dv_s[cols], lax.dot_general(
+            lax.convert_element_type(a_drop, g.dtype), g,
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+        dk_s[cols] = lax.add(dk_s[cols], lax.dot_general(
+            lax.convert_element_type(ds, q.dtype), q,
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+
+    def _tile(masked):
+        mask, keep_inv = _generic_masks(
+            masked, causal, window, has_seg, dropout_rate, qseg_ref, kseg_ref,
+            seed, bh_idx, goff_q + q_off, bq, goff_k + k_off, bk)
+        accumulate(slice(None), slice(None), mask, keep_inv)
+
+    def _edge(strips):
+        # each sub-tile column takes the pieces of its strip of query rows
+        # that hold a visible pair; only a piece an edge crosses is masked
+        gap = _sub_gap(plan.sub)
+        for c, pieces in enumerate(strips):
+            cols = slice(c * plan.sub, (c + 1) * plan.sub)
+            for piece in pieces:
+                accumulate(cols, slice(piece.start, piece.stop),
+                           _piece_mask(piece, gap), None)
+
     # causal: this q tile contributes only if its last row sees the
     # k tile's first row (and, under a window, its first row the last)
     run = _tile_runs(causal, window, goff_q + q_off, bq, goff_k + k_off, bk,
                      qq >= 0)
-    if banded:
-        run = run & (q_tile < n_tiles)
-
-    @pl.when(run)
-    def _tile():
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        k_pos = goff_k + k_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
-        q_pos = goff_q + q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        seg_q = qseg_ref[0, 0] if has_seg else None
-        seg_k = kseg_ref[0, 0] if has_seg else None
-        mask = _mask_tile(causal, q_pos, k_pos, seg_q, seg_k, window)
-        a = jnp.exp(s - lse[:, None])                     # normalized probs
-        if mask is not None:
-            a = jnp.where(mask, a, 0.0)
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            keep = _keep_mask(seed, bh_idx, q_pos, k_pos, dropout_rate)
-            inv = 1.0 / (1.0 - dropout_rate)
-            a_drop = jnp.where(keep, a * inv, 0.0)
-            da = jnp.where(keep, dp * inv, 0.0)
-        else:
-            a_drop = a
-            da = dp
-        dv_s[...] = dv_s[...] + jax.lax.dot_general(
-            a_drop.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = a * (da - delta[:, None]) * sm_scale
-        if with_lse:
-            # cotangent flowing into the logsumexp output: d lse_i / d s_ij
-            # = a_ij (same a as above), in scaled-score space
-            glse = glse_ref[0, 0]
-            ds = ds + a * glse[:, None] * sm_scale
-        dk_s[...] = dk_s[...] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    inside = q_tile < n_tiles if banded else True
+    _when_classified(plan and (plan.interior, plan.cols), run, inside,
+                     q_tile - pl.program_id(1), _tile, _edge)
 
     @pl.when(qq == n_q - 1)
     def _finish():
@@ -486,7 +755,7 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
 def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
                sm_scale, causal, has_seg, dropout_rate,
                has_offsets, with_lse, window=None, banded=False,
-               n_tiles=None):
+               n_tiles=None, plan=None):
     # Streaming grid (bh, q-tile, k-tile): q_ref/g_ref [1, BQ, D] fixed
     # per (bh, j); k_ref/v_ref [1, BK, D] = this step's tile;
     # lse_ref/delta_ref [1, 1, BQ]; optional glse [1, 1, BQ]; output
@@ -499,14 +768,8 @@ def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
         glse_ref = None
         dq_ref, dq_s = outs
 
-    q = q_ref[0]
-    g = g_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
-    bq = q.shape[0]
-    bk = k.shape[0]
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
     kk = pl.program_id(2)
     n_k = pl.num_programs(2)
     q_off = pl.program_id(1) * bq
@@ -523,38 +786,40 @@ def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
+    def accumulate(rows, cols, mask, keep_inv):
+        """Add the pairs of query rows ``rows`` and key rows ``cols``."""
+        k = k_ref[0, cols, :]
+        _, ds = _score_grads(
+            q_ref[0, rows, :], g_ref[0, rows, :], k, v_ref[0, cols, :],
+            lse_ref[0, 0, rows], delta_ref[0, 0, rows],
+            glse_ref[0, 0, rows] if with_lse else None, sm_scale, mask,
+            keep_inv)
+        dq_s[rows] = lax.add(dq_s[rows], lax.dot_general(
+            lax.convert_element_type(ds, k.dtype), k,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+
+    def _tile(masked):
+        mask, keep_inv = _generic_masks(
+            masked, causal, window, has_seg, dropout_rate, qseg_ref, kseg_ref,
+            seed, bh_idx, goff_q + q_off, bq, goff_k + k_off, bk)
+        accumulate(slice(None), slice(None), mask, keep_inv)
+
+    def _edge(strips):
+        # each sub-tile row takes the pieces of its strip of key rows that
+        # hold a visible pair; only a piece an edge crosses is masked
+        gap = _sub_gap(plan.sub)
+        for a, pieces in enumerate(strips):
+            rows = slice(a * plan.sub, (a + 1) * plan.sub)
+            for piece in pieces:
+                accumulate(rows, slice(piece.start, piece.stop),
+                           _piece_mask(piece, gap), None)
+
     run = _tile_runs(causal, window, goff_q + q_off, bq, goff_k + k_off, bk,
                      kk >= 0)
-    if banded:      # a band that runs off the end of the sequence
-        run = run & (k_tile < n_tiles)
-
-    @pl.when(run)
-    def _tile():
-        q_pos = goff_q + q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        k_pos = goff_k + k_off + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        seg_q = qseg_ref[0, 0] if has_seg else None
-        seg_k = kseg_ref[0, 0] if has_seg else None
-        mask = _mask_tile(causal, q_pos, k_pos, seg_q, seg_k, window)
-        a = jnp.exp(s - lse[:, None])
-        if mask is not None:
-            a = jnp.where(mask, a, 0.0)
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            keep = _keep_mask(seed, bh_idx, q_pos, k_pos, dropout_rate)
-            da = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        else:
-            da = dp
-        ds = a * (da - delta[:, None]) * sm_scale
-        if with_lse:
-            ds = ds + a * glse_ref[0, 0][:, None] * sm_scale
-        dq_s[...] = dq_s[...] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    # a band that runs off the end of the sequence holds no tile there
+    inside = k_tile < n_tiles if banded else True
+    _when_classified(plan and (plan.interior, plan.rows), run, inside,
+                     pl.program_id(1) - k_tile, _tile, _edge)
 
     @pl.when(kk == n_k - 1)
     def _finish():
@@ -590,7 +855,11 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
     offs_in = ([offs] if has_offsets else [])
     offs_spec = ([pl.BlockSpec((1, 2), lambda i, j, kk: (i // h, 0), **kw)]
                  if has_offsets else [])
-    band = _band(window, has_offsets, bq, bk, tq // bq, tk // bk)
+    window = _effective_window(window, has_offsets, tq)
+    plan = _tile_plan(causal, window, has_offsets, has_seg, dropout_rate,
+                      tq, tk, bq, bk, _SUB)
+    band = _band(window, has_offsets, bq, bk, tq // bq, tk // bk,
+                 plan is not None)
     k_steps, k_of, q_steps, q_of = (band.k_steps, band.k_of, band.q_steps,
                                     band.q_of)
 
@@ -600,7 +869,8 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
     dkv_kern = functools.partial(
         _dkv_kernel, sm_scale=scale, causal=causal,
         has_seg=has_seg, dropout_rate=dropout_rate,
-        has_offsets=has_offsets, with_lse=with_lse, **band.q_kwargs)
+        has_offsets=has_offsets, with_lse=with_lse, plan=plan,
+        **band.q_kwargs)
     q_tile = lambda: pl.BlockSpec(
         (1, bq, d), lambda i, j, qq: (i, q_of(j, qq), 0), **kw)
     vec_q = lambda: pl.BlockSpec(
@@ -652,7 +922,8 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
     dq_kern = functools.partial(
         _dq_kernel, sm_scale=scale, causal=causal,
         has_seg=has_seg, dropout_rate=dropout_rate,
-        has_offsets=has_offsets, with_lse=with_lse, **band.k_kwargs)
+        has_offsets=has_offsets, with_lse=with_lse, plan=plan,
+        **band.k_kwargs)
     vec_j = lambda: pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, 0, j),
                                  **kw)
     ins = [qf, gf, kf, vf, lse, delta]
@@ -917,7 +1188,8 @@ def flash_attention(q, k, v, causal: bool = False,
       row i sees column j only where ``0 <= i - j < window``.  All three
       kernels skip a tile that holds no such pair, on both sides of the
       band, and without offsets do not fetch it either.  ``None`` (the
-      default) is plain causal attention, traced as it always was.
+      default) is plain causal attention, and so is, without offsets, a
+      window as long as the queries.
     """
     if window is not None:
         if not causal:
@@ -990,4 +1262,67 @@ def flash_attention(q, k, v, causal: bool = False,
                   bwd_impl, bool(return_lse), window)
 
 
-__all__ = ["flash_attention"]
+def flash_tile_census(tq: int, tk: int, block_q: Optional[int] = None,
+                      block_k: Optional[int] = None,
+                      window: Optional[int] = None,
+                      sub: Optional[int] = None, *, causal: bool = True,
+                      offsets: bool = False, segment_ids: bool = False,
+                      dropout_rate: float = 0.0) -> dict:
+    """How much of a call's work the tile classes take away: a counter of
+    shapes alone, from the arithmetic the kernels use (:func:`_tile_plan`,
+    :func:`_visible`), for one (batch, head) of one kernel.
+
+    ``classified``: whether the kernels know a tile's class (causal, no
+    offsets, no segment ids, no dropout, both sides tiled alike; see
+    :func:`_tile_plan`).  ``visited`` tiles hold a visible pair and do work:
+    ``interior`` (every pair visible) + ``edge`` (the diagonal or a window's
+    far edge crosses it); ``outside`` tiles of the plane hold none.
+    ``subtiles_total`` counts the visited tiles' sub-tiles of ``sub`` x
+    ``sub`` pairs (the file's ``_SUB`` unless given; the whole tile where it
+    does not divide the tile: ``sub`` is the query side's), ``subtiles_run`` those that do work: all of
+    them where ``classified`` is false, else the interior tiles' and the edge
+    tiles' sub-tiles that hold a visible pair.  ``visible_pair_share`` is the
+    visible pairs over the pairs of the sub-tiles run (1.0: no score is
+    computed that the mask throws away).  Blocks default as
+    :func:`flash_attention`'s do for operands under four bytes."""
+    bq = _fit_block(tq, block_q, _BLOCK_Q)
+    bk = _fit_block(tk, block_k, _BLOCK_K)
+    if window is not None and not causal:
+        raise ValueError("window needs causal=True")
+    window = _effective_window(window, offsets, tq)
+    sub = _SUB if sub is None else int(sub)
+    plan = _tile_plan(causal, window, offsets, segment_ids, dropout_rate,
+                      tq, tk, bq, bk, sub)
+    counts = {"interior": 0, "edge": 0, "outside": 0}
+    for j in range(tq // bq):
+        for kt in range(tk // bk):
+            seen = (_visible(j * bq - (kt * bk + bk - 1),
+                             j * bq + bq - 1 - kt * bk, window)
+                    if causal else (False, False))
+            counts["outside" if seen is None else
+                   "interior" if seen == (False, False) else "edge"] += 1
+    visited = counts["interior"] + counts["edge"]
+    per_tile = (bq // _sub_of(bq, sub)) * (bk // _sub_of(bk, sub))
+    sub = _sub_of(bq, sub)
+    if plan is None:
+        subtiles_run = visited * per_tile
+    else:
+        n = tq // bq
+        subtiles_run = counts["interior"] * per_tile + sum(
+            (n - rel) * sum((p.stop - p.start) // sub
+                            for pieces in strips for p in pieces)
+            for rel, strips in plan.rows.items())
+    if causal:
+        reach = tk if window is None else window
+        visible = sum(max(min(i, tk - 1) - max(i - reach + 1, 0) + 1, 0)
+                      for i in range(tq))
+    else:
+        visible = tq * tk
+    run_pairs = subtiles_run * (bq * bk // per_tile)
+    return {"classified": plan is not None, "sub": sub, "visited": visited,
+            **counts, "subtiles_run": subtiles_run,
+            "subtiles_total": visited * per_tile,
+            "visible_pair_share": visible / run_pairs if run_pairs else 0.0}
+
+
+__all__ = ["flash_attention", "flash_tile_census"]

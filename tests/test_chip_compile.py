@@ -59,6 +59,11 @@ def _norm_grad(x, scale, bias):
 
 _Q = ((1, 8192, 16, 128), jnp.bfloat16)     # the LM's [B, T, H, D]
 _KV_GQA = ((1, 8192, 4, 128), jnp.bfloat16)
+# starcoder1b-t2048: four rows of 2048, multi-query
+_Q2048 = ((4, 2048, 16, 128), jnp.bfloat16)
+_KV2048 = ((4, 2048, 1, 128), jnp.bfloat16)
+# starcoder1b-t8192's own multi-query shape
+_KV_MQA = ((1, 8192, 1, 128), jnp.bfloat16)
 _SEG = ((1, 8192), jnp.int32)
 # LFM2-8B-A1B: 32 query / 8 kv heads of 64, three rows
 _Q64 = ((3, 8192, 32, 64), jnp.bfloat16)
@@ -91,6 +96,9 @@ CASES = {
                   [_Q, _Q, _Q], 1),
     "flash_fwd_bwd": (_flash_grad, [_Q, _Q, _Q], 3),
     "flash_gqa_fwd_bwd": (_flash_grad, [_Q, _KV_GQA, _KV_GQA], 3),
+    "flash_mqa_fwd_bwd": (_flash_grad, [_Q, _KV_MQA, _KV_MQA], 3),
+    "flash_mqa_t2048_batch4_fwd_bwd": (
+        _flash_grad, [_Q2048, _KV2048, _KV2048], 3),
     "flash_segment_ids_fwd_bwd": (_flash_grad, [_Q, _Q, _Q, _SEG], 3),
     "flash_gqa_head_dim_64_fwd_bwd": (_flash_grad, [_Q64, _KV64, _KV64], 3),
     "flash_gqa_window_fwd_bwd": (_windowed_grad, [_Q32, _KV_GQA, _KV_GQA], 3),

@@ -7,13 +7,19 @@ Off-TPU the kernel runs in Pallas interpret mode — the same code path the
 TPU compiles.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.ops.flash_attention import (flash_attention,
+                                               flash_tile_census)
 from chainermn_tpu.parallel.sequence import attention, ulysses_attention
+
+# the package exports the function under the module's name
+_flash_module = sys.modules["chainermn_tpu.ops.flash_attention"]
 
 B, T, H, D = 2, 512, 4, 64
 
@@ -594,28 +600,177 @@ def _pallas_calls(jaxpr):
     return found
 
 
-def test_without_a_window_the_kernels_are_the_ones_they_were():
-    """``window=None`` traces what a call without the argument traces,
-    equation for equation: three kernels under their names, the whole
-    grid, every step its own tile.  A window keeps the count and the names
-    and walks a band."""
+# ---------------------------------------------------------------------------
+# the tile classes: interior tiles run no mask, edge tiles their visible
+# sub-tiles.  Tiles of 32 with a sub-tile a quarter of the tile.
+# ---------------------------------------------------------------------------
+
+_TILE, _QUARTER = 32, 8
+
+
+@pytest.fixture
+def quarter_sub(monkeypatch):
+    monkeypatch.setattr(_flash_module, "_SUB", _QUARTER)
+
+
+def _classified_case(window, heads, kv_heads, d, tiles, seed):
+    """Forward against the dense masked softmax, gradients against the
+    blockwise oracle, on a call the kernels classify."""
+    t = _TILE * tiles
+    census = flash_tile_census(t, t, _TILE, _TILE, window, _QUARTER)
+    assert census["classified"] and census["sub"] == _QUARTER
+    assert census["subtiles_run"] < census["subtiles_total"]
+    q, k, v = _window_case(heads, kv_heads, seed=seed, t=t, d=d)
+    fused = lambda impl: lambda a, b, c: flash_attention(
+        a, b, c, True, window=window, block_q=_TILE, block_k=_TILE,
+        bwd_impl=impl)
+    np.testing.assert_allclose(
+        fused("pallas")(q, k, v), _dense_window(q, k, v, window or t),
+        rtol=3e-4, atol=3e-4)
+    cot = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+    grads = lambda impl: jax.grad(
+        lambda *a: (fused(impl)(*a) * cot).sum(), (0, 1, 2))(q, k, v)
+    for g, w, name in zip(grads("pallas"), grads("blockwise"), "qkv"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
+                                   err_msg=f"grad wrt {name}")
+
+
+# full causal; a window of four tiles, of a tile, of sub-tiles alone (80 =
+# 10 x 8), of neither (100), and under a tile (24: the diagonal tile holds
+# both edges); MHA, GQA 4:1, MQA
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (8, 2), (4, 1)])
+@pytest.mark.parametrize("window", [None, 128, 32, 80, 100, 24])
+def test_classified_tiles_match_the_oracles(quarter_sub, window, heads,
+                                            kv_heads):
+    _classified_case(window, heads, kv_heads, d=64, tiles=8, seed=51)
+
+
+# one, two and eight tiles a side at both head sizes (one tile: the window
+# is as long as the sequence and the call is plain causal)
+@pytest.mark.parametrize("tiles", [1, 2, 8])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("window", [None, 40])
+def test_classified_tiles_by_head_size_and_tiles_a_side(quarter_sub, window,
+                                                        d, tiles):
+    _classified_case(window, 4, 1, d=d, tiles=tiles, seed=52)
+
+
+def _brute_census(t, tile, window, sub):
+    gap = np.arange(t)[:, None] - np.arange(t)[None, :]
+    seen = (gap >= 0) & (gap < (window or t))
+    blocks = lambda size: seen.reshape(t // size, size, t // size,
+                                       size).transpose(0, 2, 1, 3)
+    tiles = blocks(tile)
+    visited = tiles.any((2, 3))
+    interior = tiles.all((2, 3))
+    subs = blocks(sub)
+    per_side = tile // sub
+    in_visited = np.kron(visited, np.ones((per_side, per_side), bool))
+    run = subs.any((2, 3)) & in_visited
+    return {"classified": True, "sub": sub, "visited": int(visited.sum()),
+            "interior": int(interior.sum()),
+            "edge": int((visited & ~interior).sum()),
+            "outside": int((~visited).sum()),
+            "subtiles_run": int(run.sum()),
+            "subtiles_total": int(visited.sum()) * per_side ** 2,
+            "visible_pair_share": seen.sum() / (run.sum() * sub * sub)}
+
+
+@pytest.mark.parametrize("t,tile,window,sub", [
+    (256, 32, None, 8), (256, 32, 128, 8), (256, 32, 80, 8),
+    (256, 32, 100, 8), (256, 32, 24, 8), (64, 32, None, 16),
+    (512, 64, 200, 16), (512, 64, 64, 64), (32, 32, 7, 8)])
+def test_the_census_counts_what_a_brute_force_count_finds(t, tile, window,
+                                                          sub):
+    got = flash_tile_census(t, t, tile, tile, window, sub)
+    want = _brute_census(t, tile, window, sub)
+    assert got.pop("visible_pair_share") == pytest.approx(
+        want.pop("visible_pair_share"), rel=1e-12)
+    assert got == want
+
+
+# the benchmark's cells at the kernels' own tiles (1024) and sub-tile (512)
+@pytest.mark.parametrize("t,window,visited,interior,edge,tile_equivalents", [
+    (8192, None, 36, 28, 8, 34.0),       # the StarCoder, lfm2, nope layers
+    (2048, None, 3, 1, 2, 2.5),          # starcoder1b-t2048
+    (8192, 2048, 21, 7, 14, 17.5)])      # trinity's window layers
+def test_the_census_at_the_cells_shapes(t, window, visited, interior, edge,
+                                        tile_equivalents):
+    census = flash_tile_census(t, t, window=window)
+    assert census["classified"] and census["sub"] == 512
+    assert (census["visited"], census["interior"], census["edge"]) == (
+        visited, interior, edge)
+    assert census["subtiles_run"] / 4 == tile_equivalents
+    assert census["subtiles_total"] == 4 * visited
+    # a call the kernels cannot classify does every visited tile's work
+    generic = flash_tile_census(t, t, window=window, segment_ids=True)
+    assert not generic["classified"]
+    assert generic["subtiles_run"] == generic["subtiles_total"] == 4 * visited
+    assert generic["visible_pair_share"] < census["visible_pair_share"] < 1
+
+
+def test_three_kernels_under_their_names_and_who_walks_sub_tiles(
+        quarter_sub):
+    """Three ``pallas_call``s under the same three names on the same grid
+    with and without a window (``window=None`` and a window as long as the
+    sequence trace what a call without the argument traces, equation for
+    equation); a shorter window walks a band.  A classified call's kernels
+    walk sub-tiles on their edge tiles; a call with offsets, segment ids or
+    dropout takes the generic masked body on every tile."""
     q, k, v = _window_case(4, 2)
     traced = lambda **kw: jax.make_jaxpr(jax.grad(
         lambda *a: (flash_attention(*a, True, block_q=64, block_k=64,
                                     **kw) ** 2).sum(), (0, 1, 2)))(q, k, v)
     plain, none, windowed = traced(), traced(window=None), traced(window=100)
-    assert str(plain) == str(none) != str(windowed)
+    assert str(plain) == str(none) == str(traced(window=256)) != str(windowed)
     names = lambda jaxpr: [eqn.params["jaxpr"].debug_info.func_name
                            for eqn in _pallas_calls(jaxpr.jaxpr)]
-    assert names(plain) == names(windowed) == [
-        "_fwd_kernel", "_dkv_kernel", "_dq_kernel"]
     grids = lambda jaxpr: [tuple(eqn.params["grid_mapping"].grid)
                            for eqn in _pallas_calls(jaxpr.jaxpr)]
     index_maps = lambda jaxpr: "".join(
         str(block.index_map_jaxpr)
         for eqn in _pallas_calls(jaxpr.jaxpr)
         for block in eqn.params["grid_mapping"].block_mappings)
-    # every step its own tile; under the window the streamed dimension is
-    # the band's three tiles of the four, found by the index maps
-    assert grids(plain) == 3 * [(4, 4, 4)] and "min" not in index_maps(plain)
+    # the whole grid, where a classified call's index maps hold the
+    # diagonal's tile through the steps past it (k tiles: min; q tiles: max);
+    # under the window the streamed dimension is the band's three tiles of
+    # the four, found by the index maps
+    assert grids(plain) == 3 * [(4, 4, 4)]
+    assert "min" in index_maps(plain) and "max" in index_maps(plain)
     assert grids(windowed) == 3 * [(4, 4, 3)] and "min" in index_maps(windowed)
+    # a sub-tile walk tests row - column inside a sub-tile of 8 x 8; the
+    # generic body tests global positions over the whole tile of 64 x 64
+    walks = lambda jaxpr: ["i32[8,8]" in str(eqn.params["jaxpr"])
+                           for eqn in _pallas_calls(jaxpr.jaxpr)]
+    whole = lambda jaxpr: ["i32[64,64]" in str(eqn.params["jaxpr"])
+                           for eqn in _pallas_calls(jaxpr.jaxpr)]
+    for classified in (plain, windowed):
+        assert names(classified) == ["_fwd_kernel", "_dkv_kernel",
+                                     "_dq_kernel"]
+        assert walks(classified) == 3 * [True]
+        assert whole(classified) == 3 * [False]
+    seg = jnp.zeros((1, 256), jnp.int32)
+    for kw, census_kw in (
+            (dict(q_offset=0, kv_offset=0), dict(offsets=True)),
+            (dict(q_segment_ids=seg, kv_segment_ids=seg),
+             dict(segment_ids=True)),
+            (dict(dropout_rate=0.1, dropout_seed=3),
+             dict(dropout_rate=0.1))):
+        for window in (None, 100):
+            generic = traced(window=window, **kw)
+            assert names(generic) == ["_fwd_kernel", "_dkv_kernel",
+                                      "_dq_kernel"], kw
+            assert walks(generic) == 3 * [False], kw
+            assert whole(generic) == 3 * [True], kw
+            assert grids(generic) == (
+                grids(plain) if window is None or "q_offset" in kw
+                else grids(windowed)), kw
+            if window is None:      # every step fetches its own tile
+                assert "min" not in index_maps(generic), kw
+                assert "max" not in index_maps(generic), kw
+            census = flash_tile_census(256, 256, 64, 64, window, _QUARTER,
+                                       **census_kw)
+            assert not census["classified"], kw
+            assert census["subtiles_run"] == census["subtiles_total"]
+    assert flash_tile_census(256, 256, 64, 64, 100, _QUARTER)["classified"]
