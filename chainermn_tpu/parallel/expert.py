@@ -39,8 +39,8 @@ the imbalance — and leaves out what the absent experts would have added.
 Three named parts: :func:`dropless_route`, :func:`dropless_dispatch`,
 :func:`dropless_combine`.  Only the sort sees all ``N * K`` pairs: what
 carries a feature dimension is bounded by what the held experts get
-(:func:`dropless_rows_bound`), with a guarded second pass for a routing
-that exceeds it.
+(:func:`dropless_rows_bound`), with a remainder that walks the rows of a
+routing that exceeds it in chunks.
 """
 
 from __future__ import annotations
@@ -52,6 +52,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from chainermn_tpu.utils import pvary
 
 
 def moe_plan_topology(axis_name):
@@ -297,26 +299,25 @@ class Dispatch(NamedTuple):
 
 
 _ROW_TILE = 512     # grouped_matmul's row tile: the bound is whole tiles
+# the remainder walks the rows past the bound in chunks of four row tiles
+_REMAINDER_CHUNK = 4 * _ROW_TILE
 
 
 def dropless_rows_bound(pairs: int, held_experts: int,
                         num_experts: int) -> int:
     """How many sorted rows the layer's main pass materialises: what an even
-    routing sends to ``held_experts`` of ``num_experts``, half as much
-    again, and not under three eighths of the ``pairs``; in whole row
-    tiles, and never more than the ``pairs`` there are.  A function of
-    shapes alone: with every expert held it is ``pairs``.
+    routing sends to ``held_experts`` of ``num_experts`` and half as much
+    again, in whole row tiles, and never more than the ``pairs`` there are.
+    A function of shapes alone: with every expert held it is ``pairs``,
+    with a quarter held three eighths of them (36,864 of 98,304), with 16 of
+    128 held 12,288 of 65,536.
 
-    Why a floor: what an uneven routing adds to a share is a few busy
-    experts' load, a part of ALL the pairs that does not shrink with the
-    share held.  The largest share seen is 0.28 of the pairs where a quarter
-    of the experts is held and 0.29 where an eighth is (PERF.md, PR 32), so
-    half as much again leaves a quarter's share room (0.375) and an
-    eighth's none (0.1875: a layer passed it on a tenth to a half of a
-    run's steps)."""
+    No floor under it: what an uneven routing puts past the bound goes
+    through the remainder, which walks those rows in chunks and costs by the
+    chunk (:func:`dropless_moe`), so passing the bound by a few hundred rows
+    costs one chunk of 2,048 and not a pass over all the other rows."""
     even_and_a_half = -(-(pairs * held_experts * 3) // (num_experts * 2))
-    rows = max(even_and_a_half, -(-(pairs * 3) // 8))
-    return min(pairs, -(-rows // _ROW_TILE) * _ROW_TILE)
+    return min(pairs, -(-even_and_a_half // _ROW_TILE) * _ROW_TILE)
 
 
 def dropless_route(router_logits, expert_bias, top_k: int,
@@ -495,23 +496,47 @@ def dropless_combine(expert_rows, weights, dispatch: Dispatch,
             (start, start + expert_rows.shape[0]))
 
 
+def _remainder_chunk(pairs: int, bound: int) -> int:
+    """The rows of one of the remainder's chunks.  Under three eighths of the
+    pairs a bound is one that routings pass (a layer of Trinity-Mini's cell on
+    a tenth to a half of a run's steps: PERF.md, PR 32), and the remainder
+    walks the other rows in chunks of four row tiles.  From three eighths on
+    no routing seen has passed it (the largest share 0.28), and ONE chunk
+    holds all the other rows: what a remainder that no step takes costs is
+    what the compiler makes of the program around it, and around loops it
+    made lfm2's and Mellum's steps 2-3 % slower (PERF.md, PR 36)."""
+    rest = pairs - bound
+    return min(_REMAINDER_CHUNK, rest) if 8 * bound < 3 * pairs else rest
+
+
+def _remainder_trips(dispatch: Dispatch, bound: int, chunk: int):
+    """How many chunks hold the held experts' rows past ``bound``: int32, 0
+    on a step whose routing stays under it."""
+    past = jnp.maximum(dispatch.group_sizes.sum() - bound, 0)
+    return -(-past // chunk)
+
+
 def dropless_counters(dispatch: Dispatch, bound: Optional[int] = None):
     """What the routing did, for ``make_train_step(has_aux=True)``: all
     float32, so that the step's report can average them over devices.
     ``bound`` is the main pass's rows (default: all the pairs)."""
     sizes = dispatch.group_sizes.astype(jnp.float32)
     routed_here = dispatch.is_held.sum().astype(jnp.float32)
-    bound = dispatch.is_held.size if bound is None else bound
+    pairs = dispatch.is_held.size
+    bound = pairs if bound is None else bound
     return {
         "tokens_per_held_expert": sizes,
-        "held_share": sizes.sum() / dispatch.is_held.size,
+        "held_share": sizes.sum() / pairs,
         "load_max_over_mean": sizes.max() / jnp.maximum(sizes.mean(), 1.0),
         # pairs routed to a held expert that no group's rows cover
         "dropped_pairs": routed_here - sizes.sum(),
-        # the main pass's rows, and the rows the guarded remainder computed:
-        # 0 on every step it did not run
+        # the main pass's rows, the held rows past them, and the chunks the
+        # remainder walked to compute those: 0 on every step it took none
         "rows_bound": jnp.full((), bound, jnp.float32),
         "rows_past_bound": jnp.maximum(sizes.sum() - bound, 0.0),
+        "remainder_chunks": (
+            _remainder_trips(dispatch, bound, _remainder_chunk(pairs, bound))
+            if bound < pairs else jnp.zeros(())).astype(jnp.float32),
     }
 
 
@@ -539,6 +564,101 @@ def _remainder(x, weights, dispatch, expert_args, *, expert_fn, bound):
     return jax.checkpoint(rest)(x, weights, dispatch, expert_args)
 
 
+def _chunk_part(expert_fn, bound, chunk, i, x, weights, dispatch,
+                expert_args):
+    """The main pass again, on the ``i``-th chunk of the sorted rows past
+    ``bound``, a window that starts at a traced row.  A last chunk that would
+    run past the last pair starts earlier instead: the rows it then shares
+    with the chunk before are computed again and weigh nothing."""
+    pairs, top_k = dispatch.order.shape[0], dispatch.is_held.shape[1]
+    first = bound + i * chunk
+    start = jnp.minimum(first, pairs - chunk)
+    ends = jnp.cumsum(dispatch.group_sizes)
+    clip = lambda offsets: jnp.clip(offsets, start, start + chunk)
+    sizes = clip(ends) - clip(ends - dispatch.group_sizes)
+    pair = lax.dynamic_slice_in_dim(dispatch.order, start, chunk)
+    token, row = pair // top_k, start + jnp.arange(chunk)
+    with jax.named_scope(
+            "chainermn.moe.dispatch"):
+        # (through float32: its derivative then sums a token's rows in
+        # float32, as the main pass's does)
+        rows = x.astype(jnp.float32)[token].astype(x.dtype)
+    expert_rows = expert_fn(rows, sizes, *expert_args)
+    with jax.named_scope(
+            "chainermn.moe.combine"):
+        weight = jnp.where((row >= first) & (row < ends[-1]),
+                           weights.reshape(-1)[pair], 0.0)
+        return jnp.zeros(x.shape, jnp.float32).at[token].add(
+            expert_rows.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
+
+
+def _sum_over_chunks(term, like, dispatch, bound, chunk):
+    """``sum over i of term(i)`` for the chunks i that hold rows past
+    ``bound``, zeros ``like`` the sum where there is none: a loop whose trip
+    count follows the routing.  The ``cond`` around it keeps what does not
+    change from trip to trip, which the compiler moves out of the loop (``W1
+    | W3`` as one stack, a stack in the layout its transposed product
+    reads), off the steps that take no trip."""
+    trips = _remainder_trips(dispatch, bound, chunk)
+    zeros = lambda: jax.tree.map(jnp.zeros_like, like)
+    return lax.cond(
+        trips > 0,
+        lambda: lax.fori_loop(
+            0, trips, lambda i, sums: jax.tree.map(jnp.add, sums, term(i)),
+            zeros()),
+        zeros)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _chunked_remainder(expert_fn, bound, chunk, x, weights, dispatch,
+                       expert_args):
+    """The held experts' part of the result over their sorted rows from
+    ``bound`` on, a chunk a trip.  A loop with a traced trip count has no
+    reverse derivative of its own: the backward pass below walks the same
+    chunks."""
+    return _sum_over_chunks(
+        lambda i: _chunk_part(expert_fn, bound, chunk, i, x, weights,
+                              dispatch, expert_args),
+        x, dispatch, bound, chunk)
+
+
+def _chunked_remainder_fwd(expert_fn, bound, chunk, *inputs):
+    # it keeps its inputs alone: every chunk is recomputed in the backward
+    return _chunked_remainder(expert_fn, bound, chunk, *inputs), inputs
+
+
+def _chunked_remainder_bwd(expert_fn, bound, chunk, inputs, g):
+    x, weights, dispatch, expert_args = inputs
+
+    def gradients(i):
+        _, pullback = jax.vjp(
+            lambda x, weights, expert_args: _chunk_part(
+                expert_fn, bound, chunk, i, x, weights, dispatch,
+                expert_args), x, weights, expert_args)
+        return pullback(g)
+
+    d_x, d_weights, d_expert_args = _sum_over_chunks(
+        gradients, (x, weights, expert_args), dispatch, bound, chunk)
+    return d_x, d_weights, None, d_expert_args
+
+
+_chunked_remainder.defvjp(_chunked_remainder_fwd, _chunked_remainder_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("expert_fn", "bound", "chunk"))
+def _remainder_by_chunks(x, weights, dispatch, expert_args, *, expert_fn,
+                         bound, chunk):
+    """:func:`_chunked_remainder`, jitted so that layers with one
+    ``expert_fn`` and equal shapes share its trace and its derivative's."""
+    # inside a ``shard_map`` the loops' sums start as their inputs are typed:
+    # every input varies over the axes any of them does
+    inputs = (x, weights, dispatch, expert_args)
+    axes = frozenset().union(
+        *(jax.typeof(leaf).vma for leaf in jax.tree.leaves(inputs)))
+    inputs = jax.tree.map(lambda leaf: pvary(leaf, tuple(axes)), inputs)
+    return _chunked_remainder(expert_fn, bound, chunk, *inputs)
+
+
 def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
                  num_experts: int, top_k: int, first_expert: int = 0,
                  held_experts: Optional[int] = None, axis_name=None,
@@ -560,28 +680,41 @@ def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
 
     **Rows.**  Of the ``N * K`` sorted rows the main pass materialises the
     first ``R`` (:func:`dropless_rows_bound`: what an even routing gives
-    the held experts, half as much again, and not under three eighths of
-    them all) — the gathers, ``expert_fn`` and
-    the sums back are all ``R`` rows.  What an uneven routing puts past
-    ``R`` goes through the same pass over rows ``[R, N * K)`` under a
-    ``lax.cond`` that a normal step does not take (``rows_past_bound``
-    counts its rows); it keeps its inputs alone and recomputes in the
-    backward, so that it costs a normal step no memory.  With every expert
-    held ``R = N * K`` and no second pass is traced.
+    the held experts and half as much again) — the gathers, ``expert_fn``
+    and the sums back are all ``R`` rows.  What an uneven routing puts past
+    ``R`` goes through the same pass a CHUNK of the other sorted rows at a
+    time, a chunk for every 2,048 rows past (``rows_past_bound`` counts the
+    rows, ``remainder_chunks`` the chunks): none on a normal step, one for
+    up to 2,048 rows, so that a row past the bound costs about a row.  The
+    chunks are the trips of a loop under a ``lax.cond``; the loop keeps its
+    inputs alone, and its backward pass walks the same chunks, recomputes
+    each and sums their gradients, so that it costs a normal step no
+    memory.  Where ``R`` is three eighths of the pairs or more, a bound no
+    routing seen has passed, ONE chunk holds all the other rows
+    (:func:`_remainder_chunk` says why) and the remainder is that one pass
+    under the ``cond``, checkpointed.  With every expert held ``R = N * K``
+    and no second pass is traced.
 
-    **What the remainder costs every program** is its TRACING,
-    differentiated under the ``cond``, though almost no step runs it; on
-    the benchmark's cell an ``expert_fn`` of Pallas kernels made that 16 s
-    of warm set-up (PERF.md, PR 27).  Two things keep it small.
-    ``remainder_fn`` (default ``expert_fn``) is what the remainder calls in
-    ``expert_fn``'s place: the same function of ``(rows, group_sizes,
-    *expert_args)``, which may take XLA's own grouped product
+    **What the remainder costs every program** is its TRACING, forward and
+    backward, though almost no step runs it; on the benchmark's cell an
+    ``expert_fn`` of Pallas kernels made that 16 s of warm set-up (PERF.md,
+    PR 27).  Two things keep it small.  ``remainder_fn`` (default
+    ``expert_fn``) is what the remainder calls in ``expert_fn``'s place:
+    the same function of ``(rows, group_sizes, *expert_args)``, which may
+    take XLA's own grouped product
     (``grouped_matmul(impl="ragged_dot")``).  And the remainder is ONE
     jitted function of ``(x, weights, dispatch, expert_args)``: layers that
     pass the same callable (the same object: a module-level function, not a
     closure made per layer) and equal shapes share one trace of it, its
-    derivative included.  A closure over the weights still works, with
-    ``expert_args=()``, and shares nothing.
+    derivative included.  A closure works too, with ``expert_args=()``, and
+    shares nothing; but the loop's backward pass differentiates by its
+    arguments alone, so weights that take a gradient come through
+    ``expert_args`` (JAX refuses the derivative by a closed-over value).
+
+    **What it costs a step that runs it** is by the chunk: a gather of the
+    chunk's rows, ``remainder_fn`` on them and the scatter-add back, again
+    with their derivatives in the backward pass, and one addition into the
+    sums of ``d_x`` and of every ``expert_args`` gradient.
 
     With ``axis_name=None`` the layer runs on one device and exchanges
     nothing: it computes what ITS experts give and nothing stands in for
@@ -614,12 +747,17 @@ def dropless_moe(x, router_logits, expert_bias, expert_fn: Callable, *,
     # names the trace's readers know: docs/observability.md)
     y = _held_part(expert_fn, rows, weights, dispatch, expert_args, 0)
     if bound < pairs:
-        y = y + lax.cond(
-            dispatch.group_sizes.sum() > bound,
-            functools.partial(_remainder, expert_fn=remainder_fn or expert_fn,
-                              bound=bound),
-            lambda x, weights, dispatch, expert_args: jnp.zeros_like(x),
-            x, weights, dispatch, expert_args)
+        remainder = dict(expert_fn=remainder_fn or expert_fn, bound=bound)
+        chunk = _remainder_chunk(pairs, bound)
+        if chunk < pairs - bound:
+            y = y + _remainder_by_chunks(x, weights, dispatch, expert_args,
+                                         chunk=chunk, **remainder)
+        else:
+            y = y + lax.cond(
+                dispatch.group_sizes.sum() > bound,
+                functools.partial(_remainder, **remainder),
+                lambda x, weights, dispatch, expert_args: jnp.zeros_like(x),
+                x, weights, dispatch, expert_args)
     return y, dropless_counters(dispatch, bound)
 
 

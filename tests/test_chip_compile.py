@@ -358,21 +358,28 @@ def test_one_chip_double_buffered_step_keeps_xla_defaults(topo):
     assert "async-collective-start" not in text
 
 
-def test_moe_layer_main_pass_kernels_keep_their_names(topo):
-    """The expert layer at the benchmark cell's sizes, forward and backward,
-    alone.  The trace's readers find the grouped-matmul kernels by name
-    (``moe.<k>``: ``chipbench/layer_metrics/moe_gmm_ms.py::is_gmm``), and a
-    Pallas call is named after the innermost entry of its name stack: the
-    main pass's nine must stay directly under the module, whatever the
-    guarded remainder (under its ``cond`` and ``checkpoint``) runs."""
+@pytest.mark.parametrize("cell,pairs,rows", [
+    ("lfm2-8b-a1b-ep4share-t8192", 98304, 36864),
+    ("trinity-mini-ep8share-t8192", 65536, 12288)])
+def test_moe_layer_main_pass_kernels_keep_their_names(topo, cell, pairs, rows):
+    """The expert layer at a benchmark cell's sizes, forward and backward,
+    alone: a quarter of the experts held (lfm2) and an eighth (Trinity-Mini:
+    the even share and a half, 12,288 of 65,536 rows).  The trace's readers
+    find the grouped-matmul kernels by name (``moe.<k>``:
+    ``chipbench/layer_metrics/moe_gmm_ms.py::is_gmm``), and a Pallas call is
+    named after the innermost entry of its name stack: the main pass's nine
+    must stay directly under the module, whatever the remainder (under its
+    ``cond``, its ``checkpoint`` or its loops) runs."""
     import flax.linen as nn
 
+    from chainermn_tpu.models.afmoe import AfmoeConfig
     from chainermn_tpu.models.lfm2 import LFM2Config, SparseMoE
     from chipbench import reduce_trace, spec
     from chipbench.layer_metrics.moe_gmm_ms import is_gmm
 
-    sizes = spec.resolve("lfm2-8b-a1b-ep4share-t8192").sizes
-    config = LFM2Config.from_dict(
+    sizes = spec.resolve(cell).sizes
+    config = (AfmoeConfig if cell.startswith("trinity") else LFM2Config
+              ).from_dict(
         sizes, num_experts_routed=sizes["num_experts_published"],
         dtype=jnp.dtype(sizes["compute_dtype"]))
     assert config.moe_matmul_impl == "pallas"
@@ -399,14 +406,16 @@ def test_moe_layer_main_pass_kernels_keep_their_names(topo):
                if 'custom_call_target="tpu_custom_call"' in line]
     main_pass = [name for name in kernels if is_gmm(name)]
     # gate, up and down, each forward, dlhs and drhs, over the bound's rows
-    pairs = tokens.shape[0] * tokens.shape[1] * config.num_experts_per_tok
-    assert pairs == 98304 and len(main_pass) == 9, kernels
-    assert sum("[36864," in name for name in main_pass) == 6, main_pass
+    assert pairs == (tokens.shape[0] * tokens.shape[1]
+                     * config.num_experts_per_tok)
+    assert len(main_pass) == 9, kernels
+    assert sum(f"[{rows}," in name for name in main_pass) == 6, main_pass
     # the remainder takes XLA's own grouped product, ``W1`` and ``W3`` as
     # one (``SparseMoE``'s ``remainder_fn``: no Pallas kernel to trace, and
-    # as few kernels to load as the mathematics allows): two in the forward's
-    # branch, two recomputed and four derivatives in the backward's, kernels
-    # the compiler makes and names itself
+    # as few kernels to load as the mathematics allows): two in the forward,
+    # two recomputed and four derivatives in the backward, kernels the
+    # compiler makes and names itself, over all the other rows under a
+    # ``cond`` (lfm2) or a chunk's 2,048 under the loops (Trinity-Mini)
     remainder = [name for name in kernels if not is_gmm(name)]
     assert all(name.startswith("ragged-dot") for name in remainder), kernels
     assert sum(name.startswith("ragged-dot-none")

@@ -3,6 +3,7 @@ up to the whole layer (what every chip computes alike, a shared expert,
 counted once), nothing is dropped at any imbalance, the bias steers the
 selection only, and it holds only its own experts' weights."""
 
+import functools
 import json
 import os
 
@@ -311,20 +312,33 @@ def test_the_configuration_states_its_share_and_the_deployment():
 
 
 # ---------------------------------------------------------------------------
-# the row bound and its guarded remainder
+# the row bound and its remainder: one guarded pass, or a walk by chunks
 # ---------------------------------------------------------------------------
 
 def test_the_bound_is_the_even_share_and_a_half_in_whole_tiles():
-    """... and not under three eighths of the pairs."""
+    """... of shapes alone, with no floor under it."""
     bound = expert.dropless_rows_bound
     assert bound(3 * 8192 * 4, 8, 32) == 36864          # lfm2's cell: 72 tiles
+    assert bound(8192 * 8, 16, 64) == 24576             # mellum's: a quarter too
     assert bound(MANY * TOP_K, 2, ROUTED) == 1024       # 768 -> two tiles
     assert bound(TOKENS * TOP_K, 2, ROUTED) == TOKENS * TOP_K
     assert bound(3 * 8192 * 4, 32, 32) == 3 * 8192 * 4  # all held: no bound
     assert bound(4096, 1, 3) == 2048
-    # an eighth held (Trinity-Mini's cell): the floor, not 12,288
-    assert bound(8192 * 8, 16, 128) == 24576 == 3 * 8192 * 8 // 8
-    assert bound(8192, 1, 64) == 3072
+    # an eighth held (Trinity-Mini's cell): 1.5 x 8,192, not three eighths
+    assert bound(8192 * 8, 16, 128) == 12288 == 3 * 8192 * 8 // 16
+    assert bound(8192, 1, 64) == 512                    # 192 -> one tile
+
+
+def test_the_remainders_chunk_is_four_tiles_under_three_eighths_of_the_pairs():
+    """... and all the other rows from three eighths on, the bound of a
+    quarter's share, which no routing seen has passed."""
+    chunk = expert._remainder_chunk
+    assert chunk(8192 * 8, 12288) == 2048           # Trinity-Mini's cell
+    assert (8192 * 8 - 12288) % 2048 == 0           # ... whole chunks
+    assert chunk(8192 * 8, 24576) == 8192 * 8 - 24576       # mellum's
+    assert chunk(3 * 8192 * 4, 36864) == 3 * 8192 * 4 - 36864   # lfm2's
+    assert chunk(8192, 512) == 2048 and chunk(2048, 512) == 1536
+    assert chunk(MANY * TOP_K, 1024) == 1024        # these tests' layers
 
 
 def _unbounded(monkeypatch):
@@ -333,31 +347,121 @@ def _unbounded(monkeypatch):
                         lambda pairs, held, routed: pairs)
 
 
-def _share_outputs(share, x):
-    """``y``, the counters, and the gradients of a scalar of ``y`` by the
-    tokens and by every leaf of the share."""
+# the tests' layers have 2048 pairs and a bound of 1024 rows, half the
+# pairs: a bound whose remainder is one pass.  ``small_chunks`` makes it walk
+# chunks of 384 rows all the same: the other 1024 are two whole chunks and a
+# third that is clamped to the last 384 rows (128 of them the second chunk's)
+CHUNK = 384
+
+
+def _chunks_of(rows):
+    return lambda pairs, bound: min(rows, pairs - bound)
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    monkeypatch.setattr(expert, "_remainder_chunk", _chunks_of(CHUNK))
+
+
+def _swiglu(rows, sizes, w1, w3, w2):
+    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+
+    product = lambda a, w: grouped_matmul(a, w, sizes, "ragged_dot")
+    return product(jax.nn.silu(product(rows, w1)) * product(rows, w3), w2)
+
+
+def _crafted_logits(held_pairs, x):
+    """Logits that send EXACTLY ``held_pairs`` pairs to the held experts 2
+    and 3: the first ``held_pairs // 2`` tokens pick both, one more picks
+    expert 2 and an absent one if the count is odd, the rest pick the absent
+    experts 0 and 1."""
+    token = np.arange(x.shape[0])[:, None]
+    both, odd = held_pairs // 2, held_pairs % 2
+    favoured = np.where(token < both, [[2, 3]],
+                        np.where(token < both + odd, [[2, 0]], [[0, 1]]))
+    return jnp.asarray(
+        (np.arange(ROUTED)[None, None, :] == favoured[:, :, None]).any(1)
+        * 6.0 - 3.0) + 0.1 * x[:, :ROUTED]
+
+
+def _crafted_layer(held_pairs, x, gate, stacks):
+    """``dropless_moe`` over experts 2 and 3 on :func:`_crafted_logits` plus
+    a router's product (so that the router has a gradient): a scalar of
+    ``y``, and ``(y, counters)``."""
+    logits = _crafted_logits(held_pairs, jax.lax.stop_gradient(x)) \
+        + 0.01 * x @ gate
+    y, counters = expert.dropless_moe(
+        x, logits, None, _swiglu,
+        expert_args=(stacks["w1"], stacks["w3"], stacks["w2"]),
+        num_experts=ROUTED, top_k=TOP_K, first_expert=2, held_experts=2)
+    return jnp.sum(jnp.sin(y)), (y, counters)
+
+
+def _crafted_outputs(held_pairs, x, gate, stacks):
+    """``y``, the counters, and the gradients of the scalar by the tokens,
+    the router and every expert stack."""
+    grads, (y, counters) = jax.grad(
+        lambda *inputs: _crafted_layer(held_pairs, *inputs), (0, 1, 2),
+        has_aux=True)(x, gate, stacks)
+    return y, counters, grads
+
+
+# rows past the bound: none, one, part of a chunk, exactly one chunk, several
+# chunks with a clamped last one, every pair held
+@pytest.mark.parametrize("past,chunks", [
+    (0, 0), (1, 1), (100, 1), (CHUNK, 1), (CHUNK + 1, 2), (1000, 3),
+    (1024, 3)])
+def test_rows_past_the_bound_go_through_the_remainder_by_the_chunk(
+        monkeypatch, small_chunks, past, chunks):
+    """``y`` and the gradients by ``x``, the router and every expert stack
+    are the unbounded layer's, nothing is dropped, and the remainder took
+    the chunks that hold the rows past the bound and no more."""
+    params, x = _whole_layer(tokens=MANY)
+    x, stacks = x[0], {w: params[w][2:4] for w in ("w1", "w3", "w2")}
+    gate = params["gate"]["kernel"]
+
+    y, counters, grads = _crafted_outputs(1024 + past, x, gate, stacks)
+    assert float(counters["tokens_per_held_expert"].sum()) == 1024.0 + past
+    assert float(counters["rows_bound"]) == 1024.0
+    assert float(counters["rows_past_bound"]) == past
+    assert float(counters["remainder_chunks"]) == chunks
+    assert float(counters["dropped_pairs"]) == 0.0
+    _unbounded(monkeypatch)
+    y_all, counters_all, grads_all = _crafted_outputs(
+        1024 + past, x, gate, stacks)
+    assert float(counters_all["rows_past_bound"]) == 0.0
+    assert float(counters_all["remainder_chunks"]) == 0.0
+    np.testing.assert_allclose(y, y_all, rtol=1e-5, atol=1e-5)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_all)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+        assert np.abs(np.asarray(want)).sum() > 0
+
+
+@pytest.mark.parametrize("chunk", [CHUNK, 1024])
+def test_a_routing_forced_past_the_bound_is_the_references_layer(
+        monkeypatch, chunk):
+    """Every token picks the two held experts: 2048 held pairs against a
+    bound of 1024 rows, in chunks of 384 (three, the last clamped) and in the
+    one chunk of all the other rows that the layer's own rule makes here.
+    ``y``, ``dx``, the router's, the weights' and the expert stacks'
+    gradients are the reference's (which applies every held expert to every
+    token)."""
+    from chipbench.references.common import Products
+
+    if chunk == CHUNK:
+        monkeypatch.setattr(expert, "_remainder_chunk", _chunks_of(chunk))
+    params, x = _whole_layer(tokens=MANY)
+    bias = jnp.full((ROUTED,), -10.0).at[2].set(10.0).at[3].set(10.0)
+    share = dict(_share(params, 2, 2), expert_bias=bias)
+
     def scalar(p, x):
         y, counters = _module(2, 2).apply({"params": p}, x)
         return jnp.sum(jnp.sin(y)), (y, counters)
 
     grads, (y, counters) = jax.grad(scalar, (0, 1), has_aux=True)(share, x)
-    return y, counters, grads
-
-
-def test_a_routing_forced_past_the_bound_runs_the_remainder_and_drops_nothing(
-        monkeypatch):
-    """Every token picks the two held experts: 2048 held pairs against a
-    bound of 1024 rows.  ``y``, ``dx``, the router's, the weights' and the
-    expert stacks' gradients are the unbounded layer's and the reference's
-    (which applies every held expert to every token)."""
-    from chipbench.references.common import Products
-
-    params, x = _whole_layer(tokens=MANY)
-    bias = jnp.full((ROUTED,), -10.0).at[2].set(10.0).at[3].set(10.0)
-    share = dict(_share(params, 2, 2), expert_bias=bias)
-    y, counters, grads = _share_outputs(share, x)
     assert float(counters["rows_bound"]) == 1024.0
     assert float(counters["rows_past_bound"]) == MANY * TOP_K - 1024.0
+    assert float(counters["remainder_chunks"]) == -(-1024 // chunk)
     assert float(counters["dropped_pairs"]) == 0.0
     assert float(counters["held_share"]) == 1.0
 
@@ -367,54 +471,108 @@ def test_a_routing_forced_past_the_bound_runs_the_remainder_and_drops_nothing(
     np.testing.assert_allclose(
         y, _reference().sparse_moe(x, share, _sizes(2, 2), Products()),
         rtol=1e-5, atol=1e-5)
-    _unbounded(monkeypatch)
-    y_all, counters_all, grads_all = _share_outputs(share, x)
-    assert float(counters_all["rows_past_bound"]) == 0.0
     # (the remainder takes W1 and W3 as one product: float32 sums in another
     # order)
-    np.testing.assert_allclose(y, y_all, rtol=1e-5, atol=1e-5)
-    for got, unbounded, ref in zip(*map(jax.tree.leaves,
-                                        (grads, grads_all, want))):
-        np.testing.assert_allclose(got, unbounded, rtol=1e-4, atol=1e-4)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
         np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
     assert not np.asarray(grads[0]["expert_bias"]).any()
 
 
-def _crafted_layer(held_pairs, x, stacks):
-    """``dropless_moe`` on logits that send EXACTLY ``held_pairs`` pairs to
-    the held experts 2 and 3: the first ``held_pairs // 2`` tokens pick
-    both, one more picks expert 2 and an absent one if the count is odd,
-    the rest pick the absent experts 0 and 1."""
-    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+def test_the_remainder_runs_where_the_weights_do_not_vary_over_the_mesh(
+        small_chunks):
+    """Inside a ``shard_map`` that checks varying axes, tokens split over the
+    devices and the expert stacks the same on all: each device walks its own
+    chunks, and the stacks' gradient is the sum over the devices."""
+    from jax.sharding import Mesh, PartitionSpec as P
 
-    token = np.arange(x.shape[0])[:, None]
-    both, odd = held_pairs // 2, held_pairs % 2
-    favoured = np.where(token < both, [[2, 3]],
-                        np.where(token < both + odd, [[2, 0]], [[0, 1]]))
-    logits = jnp.asarray(
-        (np.arange(ROUTED)[None, None, :] == favoured[:, :, None]).any(1)
-        * 6.0 - 3.0) + 0.1 * x[:, :ROUTED]
+    params, x = _whole_layer(tokens=2 * MANY)
+    x, stacks = x[0], {w: params[w][2:4] for w in ("w1", "w3", "w2")}
+    gate = params["gate"]["kernel"]
+    # the first device's tokens pass the bound by 500 rows, the second's by
+    # none
+    past = (500, 0)
+    halves = [x[:MANY], x[MANY:]]
 
-    def experts(rows, sizes):
-        product = lambda a, w: grouped_matmul(a, w, sizes, "ragged_dot")
-        return product(jax.nn.silu(product(rows, stacks["w1"]))
-                       * product(rows, stacks["w3"]), stacks["w2"])
+    def scalar(x, gate, stacks, held_pairs):
+        value, (_, counters) = _crafted_layer(held_pairs, x, gate, stacks)
+        return value, counters["remainder_chunks"]
 
+    want = [jax.grad(scalar, (0, 2), has_aux=True)(
+        half, gate, stacks, 1024 + rows) for half, rows in zip(halves, past)]
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+
+    def body(x, gate, stacks):
+        def local(x, stacks):
+            both = [scalar(x, gate, stacks, 1024 + rows) for rows in past]
+            mine = jax.lax.axis_index("data")
+            return (jnp.where(mine == 0, both[0][0], both[1][0]),
+                    jnp.where(mine == 0, both[0][1], both[1][1]))
+
+        (d_x, d_stacks), chunks = jax.grad(local, (0, 1), has_aux=True)(
+            x, stacks)
+        return d_x, d_stacks, chunks[None]
+
+    d_x, d_stacks, chunks = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("data"), P(), P()),
+        out_specs=(P("data"), P(), P("data"))))(x, gate, stacks)
+    assert np.asarray(chunks).tolist() == [2.0, 0.0]
+    np.testing.assert_allclose(
+        d_x, jnp.concatenate([w[0][0] for w in want]), rtol=1e-4, atol=1e-4)
+    for name in stacks:
+        np.testing.assert_allclose(
+            d_stacks[name], want[0][0][1][name] + want[1][0][1][name],
+            rtol=1e-4, atol=1e-4)
+
+
+def test_chunks_refuse_an_expert_fn_that_closes_over_weights_it_differentiates(
+        small_chunks):
+    """The loop's backward pass differentiates by its arguments alone:
+    weights that take a gradient come through ``expert_args`` (the one-pass
+    remainder takes such a closure: ``_crafted_closure_layer`` below)."""
+    params, x = _whole_layer(tokens=MANY)
+    x, w2 = x[0], params["w2"][2:4]
+
+    def scalar(w1):
+        experts = lambda rows, sizes: _swiglu(rows, sizes, w1, w1, w2)
+        return jnp.sum(expert.dropless_moe(
+            x, _crafted_logits(1100, x), None, experts, num_experts=ROUTED,
+            top_k=TOP_K, first_expert=2, held_experts=2)[0])
+
+    with pytest.raises(Exception, match="closed-over"):
+        jax.grad(scalar)(params["w1"][2:4])
+    # ... a closure over weights that take none is as good as arguments
+    w1 = params["w1"][2:4]
+    y, counters = expert.dropless_moe(
+        x, _crafted_logits(1100, x), None,
+        lambda rows, sizes: _swiglu(rows, sizes, w1, w1, w2),
+        num_experts=ROUTED, top_k=TOP_K, first_expert=2, held_experts=2)
+    assert float(counters["remainder_chunks"]) == 1.0
+    assert np.isfinite(np.asarray(y)).all()
+
+
+def _crafted_closure_layer(held_pairs, x, stacks):
+    """``dropless_moe`` over experts 2 and 3 on :func:`_crafted_logits`, with
+    an ``expert_fn`` that closes over the stacks."""
     return expert.dropless_moe(
-        x, logits, None, experts, num_experts=ROUTED, top_k=TOP_K,
-        first_expert=2, held_experts=2)
+        x, _crafted_logits(held_pairs, x), None,
+        lambda rows, sizes: _swiglu(rows, sizes, stacks["w1"], stacks["w3"],
+                                    stacks["w2"]),
+        num_experts=ROUTED, top_k=TOP_K, first_expert=2, held_experts=2)
 
 
 @pytest.mark.parametrize("past", [0, 1, 7])
 def test_a_routing_at_the_bound_and_just_past_it(monkeypatch, past):
-    """1024 held pairs fill the bound exactly and the remainder stays out;
-    one more and it runs over the one row."""
+    """The one-pass remainder (these layers' own, of an ``expert_fn`` that
+    closes over the stacks it differentiates): 1024 held pairs fill the bound
+    exactly and the remainder stays out; one more and it runs over the one
+    row."""
     params, x = _whole_layer(tokens=MANY)
     x, stacks = x[0], _share(params, 2, 2)
 
     def outputs():
         def scalar(x, stacks):
-            y, counters = _crafted_layer(1024 + past, x, stacks)
+            y, counters = _crafted_closure_layer(1024 + past, x, stacks)
             return jnp.sum(jnp.sin(y)), (y, counters)
 
         return jax.grad(scalar, (0, 1), has_aux=True)(x, stacks)
@@ -422,6 +580,7 @@ def test_a_routing_at_the_bound_and_just_past_it(monkeypatch, past):
     grads, (y, counters) = outputs()
     assert float(counters["tokens_per_held_expert"].sum()) == 1024.0 + past
     assert float(counters["rows_past_bound"]) == past
+    assert float(counters["remainder_chunks"]) == (past > 0)
     assert float(counters["dropped_pairs"]) == 0.0
     _unbounded(monkeypatch)
     grads_all, (y_all, _) = outputs()
@@ -431,15 +590,16 @@ def test_a_routing_at_the_bound_and_just_past_it(monkeypatch, past):
     assert np.abs(np.asarray(y_all)).sum() > 0
 
 
-def _equations(jaxpr, into_cond):
-    """Every equation of ``jaxpr`` and of the jaxprs inside its equations;
-    a ``cond``'s branches only with ``into_cond``."""
+def _equations(jaxpr, skip=None):
+    """Every equation of ``jaxpr`` and of the jaxprs inside its equations,
+    but for what lies inside the equations of the primitive ``skip`` (a
+    ``cond``'s branches, a ``while``'s body)."""
     for eqn in jaxpr.eqns:
         yield eqn
-        if eqn.primitive.name == "cond" and not into_cond:
+        if eqn.primitive.name == skip:
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub, into_cond)
+            yield from _equations(sub, skip)
 
 
 def _layer_gradient_jaxpr(held, tokens):
@@ -457,10 +617,11 @@ def test_an_even_routing_stays_under_the_bound_in_rows_of_the_bound():
     _, counters = _module(2, 2).apply({"params": _share(params, 2, 2)}, x)
     assert 0.0 < float(counters["held_share"]) < 0.5
     assert float(counters["rows_past_bound"]) == 0.0
+    assert float(counters["remainder_chunks"]) == 0.0
 
     pairs, bound = MANY * TOP_K, 1024
     jaxpr = _layer_gradient_jaxpr(2, MANY).jaxpr
-    main = list(_equations(jaxpr, into_cond=False))
+    main = list(_equations(jaxpr, skip="cond"))
     shapes = {tuple(v.aval.shape) for eqn in main for v in eqn.outvars}
     per_pair = {s for s in shapes if len(s) >= 2 and s[-1] > TOP_K and (
         s[0] == pairs or s[:2] == (MANY, TOP_K))}
@@ -469,15 +630,43 @@ def test_an_even_routing_stays_under_the_bound_in_rows_of_the_bound():
     # ... and the remainder is there, under its guard, with the other rows
     assert sum(eqn.primitive.name == "cond" for eqn in main) >= 1
     everything = {tuple(v.aval.shape)
-                  for eqn in _equations(jaxpr, into_cond=True)
+                  for eqn in _equations(jaxpr)
                   for v in eqn.outvars}
     assert (pairs - bound, WIDTH) in everything
 
 
+def test_an_even_routing_stays_under_the_bound_where_the_remainder_walks_chunks(
+        small_chunks):
+    """No value of the main pass, forward or backward, has a row for every
+    pair and a feature dimension: what carries features is ``bound`` rows
+    (the remainder's, inside its loops, a chunk's)."""
+    params, x = _whole_layer(tokens=MANY, bias_std=0.05)
+    _, counters = _module(2, 2).apply({"params": _share(params, 2, 2)}, x)
+    assert 0.0 < float(counters["held_share"]) < 0.5
+    assert float(counters["rows_past_bound"]) == 0.0
+    assert float(counters["remainder_chunks"]) == 0.0
+
+    pairs, bound = MANY * TOP_K, 1024
+    jaxpr = _layer_gradient_jaxpr(2, MANY).jaxpr
+    main = list(_equations(jaxpr, skip="while"))
+    shapes = {tuple(v.aval.shape) for eqn in main for v in eqn.outvars}
+    per_pair = {s for s in shapes if len(s) >= 2 and s[-1] > TOP_K and (
+        s[0] == pairs or s[:2] == (MANY, TOP_K))}
+    assert not per_pair, per_pair
+    assert (bound, HIDDEN) in shapes and (bound, WIDTH) in shapes
+    assert (CHUNK, WIDTH) not in shapes
+    # ... and the remainder is there, a loop forward and a loop backward,
+    # with a chunk's rows
+    assert sum(eqn.primitive.name == "while" for eqn in main) == 2
+    everything = {tuple(v.aval.shape)
+                  for eqn in _equations(jaxpr)
+                  for v in eqn.outvars}
+    assert (CHUNK, WIDTH) in everything and (CHUNK, HIDDEN) in everything
+
+
 def test_a_layer_that_holds_every_expert_traces_no_second_pass():
-    eqns = list(_equations(_layer_gradient_jaxpr(ROUTED, MANY).jaxpr,
-                           into_cond=True))
-    assert not any(eqn.primitive.name == "cond" for eqn in eqns)
+    eqns = list(_equations(_layer_gradient_jaxpr(ROUTED, MANY).jaxpr))
+    assert not any(eqn.primitive.name in ("cond", "while") for eqn in eqns)
     shapes = {tuple(v.aval.shape) for eqn in eqns for v in eqn.outvars}
     assert (MANY * TOP_K, WIDTH) in shapes
 
@@ -488,11 +677,8 @@ _TRACED_ROWS = []
 def _counted_experts(rows, group_sizes, w1, w3, w2):
     """A module-level ``expert_fn`` (one object for every layer) that notes
     each time it is traced."""
-    from chainermn_tpu.ops.grouped_matmul import grouped_matmul
-
     _TRACED_ROWS.append(rows.shape[0])
-    product = lambda a, w: grouped_matmul(a, w, group_sizes, "ragged_dot")
-    return product(jax.nn.silu(product(rows, w1)) * product(rows, w3), w2)
+    return _swiglu(rows, group_sizes, w1, w3, w2)
 
 
 def test_layers_that_pass_one_callable_share_the_remainders_trace():
@@ -521,3 +707,43 @@ def test_layers_that_pass_one_callable_share_the_remainders_trace():
     assert _TRACED_ROWS == [1024, 1024, 1024], _TRACED_ROWS
     assert all(np.abs(np.asarray(g)).sum() > 0
                for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("layers", [2, 3])
+def test_layers_that_pass_one_callable_share_the_chunked_remainders_trace(
+        small_chunks, layers):
+    """Every program pays for tracing the remainder, forward and backward:
+    layers with one ``expert_fn`` object and equal shapes trace it as often
+    as ONE layer does (it is one jitted function of the weights), where a
+    closure made per layer would trace it for each."""
+    params, x = _whole_layer(tokens=MANY)
+    stacks = [tuple(0.3 * jax.random.normal(jax.random.key(layer + i),
+                                            params[name][2:4].shape)
+                    for i, name in enumerate(("w1", "w3", "w2")))
+              for layer in (10, 20, 30)]
+
+    def traced_by(stacks):
+        # a new object a program: what an earlier test traced is not found
+        experts = functools.partial(_counted_experts)
+
+        def program(x, stacks):
+            for layer in stacks:
+                y, _ = expert.dropless_moe(
+                    x, x @ params["gate"]["kernel"], None, experts,
+                    expert_args=layer, num_experts=ROUTED, top_k=TOP_K,
+                    first_expert=2, held_experts=2)
+                x = x + y
+            return jnp.sum(jnp.sin(x))
+
+        del _TRACED_ROWS[:]
+        grads = jax.jit(jax.grad(program, (0, 1)))(x[0], stacks)
+        assert all(np.abs(np.asarray(g)).sum() > 0
+                   for g in jax.tree.leaves(grads))
+        return list(_TRACED_ROWS)
+
+    one, several = traced_by(stacks[:1]), traced_by(stacks[:layers])
+    # the main pass of each layer, and the remainder's chunk as often as one
+    # layer traces it
+    assert one.count(1024) == 1 and several.count(1024) == layers
+    assert 1 <= one.count(CHUNK) == several.count(CHUNK) <= 3
+    assert set(several) == {1024, CHUNK}
