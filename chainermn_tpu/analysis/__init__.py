@@ -24,6 +24,7 @@ from chainermn_tpu.analysis.captured import (
     assert_no_captured_constants,
     find_captured_constants,
 )
+from chainermn_tpu.analysis.compiled import compiled_step_census
 from chainermn_tpu.analysis.hlo import (
     HloCollective,
     HloParse,
@@ -68,8 +69,9 @@ __all__ = [
     "LintContext", "LintError", "LintReport", "ProtocolModel",
     "all_reduce_overlap_census", "all_rules", "allreduce_hlo",
     "assert_no_captured_constants", "build_grad_probe",
-    "collective_census", "expected_kinds", "extract_protocol",
-    "extract_schedule", "find_captured_constants", "get_rule",
+    "collective_census", "compiled_step_census", "expected_kinds",
+    "extract_protocol", "extract_schedule", "find_captured_constants",
+    "get_rule",
     "lint_step", "load_events_by_rank", "parse_hlo_collectives",
     "replay_flight", "rule", "schedule_from_hlo",
 ]

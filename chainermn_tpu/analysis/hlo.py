@@ -57,13 +57,13 @@ _ASYNC_DONE = tuple(k + "-done" for k in COLLECTIVE_KINDS)
 
 # name = shape op(...) — the shape is either a tuple (...) or one token; a
 # TPU layout holds parentheses of its own (``{1,0:T(8,128)(2,1)}``), so a
-# tuple ends where the op begins
-_OP_RE = re.compile(
-    r"(?P<name>%[\w.\-]+|[\w.\-]+)\s*=\s*"
-    r"(?P<shape>\(.*?\)|\S+)\s+"
-    r"(?P<op>" + "|".join(
-        re.escape(k) + "(?:-start|-done)?" for k in COLLECTIVE_KINDS)
-    + r")\(")
+# tuple ends where the op begins.  The one instruction pattern of this
+# package: ``compiled.py`` reads with it too
+_INSTRUCTION_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?(?P<name>%?[\w.\-]+)\s+=\s+"
+    r"(?P<shape>\(.*?\)|\S+)\s+(?P<op>[a-z][\w\-]*)\(")
+_COLLECTIVE_OPS = frozenset(
+    kind + edge for kind in COLLECTIVE_KINDS for edge in ("", "-start", "-done"))
 
 # a new instruction binding starts a logical line; the HLO printer
 # renders bindings with a SPACED " = " while instruction attributes
@@ -193,8 +193,8 @@ def parse_hlo_collectives(hlo_text: str) -> HloParse:
         if header:
             owner = header.group(1)
             continue
-        m = _OP_RE.search(line)
-        if not m:
+        m = _INSTRUCTION_RE.match(line)
+        if not m or m.group("op") not in _COLLECTIVE_OPS:
             continue
         opname = m.group("op")
         # a collective inside an asynchronous-collective fusion chain is
