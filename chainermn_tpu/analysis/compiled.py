@@ -4,8 +4,9 @@
 compiled program and no trace: how many instructions run a second time
 (XLA's rematerialised clones and what ``jax.checkpoint`` recomputes, by the
 module they belong to), how many copies the compiler put in (by kind, with
-the bytes they move), and how many values it placed in the chip's fast
-memory (``S(1)`` in a result's layout).  It is a ``program_counter`` beside
+the bytes they move), how many values it placed in the chip's fast
+memory (``S(1)`` in a result's layout), and how many Pallas kernels the
+step holds under each name the chip's trace will show them by.  It is a ``program_counter`` beside
 ``all_reduce_overlap_census`` and counts INSTRUCTIONS, not time: what the
 clones and copies cost on the device is read from a trace
 (``forward_recompute_ratio``, ``compiler_copy_ms``: ``chipbench/parts.py``).
@@ -35,6 +36,9 @@ _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 # one array of a result shape with its layout: f32[8,128]{1,0:T(8,128)S(1)}
 _ARRAY_RE = re.compile(r"(\w+)\[([0-9,]*)\](\{[^{}]*\})?")
 _NUMBERED = re.compile(r"_\d+\b")
+_SERIAL = re.compile(r"\.\d+$")
+_KERNEL = 'custom_call_target="tpu_custom_call"'
+_PALLAS = "pallas_call"
 _CLONE = ".remat"
 _RECOMPUTED = "rematted_computation"
 COPY_KINDS = ("copy", "copy-start", "copy-done")
@@ -84,7 +88,12 @@ def compiled_step_census(hlo_text: str) -> dict:
     ``copy_bytes`` (what ``copy`` and ``copy-done`` results hold: each moved
     buffer once); ``fast_memory_values`` (result arrays whose layout says
     ``S(1)``: a count of placements over the whole program, not a size that
-    is live at once)."""
+    is live at once); ``pallas_kernels_by_module`` (the ``tpu_custom_call``
+    instructions that a ``pallas_call`` lowered to, by the name the compiler
+    gave them less its serial number, which is the innermost module or scope
+    around the call and what a reader of the chip's trace finds them by:
+    ``{"swa": 12, "moe": 36, "chainermn.rope": 20}``; XLA's own kernels,
+    ``ragged-dot-*``, are not counted)."""
     lines = _logical_lines(hlo_text)
     fused = {found.group(1) for found in map(_FUSED_RE.search, lines)
              if found}
@@ -94,7 +103,7 @@ def compiled_step_census(hlo_text: str) -> dict:
               "checkpoint_recomputed_by_module": {},
               "copies": dict.fromkeys(COPY_KINDS, 0),
               "copies_without_op_name": 0, "copy_bytes": 0,
-              "fast_memory_values": 0}
+              "fast_memory_values": 0, "pallas_kernels_by_module": {}}
     inside_fusion = False
     for line in lines:
         header = _COMPUTATION_RE.match(line)
@@ -114,6 +123,9 @@ def compiled_step_census(hlo_text: str) -> dict:
             census["checkpoint_recomputed"] += 1
             _count(census["checkpoint_recomputed_by_module"],
                    _module(op_name))
+        if _KERNEL in line and op_name.rsplit("/", 1)[-1] == _PALLAS:
+            _count(census["pallas_kernels_by_module"],
+                   _SERIAL.sub("", found.group("name").lstrip("%")))
         arrays = list(_arrays(found.group("shape")))
         if found.group("op") in COPY_KINDS:
             census["copies"][found.group("op")] += 1

@@ -28,7 +28,7 @@ for the experts held elsewhere.
 
 What the mathematics shares with LFM2-MoE is that model's code:
 :class:`~chainermn_tpu.models.lfm2.RMSNorm`, :func:`~chainermn_tpu.models.
-lfm2.rope`, :class:`~chainermn_tpu.models.lfm2.DenseFFN` and
+lfm2.qk_norm_and_rope`, :class:`~chainermn_tpu.models.lfm2.DenseFFN` and
 :class:`~chainermn_tpu.models.lfm2.SparseMoE` (so
 :func:`chainermn_tpu.parallel.expert.dropless_moe` and the grouped-matmul
 kernels), and :func:`chainermn_tpu.ops.flash_attention` with its ``window``.
@@ -36,8 +36,9 @@ kernels), and :func:`chainermn_tpu.ops.flash_attention` with its ``window``.
 Scopes (docs/observability.md): the attention modules are named ``swa``
 (window layers) and ``nope`` (full layers), so the chip's trace names their
 flash kernels ``swa.<k>`` and ``nope.<k>``; ``chainermn.attn_gate`` holds
-the sigmoid gate, ``chainermn.rope`` the rotation, ``chainermn.moe.{
-dispatch,experts,combine,shared}`` the expert layer's parts, and
+the sigmoid gate, ``chainermn.rope`` the rotation (with the kernels on,
+QK-norm and rotation as one kernel, ``chainermn.rope.<k>``, on the full
+layers the norm alone), ``chainermn.moe.{dispatch,experts,combine,shared}`` the expert layer's parts, and
 ``chainermn.moe.afmoe_route`` the routing (its own name: the benchmark's
 ``moe_route_ms`` reads ``chainermn.moe.route`` wherever it runs, and an
 accepted test lets only a cell with short convolutions report it).
@@ -55,7 +56,7 @@ import jax.numpy as jnp
 
 from chainermn_tpu.models.lfm2 import (DenseFFN, RMSNorm, SparseMoE, _dense,
                                        causal_attention, config_from_dict,
-                                       rope)
+                                       qk_norm_and_rope)
 
 LAYER_TYPES = ("sliding_attention", "full_attention")
 
@@ -148,12 +149,10 @@ class GatedAttention(nn.Module):
         v = split(_dense(kv_heads * head_dim, cfg.dtype, "v_proj")(a),
                   kv_heads)
         gate = _dense(heads * head_dim, cfg.dtype, "gate_proj")(a)
-        q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
-        k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
-        window = None
-        if self.sliding:
-            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-            window = cfg.sliding_window
+        q, k = qk_norm_and_rope(
+            q, k, ("q_norm", "k_norm"), cfg.rms_norm_eps, cfg.dtype,
+            cfg.attention_impl, cfg.rope_theta if self.sliding else None)
+        window = cfg.sliding_window if self.sliding else None
         out = causal_attention(q, k, v, cfg.attention_impl, window)
         with jax.named_scope("chainermn.attn_gate"):
             gated = out.reshape(gate.shape) * nn.sigmoid(gate)

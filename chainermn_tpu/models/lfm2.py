@@ -29,7 +29,9 @@ Scopes (docs/observability.md): ``chainermn.shortconv``, ``chainermn.rope``,
 ``chainermn.moe.{route,dispatch,experts,combine,shared}``; flax names
 ``layer_<n>/conv|attn`` and ``layer_<n>/ffn|moe`` keep a layer's operator
 and feed-forward apart.  The Pallas calls sit directly under their flax
-module, so the chip's trace names them after it.
+module, so the chip's trace names them after it; the one that normalises
+and rotates q and k (:func:`qk_norm_and_rope`, heads of 128) is called under
+``chainermn.rope`` and named after that.
 """
 
 from __future__ import annotations
@@ -155,6 +157,32 @@ def yarn_inv_freq(dim: int, theta: float, scaling):
     return plain * (1.0 - ramp) + plain / scaling["factor"] * ramp
 
 
+def rotary_tables(seq: int, dim: int, theta: float, scaling=None):
+    """``(cos, sin)`` of positions 0..seq-1 as :func:`rope` multiplies by
+    them, each [1, seq, 1, dim] float32 with the angles ``t * inv_freq`` in
+    both halves of ``dim``; under ``yarn`` :func:`yarn_inv_freq`'s
+    frequencies, both times ``attention_factor`` (shape and order of
+    operations are ``rope``'s of old: ``tests/test_mellum.py`` holds its
+    jaxpr)."""
+    kind = "default" if scaling is None else scaling.get(
+        "rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"rope_type must be default|yarn, got {kind!r}")
+    if kind == "yarn":
+        inv_freq = yarn_inv_freq(dim, theta, scaling)
+    else:
+        inv_freq = 1.0 / (theta ** (
+            jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
+    if kind == "yarn":
+        factor = scaling.get("attention_factor") or (
+            0.1 * math.log(scaling["factor"]) + 1.0)
+        cos, sin = cos * jnp.float32(factor), sin * jnp.float32(factor)
+    return cos, sin
+
+
 def rope(x, theta: float, scaling=None):
     """Rotary position embedding on [B, T, H, D], positions 0..T-1, pairing
     (i, i + D/2) ("rotate half"), angles in float32.  ``scaling`` is a
@@ -162,28 +190,58 @@ def rope(x, theta: float, scaling=None):
     or ``rope_type`` ``default`` rotates by ``theta^(-2i/D)``; ``yarn``
     rotates by :func:`yarn_inv_freq` and multiplies cos and sin by
     ``attention_factor`` (q and k alike: the scores scale by its square)."""
-    kind = "default" if scaling is None else scaling.get(
-        "rope_type", "default")
-    if kind not in ("default", "yarn"):
-        raise ValueError(f"rope_type must be default|yarn, got {kind!r}")
     with jax.named_scope("chainermn.rope"):
-        seq, dim = x.shape[1], x.shape[-1]
-        if kind == "yarn":
-            inv_freq = yarn_inv_freq(dim, theta, scaling)
-        else:
-            inv_freq = 1.0 / (theta ** (
-                jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-        angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
-        cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
-        sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
-        if kind == "yarn":
-            factor = scaling.get("attention_factor") or (
-                0.1 * math.log(scaling["factor"]) + 1.0)
-            cos, sin = cos * jnp.float32(factor), sin * jnp.float32(factor)
+        dim = x.shape[-1]
+        cos, sin = rotary_tables(x.shape[1], dim, theta, scaling)
         x32 = x.astype(jnp.float32)
         half = dim // 2
         rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
         return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+class _NormScale(nn.Module):
+    """The parameter of the :class:`RMSNorm` of the same name, alone: what
+    the fused kernel normalises by (the tree keeps ``<name>/scale``)."""
+
+    @nn.compact
+    def __call__(self, dim):
+        return self.param("scale", nn.initializers.ones_init(), (dim,),
+                          jnp.float32)
+
+
+def qk_norm_and_rope(q, k, names, eps, dtype, impl: str,
+                     theta: Optional[float] = None, scaling=None):
+    """What an attention layer does to q [B, T, H, D] and k [B, T, G, D]
+    before the scores: RMS-normalise each over D by the scale of its norm
+    module (``names``: the two modules' names, one scale each, shared by the
+    heads), then rotate both as :func:`rope` does (``theta`` None: a layer
+    that does not rotate).  Call it from the attention module's
+    ``__call__``: the norm modules become its children.
+
+    Which form runs is read off what is at hand: where the Pallas kernels
+    are on (``impl == "flash"``) and D is a multiple of the chip's 128
+    lanes, norm and rotation are ONE pass forward and one backward
+    (:func:`chainermn_tpu.ops.qk_norm_rope.qk_norm_rope`, a kernel for q and
+    one for k under ``chainermn.rope``, whose name the benchmark reads as
+    ``norm_rope_ms``: docs/observability.md); elsewhere (the CPU, the
+    ``xla`` references, heads of 64) the plain float32 modules, which are
+    that kernel's oracle."""
+    if impl == "flash" and q.shape[-1] % 128 == 0:
+        from chainermn_tpu.ops.qk_norm_rope import qk_norm_rope
+
+        seq, dim = q.shape[1], q.shape[-1]
+        scales = [_NormScale(name=name)(dim) for name in names]
+        with jax.named_scope("chainermn.rope"):
+            rotary = None if theta is None else [
+                table.reshape(seq, dim)
+                for table in rotary_tables(seq, dim, theta, scaling)]
+            return [qk_norm_rope(x, scale, rotary, eps=eps, dtype=dtype)
+                    for x, scale in zip((q, k), scales)]
+    q, k = (RMSNorm(eps, dtype, name=name)(x)
+            for x, name in zip((q, k), names))
+    if theta is None:
+        return q, k
+    return rope(q, theta, scaling), rope(k, theta, scaling)
 
 
 def causal_attention(q, k, v, impl: str, window: Optional[int] = None):
@@ -248,9 +306,9 @@ class Attention(nn.Module):
                   kv_heads)
         v = split(_dense(kv_heads * head_dim, cfg.dtype, "v_proj")(u),
                   kv_heads)
-        q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_layernorm")(q)
-        k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_layernorm")(k)
-        q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+        q, k = qk_norm_and_rope(
+            q, k, ("q_layernorm", "k_layernorm"), cfg.norm_eps, cfg.dtype,
+            cfg.attention_impl, cfg.rope_theta)
         out = causal_attention(q, k, v, cfg.attention_impl)
         return _dense(d, cfg.dtype, "out_proj")(out.reshape(u.shape))
 

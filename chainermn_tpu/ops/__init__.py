@@ -13,6 +13,7 @@ from chainermn_tpu.ops.fused_norm import (
     resnet_bn_traffic_bytes,
 )
 from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+from chainermn_tpu.ops.qk_norm_rope import qk_norm_rope
 
 __all__ = [
     "flash_attention",
@@ -20,6 +21,7 @@ __all__ = [
     "fused_norm",
     "fused_norm_reference",
     "grouped_matmul",
+    "qk_norm_rope",
     "FusedBatchNormAct",
     "fused_norm_traffic_bytes",
     "resnet_bn_traffic_bytes",

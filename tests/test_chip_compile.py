@@ -420,3 +420,76 @@ def test_moe_layer_main_pass_kernels_keep_their_names(topo, cell, pairs, rows):
     assert all(name.startswith("ragged-dot") for name in remainder), kernels
     assert sum(name.startswith("ragged-dot-none")
                for name in remainder) == 8, remainder
+
+
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_FLOAT32 = re.compile(r" = f32\[([0-9,]+)\]")
+
+
+@pytest.mark.parametrize("cell,two_layers,kernels", [
+    ("mellum2-ep4share-t8192",
+     dict(layer_types=["sliding_attention", "full_attention"],
+          mlp_layer_types=["sparse"] * 2), 8),
+    # one layer that rotates (and is dense), one ``nope`` that does not
+    ("trinity-mini-ep8share-t8192",
+     dict(layer_types=["sliding_attention", "full_attention"]), 8),
+    # heads of 64: the plain form, no such kernel
+    ("lfm2-8b-a1b-ep4share-t8192",
+     dict(layer_types=["conv", "full_attention"]), 0)])
+def test_qk_norm_and_rotation_kernels_are_read_as_norm_rope(
+        topo, cell, two_layers, kernels):
+    """Two layers of a MoE cell's model at the cell's widths, loss and
+    gradient, with the Pallas kernels on.  Where a head fills the lanes
+    (mellum, Trinity-Mini) QK-norm and rotation are ONE kernel a pass and a
+    tensor (q and k, forward and backward: four a layer, a layer that does
+    not rotate too), named ``chainermn.rope.<k>`` after the scope they are
+    called under: the benchmark's account of a step (``chipbench/parts.py``)
+    puts each in ``norm_rope_ms``, no flash reader takes one for its own,
+    nothing of q's size is left in float32 under ``chainermn.rope`` or a
+    QK-norm module, and ``compiled_step_census`` counts them; lfm2's heads
+    of 64 keep the plain form and the census reads none."""
+    from chainermn_tpu.analysis import compiled_step_census
+    from chipbench import parts, reduce_trace, spec
+
+    found = spec.resolve(cell)
+    sizes = dict(found.sizes, vocab_size=4096, **two_layers)
+    assert sizes["attention_impl"] == "flash"
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                             sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32)
+    grad = jax.value_and_grad(found.family.loss_fn(sizes))
+    text = _compile(grad, jax.tree.map(
+        on_chip, (found.family.param_shapes(sizes), (tokens,))))
+    lines = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()]
+    named = {reduce_trace.short_name(line): _OP_NAME.search(line).group(1)
+             for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line}
+    new = {name: path for name, path in named.items()
+           if name.startswith("chainermn.rope.")}
+    assert len(new) == kernels, sorted(named)
+    flash_readers = [is_kernel for is_kernel, flash, _
+                     in parts.kernel_readers().values() if flash]
+    for name, path in new.items():
+        assert parts.part_of(name, path) == "norm_rope_ms", (name, path)
+        assert not any(is_flash(name) for is_flash in flash_readers), name
+    census = compiled_step_census(text)["pallas_kernels_by_module"]
+    assert census.get("chainermn.rope", 0) == kernels, census
+    # the flash kernels keep their names and their count beside them
+    assert sum(any(is_flash(name) for is_flash in flash_readers)
+               for name in named) == 3 * (
+        len([kind for kind in sizes["layer_types"] if kind != "conv"]))
+    if not kernels:
+        return
+    q_elements = (sizes["batch_per_chip"] * sizes["seq_len"]
+                  * sizes["num_attention_heads"] * sizes["head_dim"])
+    for line in lines:
+        wide, path = _FLOAT32.search(line), _OP_NAME.search(line)
+        if wide and path and re.search(r"chainermn\.rope|[qk]_norm",
+                                       path.group(1)):
+            count = 1
+            for dim in wide.group(1).split(","):
+                count *= int(dim)
+            assert count < q_elements, line[:300]

@@ -67,6 +67,8 @@ def test_census_of_a_text_in_the_tpu_compilers_rendering():
     # the copy pair's destination and done, the slice pair's, ``copy.12``;
     # not the fusion's parameters, and S(2) is another memory
     assert census["fast_memory_values"] == 5
+    # the one kernel a ``pallas_call`` lowered to, under its module's name
+    assert census["pallas_kernels_by_module"] == {"moe": 1}
 
 
 def test_census_of_no_text_is_all_zeros():
@@ -74,6 +76,7 @@ def test_census_of_no_text_is_all_zeros():
     assert census["instructions"] == census["remat_clones"] == 0
     assert census["copies"] == {"copy": 0, "copy-start": 0, "copy-done": 0}
     assert census["fast_memory_values"] == 0
+    assert census["pallas_kernels_by_module"] == {}
 
 
 @pytest.mark.parametrize("op_name,module", [
@@ -121,3 +124,47 @@ def test_census_of_a_compiled_program_made_to_rematerialise(checkpointed):
     else:
         assert census["checkpoint_recomputed"] == 0
         assert census["checkpoint_recomputed_by_module"] == {}
+
+
+KERNEL = ('  %%%s = %s custom-call(%%p), custom_call_target="%s", '
+          'metadata={op_name="jit(inner)/chainermn.grad/%s" '
+          'stack_frame_id=1}')
+WIDE_Q = "bf16[1,32,8192,128]{3,2,1,0:T(8,128)(2,1)}"
+
+
+@pytest.mark.parametrize("name,shape,target,op_name,counted_as", [
+    # PR 41's kernels, forward and (two results) backward
+    ("chainermn.rope.16", WIDE_Q, "tpu_custom_call",
+     "jvp(MellumMoE)/layer_0/sliding/chainermn.rope/pallas_call",
+     "chainermn.rope"),
+    ("chainermn.rope.31", f"({WIDE_Q}, f32[1,16,8,128]{{3,2,1,0}})",
+     "tpu_custom_call",
+     "transpose(jvp(MellumMoE))/layer_3/full/chainermn.rope/pallas_call",
+     "chainermn.rope"),
+    ("sliding.9", f"({WIDE_Q}, f32[32,1,8192]{{2,1,0}})", "tpu_custom_call",
+     "jvp(MellumMoE)/layer_0/sliding/pallas_call", "sliding"),
+    ("block_7.4", WIDE_Q, "tpu_custom_call",
+     "transpose(jvp(TransformerLM))/block_7/pallas_call", "block_7"),
+    # a name without a serial number is its own key
+    ("moe", WIDE_Q, "tpu_custom_call", "jvp(M)/layer_1/moe/pallas_call",
+     "moe"),
+    # XLA's own grouped product is a tpu_custom_call and no Pallas kernel
+    ("ragged-dot-none.3", WIDE_Q, "tpu_custom_call",
+     "jvp(M)/layer_1/moe/cond/branch_1_fun/ragged_dot_general", None),
+    ("custom-call.7", WIDE_Q, "Sharding", "jvp(M)/layer_1/moe/pallas_call",
+     None),
+])
+def test_pallas_kernels_are_counted_by_the_name_a_trace_shows(
+        name, shape, target, op_name, counted_as):
+    text = "\n".join([
+        "HloModule jit_inner", "",
+        f"ENTRY %main.1 (p: {WIDE_Q}) -> {WIDE_Q} {{",
+        f"  %p = {WIDE_Q} parameter(0)",
+        KERNEL % (name, shape, target, op_name),
+        KERNEL % ("moe.47", WIDE_Q, "tpu_custom_call",
+                  "jvp(M)/layer_4/moe/pallas_call"),
+        f"  ROOT %copy.1 = {WIDE_Q} copy(%p)", "}", ""])
+    want = {"moe": 1}
+    if counted_as:
+        want[counted_as] = want.get(counted_as, 0) + 1
+    assert compiled_step_census(text)["pallas_kernels_by_module"] == want
