@@ -1,5 +1,6 @@
 from chainermn_tpu.models.afmoe import AfmoeConfig, AfmoeMoE
 from chainermn_tpu.models.alexnet import AlexNet
+from chainermn_tpu.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
 from chainermn_tpu.models.googlenet import GoogLeNet, GoogLeNetBN
 from chainermn_tpu.models.lfm2 import LFM2Config, LFM2MoE
 from chainermn_tpu.models.mellum import MellumConfig, MellumMoE
@@ -28,6 +29,8 @@ __all__ = [
     "AfmoeMoE",
     "MellumConfig",
     "MellumMoE",
+    "DeepseekV3Config",
+    "DeepseekV3",
     "MLP",
     "AlexNet",
     "NIN",

@@ -116,6 +116,7 @@ class AfmoeConfig:
 
     # what lfm2's shared modules read, under that family's names
     moe_route_scope = "chainermn.moe.afmoe_route"
+    moe_shared_scope = "chainermn.moe.shared"
     use_expert_bias = True
     norm_topk_eps = 1e-20
 

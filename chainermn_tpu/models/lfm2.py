@@ -53,7 +53,8 @@ def config_from_dict(cls, sizes, overrides):
     names = {f.name for f in dataclasses.fields(cls)}
     picked = {k: v for k, v in sizes.items() if k in names}
     picked.update(overrides)
-    picked["layer_types"] = tuple(picked["layer_types"])
+    if "layer_types" in picked:     # a flax module's attribute must hash
+        picked["layer_types"] = tuple(picked["layer_types"])
     return cls(**picked)
 
 
@@ -87,9 +88,10 @@ class LFM2Config:
     moe_matmul_impl: str = "ragged_dot"   # pallas | ragged_dot
     dtype: Any = jnp.float32         # compute dtype; parameters are float32
 
-    # the scope SparseMoE routes under and the scores it routes by (no
-    # fields: a model's, not a file's)
+    # the scope SparseMoE routes under, the one its shared experts run
+    # under and the scores it routes by (no fields: a model's, not a file's)
     moe_route_scope = "chainermn.moe.route"
+    moe_shared_scope = "chainermn.moe.shared"
     score_func = "sigmoid"
 
     def __post_init__(self):
@@ -371,7 +373,10 @@ class SparseMoE(nn.Module):
     others.  With ``num_shared_experts`` it adds ``shared(u)``, one
     SwiGLU ``num_shared_experts * moe_intermediate_size`` wide that every
     token visits and every device of a share computes alike (added up over
-    the shares it counts ONCE).  Returns ``(y, counters)``.
+    the shares it counts ONCE), under the scope ``config.moe_shared_scope``
+    (a family's own name where a benchmark reader of another family's holds
+    ``chainermn.moe.shared``: docs/observability.md).  Returns ``(y,
+    counters)``.
 
     ``config`` is an :class:`LFM2Config` or any object with its expert-layer
     attributes (``models/afmoe.py``'s, ``models/mellum.py``'s)."""
@@ -419,7 +424,7 @@ class SparseMoE(nn.Module):
         y = y.reshape(u.shape)
         if cfg.num_shared_experts:
             with jax.named_scope(
-                    "chainermn.moe.shared"):
+                    cfg.moe_shared_scope):
                 y = y + DenseFFN(
                     cfg, width * cfg.num_shared_experts, name="shared")(u)
         return y, counters

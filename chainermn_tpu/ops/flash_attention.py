@@ -424,10 +424,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
                 has_seg, dropout_rate, has_offsets, window=None,
                 banded=False, n_tiles=None, plan=None):
     # Streaming grid (bh, q-tile, k-tile): q_ref [1, BQ, D] (fixed per
-    # (bh, j)); k_ref/v_ref [1, BK, D] = THIS grid step's tile; optional
-    # qseg [1, 1, BQ], kseg [1, 1, BK], seed [1, 1], offs [1, 2]; outputs
-    # o [1, BQ, D], lse [1, 1, BQ] (written at the last k step); scratch
-    # acc [BQ, D], m [BQ, 1], l [BQ, 1] persist across the k dimension.
+    # (bh, j)); k_ref [1, BK, D], v_ref [1, BK, Dv] = THIS grid step's tile;
+    # optional qseg [1, 1, BQ], kseg [1, 1, BK], seed [1, 1], offs [1, 2];
+    # outputs o [1, BQ, Dv], lse [1, 1, BQ] (written at the last k step);
+    # scratch acc [BQ, Dv], m [BQ, 1], l [BQ, 1] persist across the k
+    # dimension.
     qseg_ref, kseg_ref, seed_ref, offs_ref, rest = _unpack_rest(
         rest, has_seg, dropout_rate, has_offsets)
     o_ref, lse_ref, acc_s, m_s, l_s = rest
@@ -556,10 +557,17 @@ def _scratch(shapes_dtypes):
             for s, dt in shapes_dtypes]
 
 
+def _fold(x):
+    """[B, T, H, D] -> [B*H, T, D], whatever the head size (q's and k's, or
+    v's own)."""
+    return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], x.shape[3])
+
+
 def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
              block_k, dropout_rate, interpret, window=None):
     b, tq, h, d = q.shape
     tk, hk = k.shape[1], k.shape[2]
+    d_v = v.shape[3]  # the value (and output) head size; d is q's and k's
     grp = h // hk  # q heads per kv head (1 = MHA; >1 = GQA/MQA)
     scale = sm_scale if sm_scale is not None else d ** -0.5
     bq = min(block_q, tq)
@@ -569,10 +577,7 @@ def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
             f"flash_attention needs seq lens ({tq}, {tk}) divisible by "
             f"their tiles ({bq}, {bk}); pad the sequence or pass smaller "
             f"block sizes")
-    # [B, T, H, D] -> [B*H, T, D]
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
-    qf, kf, vf = fold(q), fold(k), fold(v)
+    qf, kf, vf = _fold(q), _fold(k), _fold(v)
     # grid dim 0 iterates q heads (b*h programs); a kv tensor row for
     # program i is its (batch, kv-head) pair
     kv_row = lambda i: (i // h) * hk + (i % h) // grp
@@ -596,7 +601,7 @@ def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
         pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0), **kw),
         pl.BlockSpec((1, bk, d),
                      lambda i, j, kk: (kv_row(i), k_of(j, kk), 0), **kw),
-        pl.BlockSpec((1, bk, d),
+        pl.BlockSpec((1, bk, d_v),
                      lambda i, j, kk: (kv_row(i), k_of(j, kk), 0), **kw),
     ]
     if has_seg:
@@ -621,22 +626,22 @@ def _forward(q, k, v, qseg, kseg, seed, offs, causal, sm_scale, block_q,
             pl.BlockSpec((1, 2), lambda i, j, kk: (i // h, 0), **kw))
     # Inside shard_map the outputs must carry the inputs' varying-axes
     # metadata (vma) so the kernel composes with sequence parallelism.
-    out_shape = [_shape_like(qf, (b * h, tq, d), q.dtype),
+    out_shape = [_shape_like(qf, (b * h, tq, d_v), q.dtype),
                  _shape_like(qf, (b * h, 1, tq), jnp.float32)]
     out, lse = pl.pallas_call(
         kern,
         grid=(b * h, tq // bq, k_steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0), **kw),
+            pl.BlockSpec((1, bq, d_v), lambda i, j, kk: (i, j, 0), **kw),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, 0, j), **kw)],
         out_shape=out_shape,
-        scratch_shapes=_scratch([((bq, d), jnp.float32),
+        scratch_shapes=_scratch([((bq, d_v), jnp.float32),
                                  ((bq, 1), jnp.float32),
                                  ((bq, 1), jnp.float32)]),
         interpret=interpret,
     )(*ins)
-    return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3), lse
+    return out.reshape(b, h, tq, d_v).transpose(0, 2, 1, 3), lse
 
 
 # ---------------------------------------------------------------------------
@@ -676,11 +681,12 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
                 sm_scale, causal, has_seg, dropout_rate,
                 has_offsets, with_lse, window=None, banded=False,
                 n_tiles=None, plan=None):
-    # Streaming grid (bh, k-tile, q-tile): k_ref/v_ref [1, BK, D] fixed
-    # per (bh, kk); q_ref/g_ref [1, BQ, D] = this step's q tile;
-    # lse_ref/delta_ref [1, 1, BQ] tiles; optional glse [1, 1, BQ];
-    # outputs dk/dv [1, BK, D] written at the last q step; scratch
-    # dk/dv accumulators persist across the q dimension.
+    # Streaming grid (bh, k-tile, q-tile): k_ref [1, BK, D] and v_ref
+    # [1, BK, Dv] fixed per (bh, kk); q_ref [1, BQ, D] and g_ref [1, BQ, Dv]
+    # = this step's q tile; lse_ref/delta_ref [1, 1, BQ] tiles; optional
+    # glse [1, 1, BQ]; outputs dk [1, BK, D] and dv [1, BK, Dv] written at
+    # the last q step; scratch dk/dv accumulators persist across the q
+    # dimension.
     qseg_ref, kseg_ref, seed_ref, offs_ref, outs = _unpack_rest(
         rest, has_seg, dropout_rate, has_offsets)
     if with_lse:
@@ -756,10 +762,11 @@ def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, *rest,
                sm_scale, causal, has_seg, dropout_rate,
                has_offsets, with_lse, window=None, banded=False,
                n_tiles=None, plan=None):
-    # Streaming grid (bh, q-tile, k-tile): q_ref/g_ref [1, BQ, D] fixed
-    # per (bh, j); k_ref/v_ref [1, BK, D] = this step's tile;
-    # lse_ref/delta_ref [1, 1, BQ]; optional glse [1, 1, BQ]; output
-    # dq [1, BQ, D] written at the last k step; scratch dq accumulator.
+    # Streaming grid (bh, q-tile, k-tile): q_ref [1, BQ, D] and g_ref
+    # [1, BQ, Dv] fixed per (bh, j); k_ref [1, BK, D] and v_ref [1, BK, Dv]
+    # = this step's tile; lse_ref/delta_ref [1, 1, BQ]; optional glse
+    # [1, 1, BQ]; output dq [1, BQ, D] written at the last k step; scratch
+    # dq accumulator.
     qseg_ref, kseg_ref, seed_ref, offs_ref, outs = _unpack_rest(
         rest, has_seg, dropout_rate, has_offsets)
     if with_lse:
@@ -831,13 +838,12 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
                      interpret, window=None):
     b, tq, h, d = q.shape
     tk, hk = k.shape[1], k.shape[2]
+    d_v = v.shape[3]  # the head size of v, out and g; d is q's and k's
     grp = h // hk  # q heads per kv head (GQA); dk/dv computed per q head
     scale = sm_scale if sm_scale is not None else d ** -0.5
     bq = min(block_q, tq)
     bk = min(block_k, tk)
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
-    qf, kf, vf, of, gf = fold(q), fold(k), fold(v), fold(out), fold(g)
+    qf, kf, vf, of, gf = _fold(q), _fold(k), _fold(v), _fold(out), _fold(g)
     kv_row = lambda i: (i // h) * hk + (i % h) // grp
     # delta = rowsum(dO * O): cheap fused elementwise+reduce, XLA's job.
     # lse arrives as [B*H, 1, T] (see _forward's tiling note); delta gets
@@ -871,15 +877,15 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
         has_seg=has_seg, dropout_rate=dropout_rate,
         has_offsets=has_offsets, with_lse=with_lse, plan=plan,
         **band.q_kwargs)
-    q_tile = lambda: pl.BlockSpec(
-        (1, bq, d), lambda i, j, qq: (i, q_of(j, qq), 0), **kw)
+    q_tile = lambda width: pl.BlockSpec(
+        (1, bq, width), lambda i, j, qq: (i, q_of(j, qq), 0), **kw)
     vec_q = lambda: pl.BlockSpec(
         (1, 1, bq), lambda i, j, qq: (i, 0, q_of(j, qq)), **kw)
     ins = [qf, gf, kf, vf, lse, delta]
-    in_specs = [q_tile(), q_tile(),
+    in_specs = [q_tile(d), q_tile(d_v),
                 pl.BlockSpec((1, bk, d),
                              lambda i, j, qq: (kv_row(i), j, 0), **kw),
-                pl.BlockSpec((1, bk, d),
+                pl.BlockSpec((1, bk, d_v),
                              lambda i, j, qq: (kv_row(i), j, 0), **kw),
                 vec_q(), vec_q()]
     if has_seg:
@@ -901,11 +907,11 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0), **kw),
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0), **kw)],
+            pl.BlockSpec((1, bk, d_v), lambda i, j, qq: (i, j, 0), **kw)],
         out_shape=[shape((b * h, tk, d), k.dtype),
-                   shape((b * h, tk, d), v.dtype)],
+                   shape((b * h, tk, d_v), v.dtype)],
         scratch_shapes=_scratch([((bk, d), jnp.float32),
-                                 ((bk, d), jnp.float32)]),
+                                 ((bk, d_v), jnp.float32)]),
         interpret=interpret,
     )(*ins)
     if grp > 1:
@@ -914,7 +920,8 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
         # output dtype; summing them in bf16 would compound rounding the
         # blockwise oracle doesn't have)
         group_sum = lambda x: x.astype(jnp.float32).reshape(
-            b, hk, grp, tk, d).sum(2).reshape(b * hk, tk, d).astype(x.dtype)
+            b, hk, grp, tk, x.shape[-1]).sum(2).reshape(
+                b * hk, tk, x.shape[-1]).astype(x.dtype)
         dk, dv = group_sum(dk), group_sum(dv)
 
     # dq: grid (bh, q-tile, k-tile) — k/v stream over the minor k
@@ -928,11 +935,11 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
                                  **kw)
     ins = [qf, gf, kf, vf, lse, delta]
     in_specs = [pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0), **kw),
-                pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0), **kw),
+                pl.BlockSpec((1, bq, d_v), lambda i, j, kk: (i, j, 0), **kw),
                 pl.BlockSpec((1, bk, d),
                              lambda i, j, kk: (kv_row(i), k_of(j, kk), 0),
                              **kw),
-                pl.BlockSpec((1, bk, d),
+                pl.BlockSpec((1, bk, d_v),
                              lambda i, j, kk: (kv_row(i), k_of(j, kk), 0),
                              **kw),
                 vec_j(), vec_j()]
@@ -959,7 +966,8 @@ def _pallas_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
         interpret=interpret,
     )(*ins)
 
-    unfold = lambda x, t_, h_: x.reshape(b, h_, t_, d).transpose(0, 2, 1, 3)
+    unfold = lambda x, t_, h_: x.reshape(
+        b, h_, t_, x.shape[-1]).transpose(0, 2, 1, 3)
     return unfold(dq, tq, h), unfold(dk, tk, hk), unfold(dv, tk, hk)
 
 
@@ -1048,11 +1056,12 @@ def _blockwise_backward(q, k, v, out, lse, qseg, kseg, seed, offs, g, g_lse,
     dq0 = jnp.zeros_like(qT)
     dq, (dk_tiles, dv_tiles) = jax.lax.scan(grad_fold, dq0, jnp.arange(n))
     # [n, B, H, bk, D] -> [B, H, Tk, D]
-    merge = lambda tiles: tiles.transpose(1, 2, 0, 3, 4).reshape(b, h, tk, d)
+    merge = lambda tiles: tiles.transpose(1, 2, 0, 3, 4).reshape(
+        b, h, tk, tiles.shape[-1])
     back = lambda x, ref: x.transpose(0, 2, 1, 3).astype(ref.dtype)
     dk_full, dv_full = merge(dk_tiles), merge(dv_tiles)
     if grp > 1:  # sum each kv head's gradient over its q-head group
-        gsum = lambda x: x.reshape(b, hk, grp, tk, d).sum(2)
+        gsum = lambda x: x.reshape(b, hk, grp, tk, x.shape[-1]).sum(2)
         dk_full, dv_full = gsum(dk_full), gsum(dv_full)
     return (back(dq, q), back(dk_full, k), back(dv_full, v))
 
@@ -1145,8 +1154,14 @@ def flash_attention(q, k, v, causal: bool = False,
                     return_lse: bool = False,
                     bwd_impl: str = "pallas",
                     window: Optional[int] = None):
-    """Fused softmax attention: q [B, Tq, H, D], k/v [B, Tkv, Hkv, D]
-    -> [B, Tq, H, D].  ``Tq != Tkv`` is supported (cross-attention /
+    """Fused softmax attention: q [B, Tq, H, D], k [B, Tkv, Hkv, D],
+    v [B, Tkv, Hkv, Dv] -> [B, Tq, H, Dv].  What must agree: the batch of
+    all three, the kv length and kv heads of k and v, and the head size of
+    q and k; the VALUE head size ``Dv`` is v's own (latent attention scores
+    192-wide keys and sums 128-wide values) and is neither padded nor
+    copied: v, the output and their gradients move at ``Dv``, q, k and
+    theirs at ``D``, and the default ``sm_scale`` is ``D ** -0.5``.
+    ``Tq != Tkv`` is supported (cross-attention /
     decode-over-cache); with ``causal`` the mask compares GLOBAL
     positions (row ``q_offset+i`` sees column ``kv_offset+j`` iff
     ``i+q_offset >= j+kv_offset``).  ``Hkv`` may divide ``H``
@@ -1238,11 +1253,14 @@ def flash_attention(q, k, v, causal: bool = False,
         offs = None
     # cross-attention supported: Tq (from q) and Tkv (from k/v) may
     # differ; GQA/MQA supported: k/v head count may divide q's
-    if k.shape != v.shape:
-        raise ValueError(f"k and v shapes differ: {k.shape} vs {v.shape}")
+    if k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            "k and v must share batch, kv length and kv heads (the head "
+            f"size of v is its own): {k.shape} vs {v.shape}")
     if (q.shape[0], q.shape[3]) != (k.shape[0], k.shape[3]):
         raise ValueError(
-            f"q and k/v must share batch/dim: {q.shape} vs {k.shape}")
+            "q and k must share batch and head size (v's head size is its "
+            f"own, the output's): {q.shape} vs {k.shape}")
     if q.shape[2] % k.shape[2]:
         raise ValueError(
             f"q head count ({q.shape[2]}) must be a multiple of the kv "
@@ -1267,7 +1285,9 @@ def flash_tile_census(tq: int, tk: int, block_q: Optional[int] = None,
                       window: Optional[int] = None,
                       sub: Optional[int] = None, *, causal: bool = True,
                       offsets: bool = False, segment_ids: bool = False,
-                      dropout_rate: float = 0.0) -> dict:
+                      dropout_rate: float = 0.0,
+                      head_dim: Optional[int] = None,
+                      value_head_dim: Optional[int] = None) -> dict:
     """How much of a call's work the tile classes take away: a counter of
     shapes alone, from the arithmetic the kernels use (:func:`_tile_plan`,
     :func:`_visible`), for one (batch, head) of one kernel.
@@ -1284,7 +1304,15 @@ def flash_tile_census(tq: int, tk: int, block_q: Optional[int] = None,
     tiles' sub-tiles that hold a visible pair.  ``visible_pair_share`` is the
     visible pairs over the pairs of the sub-tiles run (1.0: no score is
     computed that the mask throws away).  Blocks default as
-    :func:`flash_attention`'s do for operands under four bytes."""
+    :func:`flash_attention`'s do for operands under four bytes.
+
+    With ``head_dim`` (q's and k's; ``value_head_dim`` is v's own and
+    defaults to it) also the forward kernel's matmul operations for that
+    (batch, head), a multiply-add as two: ``forward_flop_run`` over the pairs
+    of the sub-tiles run (scores at ``head_dim``, the weighted sum at
+    ``value_head_dim``) and ``forward_flop_visible`` over the visible pairs
+    alone; the two backward kernels run twice that and recompute the
+    scores."""
     bq = _fit_block(tq, block_q, _BLOCK_Q)
     bk = _fit_block(tk, block_k, _BLOCK_K)
     if window is not None and not causal:
@@ -1319,10 +1347,15 @@ def flash_tile_census(tq: int, tk: int, block_q: Optional[int] = None,
     else:
         visible = tq * tk
     run_pairs = subtiles_run * (bq * bk // per_tile)
-    return {"classified": plan is not None, "sub": sub, "visited": visited,
-            **counts, "subtiles_run": subtiles_run,
-            "subtiles_total": visited * per_tile,
-            "visible_pair_share": visible / run_pairs if run_pairs else 0.0}
+    census = {"classified": plan is not None, "sub": sub, "visited": visited,
+              **counts, "subtiles_run": subtiles_run,
+              "subtiles_total": visited * per_tile,
+              "visible_pair_share": visible / run_pairs if run_pairs else 0.0}
+    if head_dim is not None:
+        per_pair = 2 * (int(head_dim) + int(value_head_dim or head_dim))
+        census["forward_flop_run"] = run_pairs * per_pair
+        census["forward_flop_visible"] = visible * per_pair
+    return census
 
 
 __all__ = ["flash_attention", "flash_tile_census"]

@@ -73,6 +73,8 @@ _Q64 = ((3, 8192, 32, 64), jnp.bfloat16)
 _KV64 = ((3, 8192, 8, 64), jnp.bfloat16)
 # Trinity-Mini: 32 query / 4 kv heads of 128, one row, a window of 2048
 _Q32 = ((1, 8192, 32, 128), jnp.bfloat16)
+# Kimi-VL-A3B's latent attention: 16 heads, keys of 192 beside values of 128
+_QK192 = ((1, 8192, 16, 192), jnp.bfloat16)
 # its expert layer: tokens x top-4 rows through 8 held experts of 1792
 _ROWS = ((3 * 8192 * 4, 2048), jnp.bfloat16)
 _EXPERTS_UP = ((8, 2048, 1792), jnp.bfloat16)
@@ -111,6 +113,8 @@ CASES = {
     "flash_segment_ids_fwd_bwd": (_flash_grad, [_Q, _Q, _Q, _SEG], 3),
     "flash_gqa_head_dim_64_fwd_bwd": (_flash_grad, [_Q64, _KV64, _KV64], 3),
     "flash_gqa_window_fwd_bwd": (_windowed_grad, [_Q32, _KV_GQA, _KV_GQA], 3),
+    "flash_keys_of_192_values_of_128_fwd_bwd": (
+        _flash_grad, [_QK192, _QK192, _Q], 3),
     "flash_gqa_window_of_one_tile_fwd_bwd": (
         _one_tile_window_grad, [_Q32, _KV_GQA, _KV_GQA], 3),
     "grouped_matmul_2304_by_896_fwd_bwd": (

@@ -414,7 +414,7 @@ def test_cross_attention_shape_validation():
     with pytest.raises(ValueError, match="multiple of the kv"):
         flash_attention(q, k, k, False)
     d_mismatch = jnp.asarray(rng.randn(1, 128, 2, 16), jnp.float32)
-    with pytest.raises(ValueError, match="batch/dim"):
+    with pytest.raises(ValueError, match="batch and head size"):
         flash_attention(q, d_mismatch, d_mismatch, False)
     v = jnp.asarray(rng.randn(1, 64, 2, 32), jnp.float32)
     with pytest.raises(ValueError, match="k and v"):

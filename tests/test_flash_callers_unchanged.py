@@ -1,0 +1,71 @@
+"""With ``Dv == D`` the traced program of every existing caller of
+``flash_attention`` is the one it was (PR 42 gave the kernels a value head
+size of their own, and ``SparseMoE`` a scope it reads off its config): the
+jaxpr of each accepted LM cell's toy step, traced with the Pallas kernels on
+as the chip traces them, against the digest taken on the parent's tree
+(``tests/data/flash_callers_jaxpr_pr41.json``).
+
+A digest, because the parent's kernels are 1,300 lines and not a function a
+test can carry beside it, as ``tests/test_mellum.py`` carries ``rope``'s.
+The text of a jaxpr holds no path and no line number (the same digests came
+from two trees at two paths, under several ``PYTHONHASHSEED``s once the
+frozensets of axis names are printed in sorted order), but it does follow
+the installed JAX.  A PR
+that changes these kernels ON PURPOSE takes the digests again on its own
+tree (the function below, printed) and says so; a PR that meant to leave the
+other cells alone and fails here did not."""
+
+import hashlib
+import json
+import os
+import re
+import unittest.mock
+
+import jax
+import pytest
+
+from chipbench import generator, spec, weights
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(HERE, "data", "flash_callers_jaxpr_pr41.json")) as f:
+    DIGESTS = {k: v for k, v in json.load(f).items() if k != "note"}
+
+
+def toy_step_jaxpr(cell_name):
+    """The text of the jaxpr of the cell's toy step, kernels on."""
+    cell = spec.resolve(cell_name, rehearse=True)
+    sizes = dict(cell.sizes, attention_impl="flash")
+    plain = dict(sizes, attention_impl="xla")
+    if "moe_matmul_impl" in sizes:
+        sizes["moe_matmul_impl"] = "pallas"
+        plain["moe_matmul_impl"] = "ragged_dot"
+    comm = cell.family.make_comm(sizes, jax.devices()[:cell.chips])
+    params = cell.family.make_params(plain, weights.seed_key(0, 0))
+    step, state = cell.family.build(comm, sizes, params)
+    ring = generator.make_ring(dict(sizes, ring=1), cell.chips,
+                               weights.seed_key(0, 1), comm.mesh,
+                               comm.data_axes)
+    # only the trace: off the TPU a pallas_call inside shard_map cannot be
+    # lowered, and the jaxpr is what is asked for
+    with unittest.mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = str(step.trace(*state, ring[0]).jaxpr)
+    # a frozenset of names prints in the order of the process's string hashes
+    return re.sub(r"frozenset\(\{([^}]*)\}\)", lambda found: "frozenset({"
+                  + ", ".join(sorted(found.group(1).split(", "))) + "})", text)
+
+
+def test_the_digests_are_of_the_six_accepted_language_model_cells():
+    bench = spec.load_benchmark()
+    accepted = [w["name"] for w in bench["workloads"]
+                if w["config"] in ("starcoderbase-1b", "lfm2-8b-a1b",
+                                   "trinity-mini", "mellum2-12b")]
+    assert sorted(accepted) == sorted(DIGESTS) and len(DIGESTS) == 6
+
+
+@pytest.mark.parametrize("cell_name", sorted(DIGESTS))
+def test_a_callers_traced_step_is_the_parents(cell_name):
+    text = toy_step_jaxpr(cell_name)
+    assert "pallas_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == DIGESTS[cell_name], (
+        f"{cell_name}: the toy step with the kernels on no longer traces to "
+        "the program it traced to at PR 41")
