@@ -12,7 +12,10 @@ from chainermn_tpu.ops.fused_norm import (
     fused_norm_traffic_bytes,
     resnet_bn_traffic_bytes,
 )
-from chainermn_tpu.ops.grouped_matmul import grouped_matmul
+from chainermn_tpu.ops.grouped_matmul import (
+    grouped_matmul,
+    grouped_matmul_census,
+)
 from chainermn_tpu.ops.qk_norm_rope import qk_norm_rope
 
 __all__ = [
@@ -21,6 +24,7 @@ __all__ = [
     "fused_norm",
     "fused_norm_reference",
     "grouped_matmul",
+    "grouped_matmul_census",
     "qk_norm_rope",
     "FusedBatchNormAct",
     "fused_norm_traffic_bytes",

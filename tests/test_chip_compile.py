@@ -120,6 +120,12 @@ CASES = {
     "grouped_matmul_2304_by_896_fwd_bwd": (
         _expert_grad,
         [_ROWS_2304, _EXPERTS_UP_896, _EXPERTS_DOWN_896, _GROUPS_16], 5),
+    # 53 x 128: no divisor to tile by and too long to hold whole, so equal
+    # tiles with a ragged last one, as K (masked), as N and in drhs
+    "grouped_matmul_6784_in_ragged_tiles_fwd_bwd": (
+        _expert_grad,
+        [((1024, 6784), jnp.bfloat16), ((2, 6784, 1024), jnp.bfloat16),
+         ((2, 1024, 6784), jnp.bfloat16), ((2,), jnp.int32)], 5),
     "grouped_matmul_fwd": (grouped_matmul, [_ROWS, _EXPERTS_UP, _GROUPS], 1),
     "grouped_matmul_fwd_bwd": (
         # the second forward is dead under a sum: 1 + 2 x (dlhs, drhs)
@@ -364,11 +370,15 @@ def test_one_chip_double_buffered_step_keeps_xla_defaults(topo):
 
 @pytest.mark.parametrize("cell,pairs,rows", [
     ("lfm2-8b-a1b-ep4share-t8192", 98304, 36864),
-    ("trinity-mini-ep8share-t8192", 65536, 12288)])
+    ("trinity-mini-ep8share-t8192", 65536, 12288),
+    ("kimi-vl-a3b-ep8share-t8192", 49152, 9216)])
 def test_moe_layer_main_pass_kernels_keep_their_names(topo, cell, pairs, rows):
     """The expert layer at a benchmark cell's sizes, forward and backward,
     alone: a quarter of the experts held (lfm2) and an eighth (Trinity-Mini:
-    the even share and a half, 12,288 of 65,536 rows).  The trace's readers
+    the even share and a half, 12,288 of 65,536 rows; Kimi-VL-A3B: 9,216 of
+    49,152 through experts 1,408 wide, a dimension the grouped kernels hold
+    whole, so that this compile is also the check that their blocks fit the
+    VMEM they ask for, two shared experts beside).  The trace's readers
     find the grouped-matmul kernels by name (``moe.<k>``:
     ``chipbench/layer_metrics/moe_gmm_ms.py::is_gmm``), and a Pallas call is
     named after the innermost entry of its name stack: the main pass's nine
@@ -377,13 +387,14 @@ def test_moe_layer_main_pass_kernels_keep_their_names(topo, cell, pairs, rows):
     import flax.linen as nn
 
     from chainermn_tpu.models.afmoe import AfmoeConfig
+    from chainermn_tpu.models.deepseek_v3 import DeepseekV3Config
     from chainermn_tpu.models.lfm2 import LFM2Config, SparseMoE
     from chipbench import reduce_trace, spec
     from chipbench.layer_metrics.moe_gmm_ms import is_gmm
 
     sizes = spec.resolve(cell).sizes
-    config = (AfmoeConfig if cell.startswith("trinity") else LFM2Config
-              ).from_dict(
+    config = {"lfm2": LFM2Config, "trinity": AfmoeConfig,
+              "kimi": DeepseekV3Config}[cell.split("-")[0]].from_dict(
         sizes, num_experts_routed=sizes["num_experts_published"],
         dtype=jnp.dtype(sizes["compute_dtype"]))
     assert config.moe_matmul_impl == "pallas"

@@ -13,12 +13,16 @@ frozensets of axis names are printed in sorted order), but it does follow
 the installed JAX.  A PR
 that changes these kernels ON PURPOSE takes the digests again on its own
 tree (the function below, printed) and says so; a PR that meant to leave the
-other cells alone and fails here did not."""
+other cells alone and fails here did not.  PR 43 did, for the three MoE
+cells: it gave ``ops/grouped_matmul.py``'s kernels their tile plan (the
+file's note); the StarCoder cells' digests are PR 41's still."""
 
 import hashlib
 import json
 import os
 import re
+import subprocess
+import sys
 import unittest.mock
 
 import jax
@@ -62,10 +66,39 @@ def test_the_digests_are_of_the_six_accepted_language_model_cells():
     assert sorted(accepted) == sorted(DIGESTS) and len(DIGESTS) == 6
 
 
+@pytest.fixture(scope="module")
+def traced_in_a_fresh_process():
+    """``{cell: [digest, whether the text holds a pallas_call]}`` from a
+    process of its own.  What a step traces to also follows what the
+    process did before (with observability left enabled by another test the
+    step gains its callbacks, for one), and under ``--dist loadfile`` which
+    files share a worker goes by their run times: at PR 43 all six digests
+    failed in one whole run of the tests and passed in the next."""
+    done = subprocess.run(
+        [sys.executable, "-c",
+         "import conftest, test_flash_callers_unchanged as t; t.main()"],
+        cwd=HERE, capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+            [os.path.dirname(HERE), HERE])))
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.splitlines()[-1])
+
+
+def main():
+    traced = {}
+    for cell_name in sorted(DIGESTS):
+        text = toy_step_jaxpr(cell_name)
+        traced[cell_name] = [hashlib.sha256(text.encode()).hexdigest(),
+                             "pallas_call" in text]
+    print(json.dumps(traced))
+
+
 @pytest.mark.parametrize("cell_name", sorted(DIGESTS))
-def test_a_callers_traced_step_is_the_parents(cell_name):
-    text = toy_step_jaxpr(cell_name)
-    assert "pallas_call" in text
-    assert hashlib.sha256(text.encode()).hexdigest() == DIGESTS[cell_name], (
+def test_a_callers_traced_step_is_the_parents(
+        cell_name, traced_in_a_fresh_process):
+    digest, has_kernels = traced_in_a_fresh_process[cell_name]
+    assert has_kernels
+    assert digest == DIGESTS[cell_name], (
         f"{cell_name}: the toy step with the kernels on no longer traces to "
-        "the program it traced to at PR 41")
+        "the program its digest was taken of (tests/data/"
+        "flash_callers_jaxpr_pr41.json)")
