@@ -26,13 +26,13 @@ from chainermn_tpu.analysis.hlo import (
     HLO_DTYPE_BYTES,
     _COMPUTATION_RE,
     _INSTRUCTION_RE,
+    _OP_NAME_RE,
     _logical_lines,
 )
 
 # a computation that describes ONE instruction of its caller: a fusion's, or
 # the wrapper the chip's own text puts around an asynchronous operation
 _FUSED_RE = re.compile(r"\b(?:fusion|async-start)\(.*\bcalls=%?([\w.\-]+)")
-_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 # one array of a result shape with its layout: f32[8,128]{1,0:T(8,128)S(1)}
 _ARRAY_RE = re.compile(r"(\w+)\[([0-9,]*)\](\{[^{}]*\})?")
 _NUMBERED = re.compile(r"_\d+\b")

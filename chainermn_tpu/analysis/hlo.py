@@ -64,6 +64,10 @@ _INSTRUCTION_RE = re.compile(
     r"(?P<shape>\(.*?\)|\S+)\s+(?P<op>[a-z][\w\-]*)\(")
 _COLLECTIVE_OPS = frozenset(
     kind + edge for kind in COLLECTIVE_KINDS for edge in ("", "-start", "-done"))
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# the scope the gradient exchange opens around its cast to the wire dtype
+# (``_packing.pack`` and the leaf-wise lowering of ``planner.compiler``)
+_PACK_SCOPE = "chainermn.pack"
 
 # a new instruction binding starts a logical line; the HLO printer
 # renders bindings with a SPACED " = " while instruction attributes
@@ -253,6 +257,13 @@ def collective_census(hlo_text: str) -> List[dict]:
     return [o.as_census_dict() for o in parse_hlo_collectives(hlo_text).ops]
 
 
+def _is_wire_cast(line: str) -> bool:
+    found = _INSTRUCTION_RE.match(line)
+    op_name = _OP_NAME_RE.search(line)
+    return bool(found and op_name and found.group("op") == "convert"
+                and _PACK_SCOPE in op_name.group(1).split("/"))
+
+
 def all_reduce_overlap_census(hlo_text: str) -> dict:
     """How many of a compiled program's all-reduces block the device and
     how many run beside other work, with the bytes each kind reduces — the
@@ -260,9 +271,21 @@ def all_reduce_overlap_census(hlo_text: str) -> dict:
     (0 % asynchronous under TPU XLA's defaults).  Asynchronous is a
     start/done pair or an asynchronous-collective fusion chain; any other
     all-reduce, a variadic one included, blocks.  Bytes are the result
-    shape's, which for an all-reduce is what crosses the wire once."""
+    shape's, which for an all-reduce is what crosses the wire once.
+
+    ``wire_casts`` counts the ``convert`` instructions under the scope
+    ``chainermn.pack``, fused or not: the casts of gradients to the wire
+    dtype that stand before the exchange's collectives.  One a leaf (or
+    fewer, where the compiler merged them) on an exchange handed
+    gradients wider than its wire, which then waits for them; 0 where the
+    gradients arrive in the wire dtype (the double buffer's ``pending``)
+    or there is no wire dtype.  It reads the text of ``compiled.as_text()``
+    and of ``lowered.as_text(dialect="hlo", debug_info=True)`` alike (the
+    scope is in the instructions' ``op_name``)."""
     census = {"synchronous": 0, "asynchronous": 0,
-              "synchronous_bytes": 0, "asynchronous_bytes": 0}
+              "synchronous_bytes": 0, "asynchronous_bytes": 0,
+              "wire_casts": sum(map(_is_wire_cast,
+                                    _logical_lines(hlo_text)))}
     for op in parse_hlo_collectives(hlo_text).ops:
         if op.op == "all-reduce":
             kind = "asynchronous" if op.is_async else "synchronous"

@@ -36,6 +36,19 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def cast_like(tree: Any, like: Any = None):
+    """``tree`` with every leaf in the dtype of ``like``'s leaf (``like``
+    None: ``tree`` as it is).  What ``allreduce_grad(..., like=)`` does to
+    gradients kept in a wire dtype before a lowering that has no use for
+    them so: a narrower float widens losslessly, and the same values reach
+    the wire."""
+    if like is None:
+        return tree
+    return jax.tree.map(
+        lambda leaf, l: leaf if leaf.dtype == l.dtype
+        else leaf.astype(l.dtype), tree, like)
+
+
 def pack(tree: Any, comm_dtype: Optional[jnp.dtype] = None):
     """Flatten a pytree into per-dtype flat buffers.
 

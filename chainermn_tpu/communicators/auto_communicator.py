@@ -73,7 +73,7 @@ class AutoCommunicator(MeshCommunicator):
                                        np.dtype(dtype).name, int(nbytes))
         return found if found is not None else self.plan()
 
-    def _allreduce_grad_traced(self, grads):
+    def _allreduce_grad_traced(self, grads, like=None):
         from chainermn_tpu.planner.compiler import execute_plan
         from chainermn_tpu.planner.schedule import register_plan_slot
         leaves = jax.tree.leaves(grads)
@@ -95,4 +95,5 @@ class AutoCommunicator(MeshCommunicator):
         register_plan_slot("allreduce", nbytes=nbytes, dtype=dtype,
                            op="all-reduce",
                            owners=("plan:", "fsdp", "collective"))
-        return execute_plan(self.plan_for(nbytes, dtype), self, grads)
+        return execute_plan(self.plan_for(nbytes, dtype), self, grads,
+                            like=like)

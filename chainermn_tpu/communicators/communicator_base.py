@@ -131,7 +131,8 @@ class CommunicatorBase(abc.ABC):
 
     # ---- gradient entry points (the hot path) ------------------------------
     @abc.abstractmethod
-    def allreduce_grad(self, grads, *, compressor=None, state=None): ...
+    def allreduce_grad(self, grads, *, compressor=None, state=None,
+                       like=None): ...
 
     @abc.abstractmethod
     def bcast_data(self, params): ...

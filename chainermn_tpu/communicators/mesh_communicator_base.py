@@ -28,6 +28,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from chainermn_tpu.communicators import _packing
 from chainermn_tpu.communicators.communicator_base import CommunicatorBase
 from chainermn_tpu.parallel import topology as topo_mod
 from chainermn_tpu.runtime import control_plane as cp_mod
@@ -467,7 +468,8 @@ class MeshCommunicator(CommunicatorBase):
         return jax.tree.map(lambda v: lax.ppermute(v, axis, perm), x)
 
     # ---- gradient entry points ---------------------------------------------
-    def allreduce_grad(self, grads, *, compressor=None, state=None):
+    def allreduce_grad(self, grads, *, compressor=None, state=None,
+                       like=None):
         """Average gradients across the data-parallel world.
 
         Reference: ``Communicator.allreduce_grad(model)``
@@ -502,15 +504,25 @@ CompressionState` from :meth:`init_compression_state`) and the call
           Passing a stage-keyed ``state`` dict with ``compressor=None``
           runs this communicator's own :meth:`plan` per hop.
 
+        ``like`` (a tree of ``grads``' structure) says which dtype each
+        mean comes back in: its leaf's, where ``None`` says the gradient's
+        own.  It is how a caller that keeps gradients in the wire dtype
+        already (the double buffer's ``pending``) gets them back as wide as
+        its parameters: such a leaf is reduced as it lies, with no cast
+        before the collective, and cast to ``like``'s dtype BEFORE the
+        ``1 / size`` scale, so the scale multiplies in that precision.  The
+        lowerings that build a buffer widen the leaves first (a lossless
+        cast: the same values reach the wire).
+
         Everything the call puts into a traced program carries the named
         scope ``chainermn.allreduce_grad`` (docs/observability.md): the
         device trace then says what the exchange costs, whatever the
         flavor, the codec or the optimizer wrapper around it.
         """
         with jax.named_scope("chainermn.allreduce_grad"):
-            return self._allreduce_grad(grads, compressor, state)
+            return self._allreduce_grad(grads, compressor, state, like)
 
-    def _allreduce_grad(self, grads, compressor, state):
+    def _allreduce_grad(self, grads, compressor, state, like=None):
         from chainermn_tpu.compression import base as _cbase
         from chainermn_tpu.compression import quantize as _cq
         from chainermn_tpu.planner.ir import Plan as _Plan
@@ -518,7 +530,7 @@ CompressionState` from :meth:`init_compression_state`) and the call
         if plan is None and isinstance(state, dict):
             plan = self.plan()
         if plan is not None:
-            return self._allreduce_grad_plan(grads, plan, state)
+            return self._allreduce_grad_plan(grads, plan, state, like)
         comp = (_cbase.resolve_compressor(compressor)
                 if compressor is not None else
                 (self.compression if _cq.is_quantizing(self.compression)
@@ -530,16 +542,18 @@ CompressionState` from :meth:`init_compression_state`) and the call
                     "pass state=comm.init_compression_state(grads, "
                     "compressor) and thread the returned new state into "
                     "the next call")
-            return self._allreduce_grad_compressed(grads, comp, state)
+            return self._allreduce_grad_compressed(
+                _packing.cast_like(grads, like), comp, state)
         wire = comp.wire if comp is not None else None
         if self.in_spmd_context():
             if wire is not None:
-                return self._allreduce_grad_wire(grads, wire)
-            return self._allreduce_grad_traced(grads)
+                return self._allreduce_grad_wire(grads, wire, like)
+            return self._allreduce_grad_traced(grads, like)
         dt = wire if wire is not None else self.allreduce_grad_dtype
         if dt is None:
-            return grads
-        return jax.tree.map(lambda g: g.astype(dt).astype(g.dtype), grads)
+            return _packing.cast_like(grads, like)
+        return jax.tree.map(lambda g, l: g.astype(dt).astype(l.dtype),
+                            grads, grads if like is None else like)
 
     # Upstream ChainerMN later renamed this; keep both spellings.
     multi_node_mean_grad = allreduce_grad
@@ -572,7 +586,7 @@ CompressionState` from :meth:`init_compression_state`) and the call
             return None
         return comp.init_state(n, self.size)
 
-    def _allreduce_grad_plan(self, grads, plan, states):
+    def _allreduce_grad_plan(self, grads, plan, states, like=None):
         """Per-hop compressed exchange: execute ``plan`` with one EF
         state per quantizing stage (``states`` keyed by stage index).
         Returns ``(mean_grads, new_states)`` when ``states`` is given,
@@ -592,9 +606,9 @@ CompressionState` from :meth:`init_compression_state`) and the call
                     f"per-hop compression states missing for stage(s) "
                     f"{missing} of plan {plan.name!r}: build them with "
                     "comm.init_compression_state(grads, plan)")
-        return execute_plan(plan, self, grads, states=states)
+        return execute_plan(plan, self, grads, states=states, like=like)
 
-    def _allreduce_grad_wire(self, grads, wire):
+    def _allreduce_grad_wire(self, grads, wire, like=None):
         """NoCompression(wire_dtype): the cast-allreduce-cast program of
         the ``allreduce_grad_dtype`` knob — by construction, because it
         IS the xla flavor's plan at that wire dtype, through the one
@@ -602,13 +616,13 @@ CompressionState` from :meth:`init_compression_state`) and the call
         from chainermn_tpu.planner.compiler import execute_plan
         from chainermn_tpu.planner.plans import flavor_plan
         return execute_plan(
-            flavor_plan("xla", wire_dtype=np.dtype(wire).name), self, grads)
+            flavor_plan("xla", wire_dtype=np.dtype(wire).name), self, grads,
+            like=like)
 
     def _allreduce_grad_compressed(self, grads, comp, state):
         """Quantized exchange: pack to one f32 buffer, EF-encode to wire
         codes, SUM the codes in wire arithmetic, decode + delayed-scale
         update, mean, unpack.  Returns ``(mean_grads, new_state)``."""
-        from chainermn_tpu.communicators import _packing
         from chainermn_tpu.compression import observe as _cobs
         from chainermn_tpu.compression import quantize as _cq
         traced = self.in_spmd_context()
@@ -657,12 +671,12 @@ CompressionState` from :meth:`init_compression_state`) and the call
         scale = (1.0 / n) if traced else None
         return _packing.unpack([out], meta, scale=scale), state
 
-    def _allreduce_grad_traced(self, grads):
+    def _allreduce_grad_traced(self, grads, like=None):
         """Execute this flavor's fixed plan through the one compiler.
         The zoo's per-class hand-lowered bodies live on as
         ``_legacy_allreduce_grad_traced`` parity references."""
         from chainermn_tpu.planner.compiler import execute_plan
-        return execute_plan(self.plan(), self, grads)
+        return execute_plan(self.plan(), self, grads, like=like)
 
     def _legacy_allreduce_grad_traced(self, grads):
         """Pre-planner decomposition (naive): per-leaf psum over all

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from chainermn_tpu.communicators import _packing
 from chainermn_tpu.communicators.flat_communicator import FlatCommunicator
 from chainermn_tpu.utils.placement import local_device_put
 
@@ -27,7 +28,8 @@ class NonCudaAwareCommunicator(FlatCommunicator):
     # own plan name so sweep rows / plan tables attribute timings right
     flavor = "non_cuda_aware"
 
-    def allreduce_grad(self, grads, *, compressor=None, state=None):
+    def allreduce_grad(self, grads, *, compressor=None, state=None,
+                       like=None):
         from chainermn_tpu.compression import base as _cbase
         from chainermn_tpu.compression import quantize as _cq
         comp = (_cbase.resolve_compressor(compressor)
@@ -39,7 +41,7 @@ class NonCudaAwareCommunicator(FlatCommunicator):
             # ride the in-wire-summing collective either way; use the flat
             # decomposition (codec handling included).
             return super().allreduce_grad(
-                grads, compressor=compressor, state=state)
+                grads, compressor=compressor, state=state, like=like)
         # Eager: device -> host -> (DCN mean across hosts) -> device, the
         # staged path the reference implements with pinned buffers.
         if comp is not None and comp.wire is not None:
@@ -47,7 +49,7 @@ class NonCudaAwareCommunicator(FlatCommunicator):
             # cast-roundtrip the in-program path observes.
             grads = jax.tree.map(
                 lambda g: g.astype(comp.wire).astype(g.dtype), grads)
-        host = jax.device_get(grads)
+        host = jax.device_get(_packing.cast_like(grads, like))
         if self.host_size > 1:
             summed = self.allreduce_obj(host, op="sum")
             host = jax.tree.map(lambda a: np.asarray(a) / self.host_size, summed)

@@ -55,6 +55,33 @@ def _unflatten_state(arrays: dict, treedef, like_leaves: List[Any]):
     return jax.tree.unflatten(treedef, leaves)
 
 
+def _in_live_dtype(new, old, name: str):
+    """One restored host array in the LIVE leaf's dtype.  An npz keeps no
+    extension dtype (bfloat16 comes back as two-byte void): the live leaf
+    says what it was.  A float leaf saved wider or narrower than the live
+    state holds it is cast, which is exact for the one state that changed
+    so: the double buffer's ``pending`` was float32 until PR 46 and the
+    exchange rounded it to the wire dtype at its first read.  Bytes that
+    no dtype of the live leaf's size explains are refused by name."""
+    dtype = getattr(old, "dtype", None)
+    if dtype is None or new.dtype == dtype:
+        return new
+    dtype = np.dtype(dtype)
+    if new.dtype.kind == "V":
+        if new.dtype.itemsize != dtype.itemsize:
+            raise ValueError(
+                f"checkpoint leaf {name} holds {new.dtype.itemsize}-byte "
+                f"values of a dtype numpy does not name (bfloat16 or an "
+                f"fp8 saved through an npz) but the resume target holds "
+                f"it as {dtype}: resume into a state built as the run "
+                f"that saved was (the same wire dtype on the "
+                f"communicator), or save it again from such a state")
+        return new.view(dtype)
+    if new.dtype.kind == "f" and jax.dtypes.issubdtype(dtype, np.floating):
+        return new.astype(dtype)
+    return new
+
+
 def _place_like(new, old):
     """Place one restored host array with the LIVE leaf's sharding.
     Restores must never cross processes — every rank's npz holds what
@@ -371,6 +398,11 @@ class _MultiNodeCheckpointer:
             with np.load(self._file(gen)) as data:
                 arrays = {k: data[k] for k in data.files}
             self._validate_restore(arrays, state, leaves, gen)
+            paths = [jax.tree_util.keystr(path) for path, _ in
+                     jax.tree_util.tree_flatten_with_path(state)[0]]
+            arrays = {f"leaf_{i}": _in_live_dtype(
+                arrays[f"leaf_{i}"], leaf, f"leaf_{i} ({paths[i]})")
+                for i, leaf in enumerate(leaves)}
             restored = _unflatten_state(arrays, treedef, leaves)
             # preserve shardings of the live state (host-local placement;
             # see _place_like for why this must not cross processes)

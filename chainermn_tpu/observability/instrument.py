@@ -163,11 +163,12 @@ class InstrumentedCommunicator:
         return out
 
     # ---- gradient entry points (the hot path) ------------------------------
-    def allreduce_grad(self, grads, *, compressor=None, state=None):
+    def allreduce_grad(self, grads, *, compressor=None, state=None,
+                       like=None):
         return self._run_collective(
             "allreduce_grad", grads,
             lambda: self._comm.allreduce_grad(
-                grads, compressor=compressor, state=state))
+                grads, compressor=compressor, state=state, like=like))
 
     multi_node_mean_grad = allreduce_grad
 

@@ -114,7 +114,8 @@ class _CompressedOptimizer:
 
 class _DoubleBufferState(NamedTuple):
     inner: Any            # wrapped optimizer's state (replicated)
-    pending: Any          # previous step's *local* grads (device-varying)
+    pending: Any          # previous step's *local* grads (device-varying),
+    #                       matrices in the exchange's wire dtype if it has one
     step: jnp.ndarray     # update counter
 
 
@@ -126,6 +127,21 @@ class _DoubleBufferingOptimizer:
     applies zero gradients (buffers start zero-filled).  The allreduce of the
     pending buffer depends on nothing step t computes; whether anything runs
     beside it is the compiler's: ``comm.exchange_compiler_options()`` (TPU).
+
+    ``pending`` is kept in the dtype the exchange sends.  Where the
+    communicator has a wire dtype (``allreduce_grad_dtype``) every leaf of
+    two dimensions or more is rounded to it as it is stored, which is the
+    rounding the next step's exchange applied to the same values, one step
+    earlier: bit for bit the same update, from half the buffer, with no
+    cast before the collective (PERF.md, PR 46).  The means come back in
+    the fresh gradients' dtype (the parameters'), so the ``1 / size`` scale
+    multiplies there (``allreduce_grad(like=)``).  A VECTOR keeps its own
+    dtype and is cast at the read as before (a few ten-thousandths of the
+    bytes): a bias gradient written as bfloat16 made TPU XLA fuse its
+    reduction into the product that makes the activations' gradient, whose
+    layout then cost a transposing copy a layer (PERF.md, PR 46).  A
+    communicator without a wire dtype keeps every leaf as its gradient is
+    and traces the program it always traced.
     """
 
     def __init__(self, actual_optimizer: optax.GradientTransformation, comm):
@@ -133,7 +149,10 @@ class _DoubleBufferingOptimizer:
         self.communicator = comm
 
     def init(self, params):
-        zeros = jax.tree.map(jnp.zeros_like, params)
+        wire = getattr(self.communicator, "allreduce_grad_dtype", None)
+        zeros = jax.tree.map(
+            lambda p: jnp.zeros_like(
+                p, dtype=None if jnp.ndim(p) < 2 else wire), params)
         return _DoubleBufferState(
             inner=self.actual_optimizer.init(params),
             pending=zeros,
@@ -141,12 +160,32 @@ class _DoubleBufferingOptimizer:
         )
 
     def update(self, grads, state, params=None, **kwargs):
-        comm_grads = self.communicator.allreduce_grad(state.pending)
+        with jax.named_scope("chainermn.allreduce_grad"):
+            # Step 0 applies zeros: the buffer starts so, and for a leaf
+            # held in the wire dtype this select says so in the program.  It
+            # changes no value and stands where the wire cast stood, because
+            # the asynchronous exchange stands on it: TPU XLA gives an
+            # all-reduce its start - steps - done form only where a fusion
+            # of the step makes its operand, and one that reads the step's
+            # ARGUMENT stays blocking (11 of the dp4 LM's 43 matrices;
+            # PERF.md, PR 46).  It costs no pass of its own: the argument is
+            # donated, so XLA copies it before an in-place all-reduce
+            # anyway; on one device it rides in the update's fusion.
+            stale = jax.tree.map(
+                lambda held, g: held if held.dtype == g.dtype
+                else jax.lax.select(
+                    state.step != 0, held, jnp.zeros_like(held)),
+                state.pending, grads)
+        comm_grads = self.communicator.allreduce_grad(stale, like=grads)
         with jax.named_scope("chainermn.update"):
             updates, inner = self.actual_optimizer.update(
                 comm_grads, state.inner, params, **kwargs)
+            # the state's write: the fresh gradients in the dtype they are
+            # held in (the exchange's wire cast, made where they are made)
+            pending = jax.tree.map(
+                lambda g, held: g.astype(held.dtype), grads, state.pending)
         new_state = _DoubleBufferState(
-            inner=inner, pending=grads, step=state.step + 1)
+            inner=inner, pending=pending, step=state.step + 1)
         return updates, new_state
 
     def state_partition_spec(self):
@@ -598,7 +637,9 @@ def init_model_state(communicator, model_state):
 def init_opt_state(communicator, optimizer, params):
     """Initialize optimizer state with the right shardings: replicated inner
     state; for double buffering, a stacked per-device ``pending`` buffer
-    (leading axis == communicator.size) sharded over the data axes."""
+    (leading axis == communicator.size) sharded over the data axes, in the
+    dtype the optimizer's ``init`` gives it (matrices in the communicator's
+    wire dtype where it has one, everything else in the parameters')."""
     comm = communicator
     state = optimizer.init(params)
     if isinstance(state, _ZeroState):

@@ -517,14 +517,19 @@ def plan_needs_buffer(plan: Plan, topology: PlanTopology) -> bool:
                if topology.scope_axes(st.scope))
 
 
-def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs):
+def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs,
+                      like=None):
     """The lowering of a flat plan that needs no buffer: the same cast,
     reduce, cast back, scale as pack -> stages -> unpack, leaf by leaf.
     The scopes stay where ``_packing`` has them, so ``chainermn.pack``
     goes on naming what the exchange costs before the collective (here
-    the wire cast alone) and ``chainermn.unpack`` what it costs after."""
+    the wire cast alone) and ``chainermn.unpack`` what it costs after.
+    The means come back in ``like``'s dtypes (None: the leaves' own): a
+    leaf that arrives in the wire dtype meets no cast before its
+    collective, and is cast to its ``like`` before the scale."""
     leaves, treedef = jax.tree_util.tree_flatten(grads)
-    dtypes = [l.dtype for l in leaves]
+    dtypes = [l.dtype for l in
+              (leaves if like is None else treedef.flatten_up_to(like))]
     if plan.wire_dtype is not None:
         wire = jnp.dtype(plan.wire_dtype)
         with jax.named_scope("chainermn.pack"):
@@ -535,15 +540,16 @@ def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs):
         group=None if plan.groups is None else 0)
     scale = 1.0 / topology.size
     with jax.named_scope("chainermn.unpack"):
-        # cast back FIRST: the scale multiplies in leaf precision
-        # (``_packing.unpack``'s order)
+        # cast back FIRST: the scale multiplies in the precision asked
+        # for, the leaf's or its ``like``'s (``_packing.unpack``'s order)
         leaves = [(l if l.dtype == dt else l.astype(dt))
                   * jnp.asarray(scale, dt)
                   for l, dt in zip(leaves, dtypes)]
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
+def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
+                 like=None):
     """Run ``plan`` as ``comm``'s gradient mean — the one lowering every
     flavor's ``_allreduce_grad_traced`` now delegates to.
 
@@ -564,6 +570,11 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
     from a cold in-trace state (EF discarded — the one-shot
     benchmark/validation path) and the return is just ``mean_grads``,
     keeping every pre-existing call site unchanged.
+
+    ``like`` gives the dtypes the means come back in
+    (``allreduce_grad``'s keyword; None: the gradients' own).  The
+    leaf-wise lowering of a flat plan reduces a leaf that is in the wire
+    dtype already as it lies; the other two widen the tree first.
     """
     from chainermn_tpu.communicators import _packing
 
@@ -576,13 +587,15 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None):
             raise PlanError(
                 f"plan {plan.name!r}: leaf packing carries no per-hop "
                 "compression state")
-        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        leaves, treedef = jax.tree_util.tree_flatten(
+            _packing.cast_like(grads, like))
         leaves = _run_stages_leaves(plan, topology, leaves, pobs)
         return jax.tree_util.tree_unflatten(
             treedef, [l / n for l in leaves])
     if not plan_needs_buffer(plan, topology):
-        result = _mean_over_leaves(plan, topology, grads, pobs)
+        result = _mean_over_leaves(plan, topology, grads, pobs, like)
         return (result, {}) if states is not None else result
+    grads = _packing.cast_like(grads, like)
     # Quantizing plans exchange ONE float32 buffer (the quantizer's
     # native dtype; per-stage wires still cast per hop) so EF state maps
     # one-to-one onto the packed buffer.

@@ -178,25 +178,55 @@ ENTRY %main.332_spmd (param.1: bf16[2048,2048], param.2: bf16[2048], param.3: bf
 """
 
 
+# A wire cast as the chip's compiler prints it, inside the fusion that makes
+# an all-reduce's operand, beside a cast back under ``chainermn.unpack`` and
+# one the model made (neither is counted).
+WIRE_CAST_HLO = """
+HloModule jit_per_rank, is_scheduled=true
+
+%fused_computation.7 (param_0.24: f32[1,4096,512]) -> bf16[4096,512] {
+  %param_0.24 = f32[1,4096,512]{2,1,0:T(8,128)} parameter(0)
+  %convert_element_type.134 = bf16[1,4096,512]{2,1,0:T(8,128)(2,1)} convert(%param_0.24), metadata={op_name="jit(per_rank)/shard_map/chainermn.allreduce_grad/chainermn.pack/convert_element_type" stack_frame_id=11}
+  ROOT %bitcast.38 = bf16[4096,512]{1,0:T(8,128)(2,1)S(1)} bitcast(%convert_element_type.134), metadata={op_name="jit(per_rank)/shard_map/chainermn.allreduce_grad/chainermn.pack/convert_element_type" stack_frame_id=11}
+}
+
+ENTRY %main.1_spmd (param.1: f32[1,4096,512], param.2: bf16[512]) -> f32[4096,512] {
+  %param.1 = f32[1,4096,512]{2,1,0:T(8,128)} parameter(0)
+  %param.2 = bf16[512]{0:T(512)(128)(2,1)} parameter(1)
+  %fusion.7 = bf16[4096,512]{1,0:T(8,128)(2,1)S(1)} fusion(%param.1), kind=kLoop, calls=%fused_computation.7
+  %all-reduce.1 = bf16[4096,512]{1,0:T(8,128)(2,1)} all-reduce(%fusion.7), channel_id=1, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_0.1
+  %convert.9 = f32[512]{0:T(512)} convert(%param.2), metadata={op_name="jit(per_rank)/shard_map/chainermn.grad/transpose(jvp(Model))/convert_element_type"}
+  ROOT %convert.3 = f32[4096,512]{1,0:T(8,128)} convert(%all-reduce.1), metadata={op_name="jit(per_rank)/shard_map/chainermn.allreduce_grad/chainermn.unpack/convert_element_type"}
+}
+"""
+
+
 @pytest.mark.parametrize("text,want", [
     (SYNC_HLO, {"synchronous": 1, "asynchronous": 0,
                 "synchronous_bytes": 1024, "asynchronous_bytes": 0,
-                "asynchronous_byte_share": 0.0}),
+                "wire_casts": 0, "asynchronous_byte_share": 0.0}),
     (ASYNC_HLO, {"synchronous": 0, "asynchronous": 1,
                  "synchronous_bytes": 0, "asynchronous_bytes": 4096,
-                 "asynchronous_byte_share": 1.0}),
+                 "wire_casts": 0, "asynchronous_byte_share": 1.0}),
     (ASYNC_FUSION_HLO, {
         "synchronous": 2, "asynchronous": 1,
         "synchronous_bytes": 2 * (2048 + 8192) + 4,
-        "asynchronous_bytes": 2 * 2048 * 2048,
+        "asynchronous_bytes": 2 * 2048 * 2048, "wire_casts": 0,
         "asynchronous_byte_share":
             2 * 2048 * 2048 / (2 * 2048 * 2048 + 2 * (2048 + 8192) + 4)}),
-], ids=["blocking", "start_done_pair", "async_collective_fusion"])
+    (WIRE_CAST_HLO, {"synchronous": 1, "asynchronous": 0,
+                     "synchronous_bytes": 2 * 4096 * 512,
+                     "asynchronous_bytes": 0, "wire_casts": 1,
+                     "asynchronous_byte_share": 0.0}),
+], ids=["blocking", "start_done_pair", "async_collective_fusion",
+        "wire_cast"])
 def test_all_reduce_overlap_census(text, want):
     """The engagement counter of the asynchronous gradient exchange: an
     all-reduce counts once however often the compiler prints it, blocking
     unless it is a start/done pair or a chain of asynchronous-collective
-    fusions, with the bytes its result holds."""
+    fusions, with the bytes its result holds; ``wire_casts`` counts the
+    ``convert`` instructions under ``chainermn.pack``, inside a fusion or
+    not, and no other cast."""
     assert all_reduce_overlap_census(text) == want
 
 

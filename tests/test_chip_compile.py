@@ -197,10 +197,16 @@ def _resnet50_tree(leaf):
     return tree
 
 
-def _exchange_program(topo, body_name, make_tree=_small_tree, chips=4):
-    """A double-buffered step's exchange: the f32 ``pending`` gradients of
+def _exchange_program(topo, body_name, make_tree=_small_tree, chips=4,
+                      held=True):
+    """A double-buffered step's exchange: the ``pending`` gradients of
     ``chips`` chips through the bf16-wire ``allreduce_grad`` into an
-    SGD-momentum update."""
+    SGD-momentum update in float32.  ``pending`` is as the optimizer holds
+    it since PR 46, matrices in the wire's bfloat16 and vectors in float32,
+    and the means come back like the momentum; ``held=False`` (and the
+    legacy body, which knows nothing else) hands the exchange float32
+    gradients, as the optimizer did before and as an exchange of fresh
+    gradients still does."""
     import chainermn_tpu
     from chainermn_tpu.parallel.topology import init_topology
 
@@ -210,17 +216,21 @@ def _exchange_program(topo, body_name, make_tree=_small_tree, chips=4):
     assert comm.size == chips
     stacked = NamedSharding(comm.mesh, P(comm.data_axes))
 
-    def leaf(*shape):
-        return jax.ShapeDtypeStruct((comm.size,) + shape, jnp.float32,
-                                    sharding=stacked)
+    held = held and body_name == "allreduce_grad"
 
-    tree = make_tree(leaf)
+    def tree(dtype_of):
+        return make_tree(lambda *shape: jax.ShapeDtypeStruct(
+            (comm.size,) + shape, dtype_of(shape), sharding=stacked))
 
     def step(pending, momentum):
-        mean = getattr(comm, body_name)(pending)
+        mean = (comm.allreduce_grad(pending, like=momentum) if held
+                else getattr(comm, body_name)(pending))
         return jax.tree.map(lambda m, g: 0.9 * m + g, momentum, mean)
 
-    return comm._spmd_program(step).lower((tree, tree)).compile().as_text()
+    pending = tree(lambda shape: jnp.bfloat16 if held and len(shape) > 1
+                   else jnp.float32)
+    return comm._spmd_program(step).lower(
+        (pending, tree(lambda shape: jnp.float32))).compile().as_text()
 
 
 def _instructions(text, *ops):
@@ -266,6 +276,19 @@ def test_four_chip_exchange_builds_no_buffer(topo, body, packs):
     assert 0 < census["synchronous"] < 11 and not census["asynchronous"]
 
 
+@pytest.mark.parametrize("held,casts", [(True, 7), (False, 11)])
+def test_four_chip_exchange_casts_only_what_is_not_held_in_the_wire_dtype(
+        topo, held, casts):
+    """``all_reduce_overlap_census``'s ``wire_casts`` on the compiled
+    exchange: handed float32 gradients it casts each of the 11 leaves before
+    the collective; handed ``pending`` as the optimizer holds it, only the 7
+    vectors (which keep float32), and no matrix."""
+    from chainermn_tpu.analysis.hlo import all_reduce_overlap_census
+
+    text = _exchange_program(topo, "allreduce_grad", held=held)
+    assert all_reduce_overlap_census(text)["wire_casts"] == casts
+
+
 @pytest.mark.parametrize("chips", [4, 1])
 def test_resnet50_exchange_is_xla_alone(topo, chips):
     """The exchange of the ``resnet50-b256`` cell's model over its real
@@ -286,17 +309,24 @@ def test_resnet50_exchange_is_xla_alone(topo, chips):
         assert 0 < reduced < 161, census
 
 
+_STEP_SHAPES = {"in": (2048, 2048), "up": (2048, 4096), "down": (4096, 2048),
+                "bias": (2048,), "gain": (2048,)}
+_STEP_PARAMETERS = 2048 * 2048 + 2 * 2048 * 4096 + 2 * 2048
+
+
 def _double_buffered_step(topo, chips):
     """``make_train_step`` over the double-buffered optimizer and the bf16
     wire, as every benchmark cell builds it, on ``chips`` described chips: a
     three-matrix model whose matrices (8 and 16 MB on the wire) lie over the
     combiner threshold of ``exchange_compiler_options`` and whose two
-    vectors lie under it.  Returns the communicator, the keywords ``jax.jit``
-    was called with and the compiled text."""
+    vectors lie under it.  The state's shapes and dtypes are the
+    optimizer's own (``init``), stacked as ``init_opt_state`` stacks them.
+    Returns the communicator, the keywords ``jax.jit`` was called with and
+    the compiled step."""
     import optax
 
     import chainermn_tpu
-    from chainermn_tpu.optimizers import _DoubleBufferState, make_train_step
+    from chainermn_tpu.optimizers import make_train_step
     from chainermn_tpu.parallel.topology import init_topology
 
     comm = chainermn_tpu.create_communicator(
@@ -312,25 +342,77 @@ def _double_buffered_step(topo, chips):
 
     replicated = NamedSharding(comm.mesh, P())
     stacked = NamedSharding(comm.mesh, P(comm.data_axes))
-    shapes = {"in": (2048, 2048), "up": (2048, 4096), "down": (4096, 2048),
-              "bias": (2048,), "gain": (2048,)}
+    state = jax.eval_shape(optimizer.init, {
+        k: jax.ShapeDtypeStruct(v, jnp.float32)
+        for k, v in _STEP_SHAPES.items()})
+    on_every_chip = lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=replicated)
     params = {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=replicated)
-              for k, v in shapes.items()}
-    inner = jax.eval_shape(optax.sgd(0.1, momentum=0.9).init, params)
-    opt_state = _DoubleBufferState(
-        inner=jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=replicated), inner),
-        pending={k: jax.ShapeDtypeStruct((chips,) + v, jnp.float32,
-                                         sharding=stacked)
-                 for k, v in shapes.items()},
-        step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated))
+              for k, v in _STEP_SHAPES.items()}
+    opt_state = state._replace(
+        inner=jax.tree.map(on_every_chip, state.inner),
+        pending=jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            (chips,) + a.shape, a.dtype, sharding=stacked), state.pending),
+        step=on_every_chip(state.step))
     batch = (jax.ShapeDtypeStruct((8 * chips, 2048), jnp.float32,
                                   sharding=stacked),)
     with unittest.mock.patch.object(jax, "jit", wraps=jax.jit) as jit:
         step = make_train_step(comm, loss_fn, optimizer)
     (_, keywords), = jit.call_args_list
-    return comm, keywords, step.lower(
-        params, opt_state, batch).compile().as_text()
+    return comm, keywords, step.lower(params, opt_state, batch).compile()
+
+
+def _assert_ten_bytes_a_parameter(compiled):
+    """The step's arguments on a chip: the parameters and the momentum in
+    float32 and ``pending`` in the wire's bfloat16, 10 bytes a parameter
+    (12 with a float32 ``pending``: 2 bytes a parameter more, 42 MB here),
+    beside the chip's 8 rows of the batch; the counter and the two vectors
+    of ``pending``, which keep float32, are the few KB over."""
+    held = compiled.memory_analysis().argument_size_in_bytes
+    over = held - (10 * _STEP_PARAMETERS + 8 * 2048 * 4)
+    assert 0 <= over < 32 * 1024, (held, over)
+
+
+def _assert_nothing_casts_pending_for_the_wire(text):
+    """Everything that reads a MATRIX of the step's ``pending`` argument (the
+    ENTRY computation's parameters named ``opt_state.pending[...]``: the
+    three matrices bfloat16, the two vectors float32), followed through a
+    ``bitcast`` or a ``copy`` into the fusion that takes it, holds no
+    ``convert`` of a matrix: the buffer is what the all-reduce sends, and
+    the census counts no wire cast but the two vectors' (one fusion)."""
+    from chainermn_tpu.analysis.hlo import all_reduce_overlap_census
+
+    entry = text[text.index("\nENTRY "):].splitlines()
+    pending, vectors = set(), 0
+    for line in entry:
+        held = re.match(r"\s+%([\w.\-]+) = (\w+)\[([\d,]+)\].* parameter\("
+                        r"\d+\).*op_name=\"opt_state\.pending", line)
+        if held and held.group(3).count(",") == 2:      # [1, rows, columns]
+            assert held.group(2) == "bf16", line
+            pending.add(held.group(1))
+        elif held:
+            assert held.group(2) == "f32", line
+            vectors += 1
+    assert len(pending) == 3 and vectors == 2, (pending, vectors)
+    readers = set()
+    for _ in range(2):                      # a bitcast of it is still it
+        for line in entry:
+            made = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.*)", line)
+            if made and made.group(1) not in pending and pending & set(
+                    re.findall(r"%([\w.\-]+)", made.group(2))):
+                if re.search(r" (bitcast|copy)\(", line):
+                    pending.add(made.group(1))
+                else:
+                    readers.add(line)
+    assert readers
+    for line in readers:
+        assert " convert(" not in line, line
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        body = re.search(r"^%" + re.escape(called.group(1)) + r" \(.*?^\}",
+                         text, re.M | re.S).group(0) if called else ""
+        assert not re.search(
+            r"= \w+\[(\d+,)*\d{3,},\d{3,}\]\S* convert\(", body), line
+    assert 0 < all_reduce_overlap_census(text)["wire_casts"] <= vectors
 
 
 def test_four_chip_double_buffered_exchange_compiles_asynchronous(topo):
@@ -341,8 +423,14 @@ def test_four_chip_double_buffered_exchange_compiles_asynchronous(topo):
     the step; the two vectors ride together in one blocking all-reduce of
     8 KB and the loss in another.  On the dp4 cell's real step the same
     options give 43 asynchronous all-reduces carrying 99.96 % of the wire
-    bytes (PERF.md, PR 29)."""
-    comm, keywords, text = _double_buffered_step(topo, 4)
+    bytes (PERF.md, PR 29), with ``pending`` held in the wire's dtype as
+    before (PR 46): nothing casts a matrix of it on its way to the
+    all-reduce, and the optimizer's select on the counter is the fusion each
+    chain starts in (without it the all-reduce that reads the step's
+    argument itself stays blocking: the first matrix here, 11 of 43
+    there)."""
+    comm, keywords, compiled = _double_buffered_step(topo, 4)
+    text = compiled.as_text()
     assert keywords["compiler_options"] == comm.exchange_compiler_options()
     assert keywords["compiler_options"]["xla_enable_async_all_reduce"]
     census = _wire_all_reduces(text)
@@ -351,21 +439,25 @@ def test_four_chip_double_buffered_exchange_compiles_asynchronous(topo):
     assert census["asynchronous_bytes"] == 2 * (2048 * 2048 + 2 * 2048 * 4096)
     assert census["asynchronous_byte_share"] > 0.999, census
     assert text.count("calls=%async_collective_fusion") >= 3
+    _assert_nothing_casts_pending_for_the_wire(text)
+    _assert_ten_bytes_a_parameter(compiled)
 
 
 def test_one_chip_double_buffered_step_keeps_xla_defaults(topo):
-    """The same step on ONE described chip, as four of the five benchmark
+    """The same step on ONE described chip, as eight of the nine benchmark
     cells run it: nothing to exchange, so ``jax.jit`` gets no compile
     options (the compiled program, and its key in the persistent cache, stay
     what they were before PR 29) and the text holds no collective in either
-    form."""
-    comm, keywords, text = _double_buffered_step(topo, 1)
+    form.  Its state is 10 bytes a parameter."""
+    comm, keywords, compiled = _double_buffered_step(topo, 1)
+    text = compiled.as_text()
     assert comm.exchange_compiler_options() is None
     assert keywords["compiler_options"] is None
     census = _wire_all_reduces(text)
     assert not census["synchronous"] and not census["asynchronous"], census
     assert "async_collective_fusion" not in text
     assert "async-collective-start" not in text
+    _assert_ten_bytes_a_parameter(compiled)
 
 
 @pytest.mark.parametrize("cell,pairs,rows", [

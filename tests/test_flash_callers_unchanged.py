@@ -18,7 +18,13 @@ cells: it gave ``ops/grouped_matmul.py``'s kernels their tile plan (the
 file's note); the StarCoder cells' digests are PR 41's still.  PR 44 gave
 ``flash_attention`` its ``block_diffusion`` keyword and ``rope`` its
 ``positions`` and took NO digest again: the six are the parent's, and the
-seventh is the new family's own toy step, taken on PR 44's tree."""
+seventh is the new family's own toy step, taken on PR 44's tree.  PR 46
+took ALL SEVEN again, on purpose and for a reason that is not the kernels':
+the double buffer holds the matrices of ``pending`` in the wire's dtype, so
+every cell's toy step has another state and another update (a select and
+the wire cast at the state's write, a matrix).  Before taking them the seven
+of PR 44's file were read to match on the parent's tree, and the two trees'
+texts were read to differ by those primitives alone (the file's note)."""
 
 import hashlib
 import json
