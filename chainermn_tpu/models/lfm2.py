@@ -25,6 +25,19 @@ grouped kv heads read natively) or the unfused math (``"xla"``, the CPU
 path); the experts through :func:`chainermn_tpu.ops.grouped_matmul`
 (``moe_matmul_impl="pallas"`` on the chip, ``"ragged_dot"`` on the CPU).
 
+What the backward pass makes again (``LFM2MoE``'s own layer; the modules
+other families import are as they were): the layer puts the XLA-only
+stretches between its matrix products under ``jax.checkpoint`` with a policy
+that keeps every product's output (:func:`_between_products`): the norms,
+the gated short convolution, the dense layer's ``silu(gate) * up`` and the
+plain QK-norm and rotation are a pass over memory each to make again, and
+keeping them cost the room in which XLA's own rematerialisation, which drops
+the LARGEST buffer, ran ``in_proj`` and the dense ``w1`` / ``w3`` a second
+time every step (three rows of 8,192 tokens on one chip: PERF.md, PR 47).
+Products and Pallas kernels run once.  On the chip's trace the recomputation
+lies under ``rematted_computation`` in the backward pass and reads as
+``backward_ms``; without a gradient ``jax.checkpoint`` is the identity.
+
 Scopes (docs/observability.md): ``chainermn.shortconv``, ``chainermn.rope``,
 ``chainermn.moe.{route,dispatch,experts,combine,shared}``; flax names
 ``layer_<n>/conv|attn`` and ``layer_<n>/ffn|moe`` keep a layer's operator
@@ -279,6 +292,17 @@ def causal_attention(q, k, v, impl: str, window: Optional[int] = None):
     raise ValueError(f"attention_impl must be flash|xla, got {impl!r}")
 
 
+def _between_products(fn):
+    """``fn(module, *args)`` under flax's lifted :func:`jax.checkpoint` with
+    a policy that KEEPS every matrix product's output: differentiated, the
+    backward pass makes again what lies between the products (a norm, a
+    gate, an activation: a pass over memory each) in place of keeping it,
+    and runs no product twice.  The parameters keep their paths; without a
+    gradient it is ``fn``.  Put no Pallas call inside: a kernel takes the
+    name of the scope around it."""
+    return nn.remat(fn, policy=jax.checkpoint_policies.dots_saveable)
+
+
 class ShortConv(nn.Module):
     """Gated short convolution: ``[B, C, z] = split3(W_in u)``; ``v = B * z``;
     ``c_t = sum_j w_j * v_{t-L+1+j}`` (depthwise, causal, zeros left of the
@@ -305,25 +329,38 @@ class ShortConv(nn.Module):
 
 class Attention(nn.Module):
     """Causal grouped-query attention; q and k are RMS-normalized over
-    head_dim (one scale each, shared by the heads) and then rotated."""
+    head_dim (one scale each, shared by the heads) and then rotated.
+    ``norm`` is the layer's RMSNorm in front of it (the layer's own module,
+    handed in and applied here): what lies in front of the kernels, that
+    norm, the three products and the plain QK-norm and rotation, is ONE
+    stretch under :func:`_between_products`."""
 
     config: LFM2Config
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, norm=None):
         cfg = self.config
         heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
         d = u.shape[-1]
         head_dim = d // heads
         split = lambda t, n: t.reshape(t.shape[:-1] + (n, head_dim))
-        q = split(_dense(heads * head_dim, cfg.dtype, "q_proj")(u), heads)
-        k = split(_dense(kv_heads * head_dim, cfg.dtype, "k_proj")(u),
-                  kv_heads)
-        v = split(_dense(kv_heads * head_dim, cfg.dtype, "v_proj")(u),
-                  kv_heads)
-        q, k = qk_norm_and_rope(
+        normed_and_rotated = lambda q, k: qk_norm_and_rope(
             q, k, ("q_layernorm", "k_layernorm"), cfg.norm_eps, cfg.dtype,
             cfg.attention_impl, cfg.rope_theta)
+        # heads of 128 under the kernels: norm and rotation are a Pallas
+        # kernel (qk_norm_and_rope's condition), and a kernel stays outside
+        kernel = cfg.attention_impl == "flash" and head_dim % 128 == 0
+
+        def in_front(attn, norm, u):
+            u = u if norm is None else norm(u)
+            q, k, v = (split(_dense(n * head_dim, cfg.dtype, name)(u), n)
+                       for name, n in (("q_proj", heads), ("k_proj", kv_heads),
+                                       ("v_proj", kv_heads)))
+            return (q, k, v) if kernel else (*normed_and_rotated(q, k), v)
+
+        q, k, v = _between_products(in_front)(self, norm, u)
+        if kernel:
+            q, k = normed_and_rotated(q, k)
         out = causal_attention(q, k, v, cfg.attention_impl)
         return _dense(d, cfg.dtype, "out_proj")(out.reshape(u.shape))
 
@@ -446,7 +483,17 @@ class SparseMoE(nn.Module):
 class DecoderLayer(nn.Module):
     """Layer ``index``: its operator (``conv`` or ``attn``) and its
     feed-forward (``ffn`` or ``moe``), each behind its RMSNorm and added to
-    the residual.  Returns ``(x, counters or None)``."""
+    the residual.  Returns ``(x, counters or None)``.
+
+    Each half that is XLA's alone runs under :func:`_between_products` WITH
+    its norm, so that the residual stream and the products' outputs are what
+    the backward pass keeps: the convolution operator whole, the dense
+    feed-forward whole (its class is other families' too, so the call is
+    wrapped here), the attention operator up to its kernels
+    (:class:`Attention`).  The expert layer holds Pallas calls and stays
+    outside; its norm alone is wrapped, which keeps the residual stream in
+    front of it from being a value XLA drops and makes again by running the
+    operator's ``out_proj``."""
 
     config: LFM2Config
     index: int
@@ -455,15 +502,25 @@ class DecoderLayer(nn.Module):
     def __call__(self, x):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype, name=name)
+
+        def operator(layer, x):
+            return ShortConv(cfg, name="conv")(norm("operator_norm")(x))
+
+        def feed_forward(layer, x):
+            return DenseFFN(cfg, name="ffn")(norm("ffn_norm")(x))
+
+        def moe_input(layer, x):
+            return norm("ffn_norm")(x)
+
         if cfg.layer_types[self.index] == "conv":
-            operator = ShortConv(cfg, name="conv")
+            x = x + _between_products(operator)(self, x)
         else:
-            operator = Attention(cfg, name="attn")
-        x = x + operator(norm("operator_norm")(x))
-        h = norm("ffn_norm")(x)
+            # its kernels stay outside: the module wraps its own products
+            x = x + Attention(cfg, name="attn")(x, norm("operator_norm"))
         if self.index < cfg.num_dense_layers:
-            return x + DenseFFN(cfg, name="ffn")(h), None
-        y, counters = SparseMoE(cfg, name="moe")(h)
+            return x + _between_products(feed_forward)(self, x), None
+        y, counters = SparseMoE(cfg, name="moe")(
+            _between_products(moe_input)(self, x))
         return x + y, counters
 
 

@@ -126,6 +126,45 @@ def test_census_of_a_compiled_program_made_to_rematerialise(checkpointed):
         assert census["checkpoint_recomputed_by_module"] == {}
 
 
+def test_census_of_the_lfm2_toy_step_names_what_the_layer_makes_again():
+    """PR 47's mechanism engaged, and only where it was put: what
+    ``LFM2MoE``'s checkpoints run again in the compiled gradient lies under
+    a norm, the short convolution's scope, the rotation or the dense
+    layer's module (``silu(gate) * up``), never under a product's module
+    (a fusion is named after its root: a product that ran again would show
+    as ``in_proj``, ``w1``, ``q_proj``, ...); the one entry without a module
+    is the expert layer's own checkpointed remainder."""
+    import optax
+    from chainermn_tpu.models import lfm2
+
+    sizes = dict(
+        vocab_size=96, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_dense_layers=1, num_attention_heads=4,
+        layer_types=("conv", "full_attention", "conv"), num_experts=3,
+        num_key_value_heads=2, num_experts_per_tok=2, first_expert=2)
+    model = lfm2.LFM2MoE(lfm2.LFM2Config(**sizes, num_experts_routed=8))
+    tokens = jax.random.randint(jax.random.key(0), (2, 24), 0, 96)
+    params = model.init(jax.random.key(1), tokens)
+
+    def loss(params, tokens):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(params, tokens)[:, :-1], tokens[:, 1:]).mean()
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    census = compiled_step_census(text)
+    assert census["checkpoint_recomputed"] > 0
+    modules = census["checkpoint_recomputed_by_module"]
+    last = {key.rsplit("/", 1)[-1] for key in modules}
+    assert last <= {"operator_norm", "ffn_norm", "q_layernorm", "k_layernorm",
+                    "chainermn.rope", "chainermn.shortconv", "ffn", ""}
+    assert {"operator_norm", "ffn_norm", "chainermn.shortconv"} <= last
+    # each under the function of the layer that was checkpointed
+    assert {key.split("/")[0] for key in modules if key} == {
+        "layer_*.operator", "layer_*.feed_forward", "layer_*.moe_input",
+        "attn.in_front"}
+    assert "pallas_call" not in text and "tpu_custom_call" not in text
+
+
 KERNEL = ('  %%%s = %s custom-call(%%p), custom_call_target="%s", '
           'metadata={op_name="jit(inner)/chainermn.grad/%s" '
           'stack_frame_id=1}')
