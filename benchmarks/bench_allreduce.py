@@ -75,17 +75,6 @@ def main():
                         help="comma-separated payload sizes in KiB for "
                              "--sweep (one rung per autotuner bucket by "
                              "default)")
-    parser.add_argument("--traced", metavar="OUT.json", default=None,
-                        help="instead of the flavor table, A/B the span-"
-                             "tracing overhead: time the same "
-                             "allreduce_grad with the flight recorder "
-                             "off, then on (plan_stage hooks re-traced "
-                             "in), and write tracing_overhead_pct to "
-                             "this JSON — the artifact behind the "
-                             "tracing_overhead_pct perf budget")
-    parser.add_argument("--repeats", type=int, default=3,
-                        help="A/B repeats for --traced (min of each arm "
-                             "is the reported time)")
     parser.add_argument("--dcn-gbps", type=float, default=None,
                         help="model the inter (DCN) hops of each swept "
                              "plan at this link bandwidth: adds "
@@ -181,8 +170,6 @@ def main():
         return _census(args)
     if args.sweep:
         return _sweep(args)
-    if args.traced:
-        return _traced(args)
 
     if args.scaling:
         counts = [c for c in (2 ** k for k in range(1, 12))
@@ -424,161 +411,6 @@ def _replay(args):
               f"-> {args.replay_out}", file=sys.stderr)
     else:
         print(blob, end="")
-    return doc
-
-
-def overhead_stats(off_s, on_s, collect_s_per_iter=0.0):
-    """Noise-aware summary of a paired A/B overhead measurement.
-
-    ``off_s``/``on_s`` are per-repeat times (seconds per iteration) for
-    the instrumented-off and instrumented-on arms; ``collect_s_per_iter``
-    is amortized into every on-arm sample.  Returns the published
-    ``tracing_overhead_pct`` plus the honesty fields:
-
-    * ``raw_overhead_pct`` — the min-vs-min center, sign preserved;
-    * ``per_repeat_pct`` — the paired overhead of each repeat (repeat i's
-      on arm vs repeat i's off arm), the spread's raw material;
-    * ``spread_pct`` — max-min across the paired repeats;
-    * ``noise_dominated`` — True when the spread swallows the center
-      (spread >= max(|center|, 1.0)) **or** the center is negative:
-      tracing cannot make the program faster, so a negative center is a
-      measurement-noise artifact, not a win.  When set, the published
-      pct is clamped at 0 instead of advertising the artifact.
-    """
-    off_s = [float(t) for t in off_s]
-    on_s = [float(t) + float(collect_s_per_iter) for t in on_s]
-    if not off_s or not on_s:
-        raise ValueError("overhead_stats needs at least one repeat "
-                         "per arm")
-    per_repeat = [(on - off) / off * 100.0
-                  for off, on in zip(off_s, on_s)]
-    center = (min(on_s) - min(off_s)) / min(off_s) * 100.0
-    spread = (max(per_repeat) - min(per_repeat)) \
-        if len(per_repeat) > 1 else 0.0
-    noise_dominated = spread >= max(abs(center), 1.0) or center < 0.0
-    published = max(center, 0.0) if noise_dominated else center
-    return {
-        "tracing_overhead_pct": round(published, 3),
-        "raw_overhead_pct": round(center, 3),
-        "per_repeat_pct": [round(p, 3) for p in per_repeat],
-        "spread_pct": round(spread, 3),
-        "noise_dominated": noise_dominated,
-    }
-
-
-def _traced(args):
-    """--traced: measure what the per-stage span hooks cost.
-
-    Times the first requested flavor's ``allreduce_grad`` twice with the
-    exact :func:`_time_spmd` discipline — once with observability off
-    (the zero-callback program) and once with a flight recorder
-    installed, which makes ``execute_plan`` re-trace the plan with its
-    ``plan_stage_begin``/``_end`` debug callbacks in.  The traced arm
-    also runs the streaming fleet-telemetry aggregator
-    (:class:`~chainermn_tpu.observability.streaming.TelemetryAggregator`)
-    once per repeat, amortizing one ``collect()`` over ``--iters``
-    iterations into the on-arm time — the cost of shipping a telemetry
-    window every ``iters`` steps, which is how ``MetricsReport``
-    triggers it.  Each arm runs ``--repeats`` times interleaved and
-    reports its MIN (standard microbenchmark noise floor), guarded by
-    :func:`overhead_stats`: the artifact carries the per-repeat paired
-    overheads and their spread, and when the spread swallows the center
-    (or the center goes negative — tracing cannot speed a program up)
-    it sets ``noise_dominated: true`` and clamps the published pct at 0
-    rather than advertising measurement noise as a win.  The written
-    artifact (``tracing_overhead/v1``) carries ``tracing_overhead_pct``,
-    the number ``tools/perf_budgets.json`` holds under 3%.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    import chainermn_tpu
-    from chainermn_tpu.observability import flight_recorder as _flight
-    from chainermn_tpu.observability.streaming import TelemetryAggregator
-
-    flavor = args.communicators.split(",")[0]
-    kwargs = {}
-    if args.intra_size is not None:
-        kwargs["intra_size"] = args.intra_size
-    comm = chainermn_tpu.create_communicator(flavor, **kwargs)
-    n = comm.size
-    n_elems = int(args.mb * (1 << 20) / np.dtype(args.dtype).itemsize)
-    stacked = jnp.tile(
-        jnp.arange(n, dtype=args.dtype).reshape(n, 1), (1, n_elems))
-
-    def make_body():
-        # a FRESH closure per arm: jit caches by function identity, so
-        # each arm traces its own program (with/without the hooks)
-        def body(g):
-            return comm.allreduce_grad(g)
-        return body
-
-    def run_arm():
-        body = make_body()
-        out = comm.run_spmd(body, stacked)  # compile + correctness
-        np.testing.assert_allclose(
-            np.asarray(out[0, :3]), (n - 1) / 2.0, rtol=1e-2)
-        return _time_spmd(comm, body, stacked, args.iters, args.warmup)
-
-    had_recorder = _flight.get_flight_recorder() is not None
-    times = {"off": [], "on": []}
-    collects = []
-    events_recorded = 0
-    try:
-        for i in range(max(int(args.repeats), 1)):
-            if not had_recorder:
-                _flight.reset_flight_recorder()
-            times["off"].append(run_arm())
-            fr = _flight.install_flight_recorder()
-            before = len(fr.snapshot())
-            times["on"].append(run_arm())
-            events_recorded = len(fr.snapshot()) - before
-            # the streaming window ride-along: one telemetry collect per
-            # emit interval (= iters steps), amortized into the on-arm
-            agg = TelemetryAggregator(comm)
-            c0 = time.perf_counter()
-            agg.collect(i)
-            collects.append(time.perf_counter() - c0)
-    finally:
-        if not had_recorder:
-            _flight.reset_flight_recorder()
-    if events_recorded <= 0:
-        print("--traced: the traced arm recorded no plan_stage events — "
-              "overhead A/B is meaningless", file=sys.stderr)
-        return 1
-    collect_s = min(collects) if collects else 0.0
-    per_iter_collect = collect_s / max(int(args.iters), 1)
-    stats = overhead_stats(times["off"], times["on"], per_iter_collect)
-    t_off = min(times["off"])
-    t_on = min(times["on"]) + per_iter_collect
-    doc = {"schema": "tracing_overhead/v1",
-           "backend": jax.default_backend(),
-           "n_devices": n,
-           "communicator": flavor,
-           "payload_mib": args.mb,
-           "iters": args.iters,
-           "repeats": args.repeats,
-           "time_ms_off": round(t_off * 1e3, 4),
-           "time_ms_on": round(t_on * 1e3, 4),
-           "streaming_collect_ms": round(collect_s * 1e3, 4),
-           "events_per_traced_run": events_recorded,
-           "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())}
-    doc.update(stats)
-    from chainermn_tpu.observability.ledger import stamp_envelope
-    stamp_envelope(doc, n_devices=n, backend=doc["backend"])
-    if stats["noise_dominated"]:
-        print(f"--traced: noise-dominated measurement (center "
-              f"{stats['raw_overhead_pct']}%, spread "
-              f"{stats['spread_pct']}% over {len(times['off'])} "
-              f"repeats) — publishing clamped overhead "
-              f"{stats['tracing_overhead_pct']}%", file=sys.stderr)
-    with open(args.traced, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    print(json.dumps({"tracing_overhead_pct": doc["tracing_overhead_pct"],
-                      "noise_dominated": doc["noise_dominated"],
-                      "time_ms_off": doc["time_ms_off"],
-                      "time_ms_on": doc["time_ms_on"]}), flush=True)
     return doc
 
 

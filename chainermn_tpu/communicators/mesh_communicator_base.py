@@ -623,8 +623,6 @@ CompressionState` from :meth:`init_compression_state`) and the call
         """Quantized exchange: pack to one f32 buffer, EF-encode to wire
         codes, SUM the codes in wire arithmetic, decode + delayed-scale
         update, mean, unpack.  Returns ``(mean_grads, new_state)``."""
-        from chainermn_tpu.compression import observe as _cobs
-        from chainermn_tpu.compression import quantize as _cq
         traced = self.in_spmd_context()
         n = self.size if traced else 1
         buffers, meta = _packing.pack(grads, comm_dtype=jnp.float32)
@@ -636,37 +634,14 @@ CompressionState` from :meth:`init_compression_state`) and the call
                 f"does not match this gradient tree (needs "
                 f"{comp._padded(m)}): build it with "
                 "comm.init_compression_state(grads, compressor)")
-        obs = _cobs.get_compression_obs() if traced else None
         rank = self.axis_index() if traced else None
-        if obs is not None:
-            bpp = _cq.wire_bits_per_param(comp, m, n)
-            saved = (m * 4 - (comp._padded(m) + comp.n_chunks(m))
-                     * jnp.dtype(comp.wire).itemsize)
-            jax.debug.callback(
-                obs.make_callback("compress", "begin", "allreduce", 0,
-                                  comp.name, bpp, saved),
-                rank, 0.0, buf[0])
-        codes, state = comp.compress(buf, state, rank=rank, world_size=n)
-        if obs is not None:
-            rnorm = jnp.sqrt(jnp.sum(jnp.square(state.ef)))
-            jax.debug.callback(
-                obs.make_callback("compress", "end", "allreduce", 0,
-                                  comp.name, bpp, saved),
-                rank, rnorm, codes[0])
+        with jax.named_scope("chainermn.compress"):
+            codes, state = comp.compress(buf, state, rank=rank, world_size=n)
         summed = lax.psum(codes, self._axis_arg()) if traced else codes
-        if obs is not None:
-            jax.debug.callback(
-                obs.make_callback("decompress", "begin", "allreduce", 0,
-                                  comp.name, bpp, saved),
-                rank, 0.0, summed[0])
-        out, state = comp.decompress(
-            summed, state, world_size=n,
-            axes=self._axis_arg() if traced else None)
-        if obs is not None:
-            jax.debug.callback(
-                obs.make_callback("decompress", "end", "allreduce", 0,
-                                  comp.name, bpp, saved),
-                rank, 0.0, out[0])
+        with jax.named_scope("chainermn.decompress"):
+            out, state = comp.decompress(
+                summed, state, world_size=n,
+                axes=self._axis_arg() if traced else None)
         out = out[:m]
         scale = (1.0 / n) if traced else None
         return _packing.unpack([out], meta, scale=scale), state

@@ -31,13 +31,8 @@ from chainermn_tpu.compression.quantize import (
     is_quantizing,
     wire_bits_per_param,
 )
-from chainermn_tpu.compression.observe import (
-    CompressionObs,
-    get_compression_obs,
-)
 
 __all__ = [
-    "CompressionObs",
     "CompressionState",
     "Compressor",
     "EF_VERSION",
@@ -46,7 +41,6 @@ __all__ = [
     "NoCompression",
     "available_compressors",
     "compression_layout",
-    "get_compression_obs",
     "init_state",
     "is_quantizing",
     "iter_compression_states",

@@ -277,8 +277,8 @@ def is_quantizing(comp) -> bool:
 
 def wire_bits_per_param(comp, length: int, world_size: int = 1) -> float:
     """Achieved wire bits per parameter, counting the chunk-grid pad
-    and the per-chunk saturation flags (the
-    ``compression_bits_per_param`` metric)."""
+    and the per-chunk saturation flags: a pure function of the
+    shapes."""
     if not is_quantizing(comp):
         return float(np.dtype(jnp.float32).itemsize * 8)
     mp = comp._padded(int(length)) + comp.n_chunks(int(length))

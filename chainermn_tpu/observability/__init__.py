@@ -54,10 +54,8 @@ from chainermn_tpu.observability.straggler import (
     summarize_durations,
 )
 from chainermn_tpu.observability.spans import (
-    PlanObs,
     Span,
     build_step_trees,
-    get_plan_obs,
 )
 from chainermn_tpu.observability.attribution import (
     BUCKETS,
@@ -125,7 +123,6 @@ __all__ = [
     "Histogram",
     "InstrumentedCommunicator",
     "MetricsRegistry",
-    "PlanObs",
     "RunLedger",
     "Span",
     "StepTelemetry",
@@ -154,7 +151,6 @@ __all__ = [
     "enabled",
     "feed_link_observations",
     "get_flight_recorder",
-    "get_plan_obs",
     "get_registry",
     "identify_desync",
     "ingest_artifacts",

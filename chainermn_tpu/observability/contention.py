@@ -28,11 +28,10 @@ link class* instead of per step:
 * :func:`attribution_consistency` — per (rank, step, link): the
   occupancy union must reconcile exactly with the ici_comm/dcn_comm
   attribution buckets once the higher-priority shave
-  (checkpoint > dcn > ici) is added back.  The CONTENTION runbook leg
-  asserts this;
+  (checkpoint > dcn > ici) is added back;
 * :func:`contention_report` — the ``contention/v1`` document
-  ``tools/obs_report.py --contention`` renders and
-  ``tools/contention_smoke.py`` commits as ``CONTENTION_r16.json``.
+  ``tools/obs_report.py --contention`` renders (``CONTENTION_r16.json``
+  is the record of one).
 
 Double-count guard: a trace-time ``collective`` span *contains* its
 plan-stage children — the same wire traffic recorded twice — so

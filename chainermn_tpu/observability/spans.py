@@ -2,12 +2,10 @@
 tree of timed regions.
 
 The flight recorder (ISSUE 2) stores *edges*: ``<kind>_begin`` /
-``<kind>_end`` pairs for tracked spans, plain ``phase`` / ``step``
-progress markers from the updater, ``fsdp_{gather,scatter}_{begin,end}``
-bucket edges from the bucketed FSDP step, and (new here) per-stage
-``plan_stage_{begin,end}`` edges from the plan compiler.  This module
-pairs those edges back into :class:`Span` intervals and nests them by
-containment into one tree per train step::
+``<kind>_end`` pairs for tracked spans and plain ``phase`` / ``step``
+progress markers from the updater.  This module pairs recorded edges
+back into :class:`Span` intervals and nests them by containment into
+one tree per train step::
 
     step #12 [0.034s]
       ├─ phase:data_load [0.002s]
@@ -24,18 +22,15 @@ containment into one tree per train step::
 the cross-rank merge, bucket decomposition, critical path, and the
 Perfetto export; ``tools/obs_report.py --attribution`` renders them.
 
-The second half of the module is :class:`PlanObs` /
-:func:`get_plan_obs` — the compiler-side hook that EMITS the per-stage
-edges, following the ``compression/observe.py`` pattern exactly: bound
-once per trace, ``None`` while observability is off (zero callbacks in
-a disabled program), delivered from device-side ``jax.debug.callback``\\ s
-gated to one representative device per controller so every process's
-recorder carries its own stage stream.
+No traced program records ``plan_stage_*`` / ``fsdp_*`` /
+``compress_*`` edges any more (a stage's device time is read from its
+``chainermn.plan.<i>.<op>`` scope in the device trace,
+docs/observability.md): the pairing of those kinds serves a caller
+that records them itself, and recorded dumps.
 """
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -255,118 +250,9 @@ def build_step_trees(events: List[dict], rank: int = 0,
     return steps
 
 
-# ---------------------------------------------------------------------------
-# PlanObs — the compiler-side per-stage span hooks
-# ---------------------------------------------------------------------------
-
-class PlanObs:
-    """Begin/end edges for each emitted plan stage, delivered from
-    device-side ``jax.debug.callback``\\ s inserted by
-    ``planner/compiler._run_stages_flat``.
-
-    Gating: the callback fires on every device of the SPMD region;
-    ``rep_rank`` picks ONE representative global device index per
-    controller (``get_plan_obs`` derives it from the communicator's
-    rank/host layout), so each process's flight recorder carries exactly
-    one stage stream — unlike the compression lane, which keeps a single
-    global stream on rank 0, the attribution merge needs per-controller
-    events to see cross-host skew.
-
-    Metric family (labels ``plan``/``stage``/``op``/``scope``/``link``/
-    ``group`` — ``group`` is the concurrent stripe index of a striped
-    plan, ``"-"`` for plain plans):
-
-    * ``plan_stage_seconds`` (histogram) — host-observed latency between
-      a stage's begin and end callbacks;
-    * ``plan_stage_bytes`` (counter) — wire bytes the stage moved
-      (``_stage_wire_elem_bytes`` pricing, compression included).
-    """
-
-    def __init__(self, flight, registry, rep_rank: int = 0,
-                 rep_stride: int = 1):
-        self.flight = flight
-        self.registry = registry
-        self.rep_rank = int(rep_rank)
-        # devices per controller: the compiler's device-side gate fires
-        # the callback only where global_idx % rep_stride == 0 (one shard
-        # per controller — the same shards rep_rank picks host-side)
-        self.rep_stride = max(int(rep_stride), 1)
-        self._begin: dict = {}
-        if registry is not None:
-            self._seconds = registry.histogram(
-                "plan_stage_seconds",
-                "host-observed per-stage latency of an executed plan")
-            self._bytes = registry.counter(
-                "plan_stage_bytes",
-                "wire bytes moved per executed plan stage")
-
-    def edge(self, edge: str, plan: str, stage: int, op: str, scope: str,
-             link: str, nbytes: int, group: Optional[int] = None) -> None:
-        now = time.perf_counter()
-        key = (plan, group, stage)
-        if self.flight is not None:
-            kw = dict(plan=plan, stage=stage, op=op, scope=scope,
-                      link=link, nbytes=nbytes)
-            if group is not None:
-                kw["group"] = group
-            self.flight.record(f"plan_stage_{edge}", **kw)
-        if self.registry is not None:
-            labels = {"plan": plan, "stage": str(stage), "op": op,
-                      "scope": scope, "link": link,
-                      "group": str(group) if group is not None else "-"}
-            if edge == "begin":
-                self._begin[key] = now
-            else:
-                t0 = self._begin.pop(key, None)
-                if t0 is not None:
-                    self._seconds.observe(now - t0, **labels)
-                self._bytes.inc(nbytes, **labels)
-
-    def make_callback(self, edge: str, plan: str, stage: int, op: str,
-                      scope: str, link: str, nbytes: int,
-                      group: Optional[int] = None):
-        """A rank-gated debug callback for one stage edge.  Called with
-        ``(rank_idx, _dep)`` — ``_dep`` pins when the device reaches the
-        edge (the stage's input on begin, its output on end).  ``group``
-        is the concurrent stripe index for striped plans."""
-
-        def cb(rank_idx, _dep):
-            if int(rank_idx) == self.rep_rank:
-                self.edge(edge, plan, stage, op, scope, link, nbytes,
-                          group=group)
-        return cb
-
-
-def get_plan_obs(comm=None) -> Optional[PlanObs]:
-    """The build-time hook: ``None`` while observability is off (a
-    disabled ``execute_plan`` trace carries no callbacks at all).  With
-    a communicator, the representative device is this controller's
-    first local device under the contiguous device→process mesh layout
-    (``rank * (size // host_size)``)."""
-    from chainermn_tpu.observability import flight_recorder as _flight
-    from chainermn_tpu.observability import registry as _registry
-
-    fr = _flight.get_flight_recorder()
-    reg = _registry.get_registry() if _registry.enabled() else None
-    if fr is None and reg is None:
-        return None
-    rep, stride = 0, 1
-    if comm is not None:
-        try:
-            size = int(getattr(comm, "size", 1) or 1)
-            hosts = max(int(getattr(comm, "host_size", 1) or 1), 1)
-            stride = max(size // hosts, 1)
-            rep = int(getattr(comm, "rank", 0) or 0) * stride
-        except Exception:
-            rep, stride = 0, 1
-    return PlanObs(fr, reg, rep_rank=rep, rep_stride=stride)
-
-
 __all__ = [
-    "PlanObs",
     "Span",
     "build_step_trees",
-    "get_plan_obs",
     "pair_events",
     "phase_spans",
     "stage_link_timings",

@@ -74,7 +74,7 @@ def moe_apply(expert_fn: Callable, gate_logits, x, axis_name,
               num_experts: Optional[int] = None,
               normalize_gates: Optional[bool] = None,
               return_stats: bool = False,
-              plan=None, plan_topology=None, plan_obs=None):
+              plan=None, plan_topology=None):
     """Route local tokens [N, D] to mesh-distributed experts; return [N, D].
 
     ``gate_logits``: [N, E].  E defaults to the gate width and must be a
@@ -100,9 +100,8 @@ def moe_apply(expert_fn: Callable, gate_logits, x, axis_name,
     ``alltoall_plans`` zoo — flat (bit-exact with the default raw
     ``lax.all_to_all`` path), hierarchical ICI+DCN, or narrow-DCN-wire.
     ``axis_name`` may then be an (inter, intra) tuple of mesh axes;
-    ``plan_topology`` overrides the derived topology and ``plan_obs``
-    (``observability.spans.get_plan_obs()``) turns on per-hop
-    ``plan_stage`` spans.  ``plan=None`` is today's raw path, untouched.
+    ``plan_topology`` overrides the derived topology.  ``plan=None`` is
+    today's raw path, untouched.
     """
     p = jax.lax.axis_size(axis_name)
     n, d = x.shape
@@ -161,7 +160,7 @@ def moe_apply(expert_fn: Callable, gate_logits, x, axis_name,
             dtype=jnp.dtype(x.dtype).name, op="all-to-all",
             owners=("moe",))
         plan = resolve_slot_plan("moe", plan)
-        exchange = lambda b: execute_alltoall(plan, topo, b, pobs=plan_obs)
+        exchange = lambda b: execute_alltoall(plan, topo, b)
     recv = exchange(send.reshape(p, epd * c, d))
     recv = recv.reshape(p, epd, c, d).transpose(1, 0, 2, 3)  # [E/P, P, C, D]
     if epd == 1:
@@ -263,16 +262,12 @@ class ExpertParallelMLP(nn.Module):
             return (jnp.einsum("eah,ehd->ead", h, down_kl.astype(self.dtype))
                     + down_bl[:, None].astype(self.dtype))
 
-        plan_obs = None
-        if self.plan is not None:
-            from chainermn_tpu.observability.spans import get_plan_obs
-            plan_obs = get_plan_obs()
         shape = x.shape
         flat = x.reshape(-1, d)
         res = moe_apply(expert_fn, router(flat), flat, self.axis_name,
                         capacity=self.capacity, top_k=self.top_k,
                         num_experts=e, return_stats=self.with_stats,
-                        plan=self.plan, plan_obs=plan_obs)
+                        plan=self.plan)
         if self.with_stats:
             y, stats = res
             return y.reshape(shape), stats

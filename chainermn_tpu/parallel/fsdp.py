@@ -56,7 +56,6 @@ part 5).
 
 from __future__ import annotations
 
-import time
 import types
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
@@ -430,9 +429,18 @@ def fsdp_full_params(state: FsdpState, meta: FsdpMeta):
     return jax.tree.unflatten(meta.treedef, leaves)
 
 
+def _leg_scope(leg: str, bucket: int):
+    """The named scope of one bucket's collective:
+    ``chainermn.fsdp.gather.<i>`` around its all-gather,
+    ``chainermn.fsdp.scatter.<i>`` around the reduce-scatter in the
+    transpose (the naming rule, docs/observability.md)."""
+    return jax.named_scope(
+        f"chainermn.fsdp.{leg}.{bucket}")
+
+
 # ---- quantized bucket exchange ---------------------------------------------
 
-def _make_compressed_gather(comp, layout, wire, axis_arg, size, cobs,
+def _make_compressed_gather(comp, layout, wire, axis_arg, size,
                             bucket: int):
     """The custom-VJP gather for ONE quantized bucket — the seam where
     compression meets the bucketed schedule.
@@ -458,11 +466,11 @@ def _make_compressed_gather(comp, layout, wire, axis_arg, size, cobs,
     ``(shards, comp)`` carry hands it back alongside the gradient
     shards, which is what lets the EF state thread through
     ``jax.value_and_grad`` without restructuring the step.
+
+    The collectives run under :func:`_leg_scope`, the quantizer under
+    ``chainermn.compress`` / ``chainermn.decompress``.
     """
     L = int(layout.shard_lens[0])
-    item = jnp.dtype(comp.wire).itemsize
-    bits_per_param = item * 8.0
-    bytes_saved = L * size * (4 - item)
 
     @jax.custom_vjp
     def cgather(shard, cstate):
@@ -475,7 +483,9 @@ def _make_compressed_gather(comp, layout, wire, axis_arg, size, cobs,
                                cstate.scale.astype(jnp.float32)])
         if wire is not None:
             ext = ext.astype(wire)
-        g = lax.all_gather(ext, axis_arg, tiled=True).reshape(size, L + 1)
+        with _leg_scope("gather", bucket):
+            g = lax.all_gather(ext, axis_arg, tiled=True)
+        g = g.reshape(size, L + 1)
         full = g[:, :L].reshape(-1).astype(orig)
         e_vec = g[:, L].astype(jnp.float32)
         return full, (e_vec, cstate)
@@ -484,35 +494,25 @@ def _make_compressed_gather(comp, layout, wire, axis_arg, size, cobs,
         e_vec, cstate = res
         rank = lax.axis_index(axis_arg)
         scale_pos = jnp.repeat(jnp.exp2(e_vec), L)
-        v = ct.astype(jnp.float32) + cstate.ef
-        if cobs is not None:
-            jax.debug.callback(
-                cobs.make_callback("compress", "begin", "fsdp", bucket,
-                                   comp.name, bits_per_param, bytes_saved),
-                rank, 0.0, v[0])
-        key = comp.make_key(cstate.step[0], rank)
-        codes = comp.encode(v, scale_pos, key, size)
-        new_ef = v - comp.decode(codes, scale_pos)
-        if cobs is not None:
-            jax.debug.callback(
-                cobs.make_callback("compress", "end", "fsdp", bucket,
-                                   comp.name, bits_per_param, bytes_saved),
-                rank, jnp.sqrt(jnp.sum(jnp.square(new_ef))), codes[0])
-        flags = comp.saturation_flags(v, scale_pos, size, L)
-        ext = jnp.concatenate([codes.reshape(size, L), flags[:, None]],
-                              axis=1).reshape(-1)
-        summed = lax.psum_scatter(ext, axis_arg, tiled=True)
-        # my slot of e_vec is my own (current) exponent by construction;
-        # the trailing slot is my shard's summed clip count
-        gshard = summed[:L].astype(jnp.float32) * jnp.exp2(cstate.scale[0])
-        if cobs is not None:
-            jax.debug.callback(
-                cobs.make_callback("decompress", "end", "fsdp", bucket,
-                                   comp.name, bits_per_param, bytes_saved),
-                rank, 0.0, gshard[0])
-        amax = jnp.max(jnp.abs(gshard))[None]
-        new_e = comp.next_exponent(cstate.scale, amax, size,
-                                   summed[L:].astype(jnp.float32))
+        with jax.named_scope("chainermn.compress"):
+            v = ct.astype(jnp.float32) + cstate.ef
+            key = comp.make_key(cstate.step[0], rank)
+            codes = comp.encode(v, scale_pos, key, size)
+            new_ef = v - comp.decode(codes, scale_pos)
+            flags = comp.saturation_flags(v, scale_pos, size, L)
+            ext = jnp.concatenate([codes.reshape(size, L), flags[:, None]],
+                                  axis=1).reshape(-1)
+        with _leg_scope("scatter", bucket):
+            summed = lax.psum_scatter(ext, axis_arg, tiled=True)
+        with jax.named_scope("chainermn.decompress"):
+            # my slot of e_vec is my own (current) exponent by
+            # construction; the trailing slot is my shard's summed clip
+            # count
+            gshard = (summed[:L].astype(jnp.float32)
+                      * jnp.exp2(cstate.scale[0]))
+            amax = jnp.max(jnp.abs(gshard))[None]
+            new_e = comp.next_exponent(cstate.scale, amax, size,
+                                       summed[L:].astype(jnp.float32))
         new_state = cstate._replace(ef=new_ef, scale=new_e,
                                     step=cstate.step + 1.0)
         return gshard.astype(ct.dtype), new_state
@@ -521,84 +521,26 @@ def _make_compressed_gather(comp, layout, wire, axis_arg, size, cobs,
     return cgather
 
 
-# ---- observability ----------------------------------------------------------
+def _make_gather(axis_arg, bucket: int):
+    """The all-gather of ONE plain bucket with its transpose, the
+    gradients' reduce-scatter, spelled out: what autodiff would derive,
+    written as a custom VJP so that each leg runs under a scope of its
+    own in the device trace (:func:`_leg_scope`)."""
 
-class _FsdpObs:
-    """Per-bucket collective observability for the bucketed step.
+    @jax.custom_vjp
+    def gather(s):
+        with _leg_scope("gather", bucket):
+            return lax.all_gather(s, axis_arg, tiled=True)
 
-    Bound ONCE at step-build time (the zero-cost-when-disabled contract:
-    when both the flight recorder and the metrics switch are off,
-    ``make_fsdp_train_step`` inserts no callbacks and returns the bare
-    jitted step).  Device-side ``jax.debug.callback``\\ s — data-dependent
-    on each bucket's gather inputs/outputs — deliver real per-bucket
-    begin/end timestamps as the device reaches them; rank gating keeps
-    one event stream per process.
+    def _fwd(s):
+        return gather(s), None
 
-    The ``fsdp_overlap`` metric family:
+    def _bwd(_, ct):
+        with _leg_scope("scatter", bucket):
+            return (lax.psum_scatter(ct, axis_arg, tiled=True),)
 
-    * ``fsdp_overlap_buckets`` / ``fsdp_overlap_prefetch`` (gauges),
-    * ``fsdp_overlap_bytes`` (counter, labels ``leg`` / ``bucket``),
-    * ``fsdp_overlap_seconds`` (histogram, labels ``leg`` / ``bucket``):
-      host-observed latency between a bucket's begin and end callbacks,
-    * ``fsdp_overlap_dispatch_seconds`` (histogram): host latency of the
-      whole step dispatch.
-
-    The scatter legs run inside the autodiff transpose, so their begin
-    edge is approximated by the loss value becoming available (the start
-    of the backward) — per-bucket *end* stamps are exact, which is what
-    the overlap lane in ``tools/obs_report.py --flight`` stagger-plots.
-    """
-
-    def __init__(self, flight, registry, num_buckets: int, prefetch: int):
-        self.flight = flight
-        self.registry = registry
-        self._begin: dict = {}
-        if registry is not None:
-            registry.gauge(
-                "fsdp_overlap_buckets",
-                "bucket count of the bucketed FSDP step").set(num_buckets)
-            registry.gauge(
-                "fsdp_overlap_prefetch",
-                "prefetch depth of the bucketed FSDP step").set(prefetch)
-            self._bytes = registry.counter(
-                "fsdp_overlap_bytes",
-                "wire bytes moved per FSDP collective leg")
-            self._seconds = registry.histogram(
-                "fsdp_overlap_seconds",
-                "host-observed per-bucket collective latency")
-            self._dispatch = registry.histogram(
-                "fsdp_overlap_dispatch_seconds",
-                "host latency of one bucketed FSDP step dispatch")
-
-    def edge(self, leg: str, edge: str, bucket: int, nbytes: int) -> None:
-        """One begin/end edge of a per-bucket collective (called from the
-        jax debug-callback thread on the gated rank only)."""
-        now = time.perf_counter()
-        if self.flight is not None:
-            # link tags the hop for step-time attribution: the bucketed
-            # per-parameter collectives ride the fast interconnect
-            self.flight.record(f"fsdp_{leg}_{edge}", bucket=bucket,
-                               nbytes=nbytes, link="ici")
-        if self.registry is not None:
-            key = (leg, bucket)
-            if edge == "begin":
-                self._begin[key] = now
-            else:
-                t0 = self._begin.pop(key, None)
-                if t0 is not None:
-                    self._seconds.observe(now - t0, leg=leg,
-                                          bucket=str(bucket))
-                self._bytes.inc(nbytes, leg=leg, bucket=str(bucket))
-
-    def make_callback(self, leg: str, edge: str, bucket: int, nbytes: int):
-        def cb(rank_idx, _dep):
-            if int(rank_idx) == 0:
-                self.edge(leg, edge, bucket, nbytes)
-        return cb
-
-    def record_dispatch(self, seconds: float) -> None:
-        if self.registry is not None:
-            self._dispatch.observe(seconds)
+    gather.defvjp(_fwd, _bwd)
+    return gather
 
 
 def make_fsdp_train_step(
@@ -703,7 +645,6 @@ def make_fsdp_train_step(
     # are, every branch below is statically dead and the step traces the
     # exact pre-compression program — the bit-for-bit contract.
     from chainermn_tpu.compression import base as _cbase
-    from chainermn_tpu.compression import observe as _cobs_mod
     bucket_comps = [
         _cbase.resolve_compressor(bl.compressor)
         if getattr(bl, "compressor", None) else None
@@ -716,27 +657,10 @@ def make_fsdp_train_step(
             "the accumulation semantics — accumulate uncompressed or "
             "drop the bucket's compressor")
 
-    # Observability is bound at BUILD time: with both switches off the
-    # traced program carries no callbacks and the bare jitted step is
-    # returned (bit-for-bit the unobserved schedule).
-    from chainermn_tpu.observability import flight_recorder as _flight
-    from chainermn_tpu.observability import registry as _registry
-    fr = _flight.get_flight_recorder()
-    reg = _registry.get_registry() if _registry.enabled() else None
-    obs = _FsdpObs(fr, reg, K, prefetch) if (fr or reg) else None
-    cobs = _cobs_mod.get_compression_obs() if any_compressed else None
-    cgathers = [
-        None if c is None else _make_compressed_gather(
-            c, meta.buckets[i], bucket_wires[i], axis_arg, size, cobs, i)
+    gathers = [
+        _make_gather(axis_arg, i) if c is None else _make_compressed_gather(
+            c, meta.buckets[i], bucket_wires[i], axis_arg, size, i)
         for i, c in enumerate(bucket_comps)]
-
-    def _wire_nbytes(i: int) -> int:
-        # the wire moves the PADDED buffers (shard_len * size elements
-        # each); float buffers ride the bucket's wire dtype, f32 assumed
-        # for the rest — a reporting approximation, not an invariant
-        bl = meta.buckets[i]
-        item = bucket_wires[i].itemsize if bucket_wires[i] is not None else 4
-        return sum(sl * size * item for sl in bl.shard_lens)
 
     def step(state, model_state, batch):
         shards = jax.tree.map(lambda a: jnp.squeeze(a, 0), state.shards)
@@ -746,20 +670,15 @@ def make_fsdp_train_step(
         if with_model_state:
             model_state = jax.tree.map(
                 lambda a: jnp.squeeze(a, 0), model_state)
-        me = lax.axis_index(axes[0]) if obs is not None else None
 
         def gather_bucket(i, bufs):
-            # all_gather over the data axes; its autodiff transpose IS
-            # the reduce-scatter of this bucket's gradients (sum over
-            # devices).  With a wire dtype the cast sits INSIDE the
-            # gather chain, so the transpose reduce-scatters in the wire
-            # dtype as well.
+            # all_gather over the data axes; its transpose
+            # (``_make_gather``) IS the reduce-scatter of this bucket's
+            # gradients (sum over devices).  With a wire dtype the cast
+            # sits INSIDE the gather chain, so the transpose
+            # reduce-scatters in the wire dtype as well.
             bl = meta.buckets[i]
             wire = bucket_wires[i]
-            if obs is not None and bufs:
-                jax.debug.callback(
-                    obs.make_callback("gather", "begin", i, _wire_nbytes(i)),
-                    me, bufs[0].reshape(-1)[0])
             full = []
             for s, n in zip(bufs, bl.orig_lens):
                 orig = s.dtype
@@ -767,12 +686,7 @@ def make_fsdp_train_step(
                         and jnp.issubdtype(orig, jnp.floating) \
                         and orig != wire:
                     s = s.astype(wire)
-                g = lax.all_gather(s, axis_arg, tiled=True)[:n]
-                full.append(g.astype(orig))
-            if obs is not None and full:
-                jax.debug.callback(
-                    obs.make_callback("gather", "end", i, _wire_nbytes(i)),
-                    me, full[0].reshape(-1)[0])
+                full.append(gathers[i](s)[:n].astype(orig))
             return full
 
         def local_loss(carry, model_state_, batch_):
@@ -792,10 +706,10 @@ def make_fsdp_train_step(
                     # the forward consumes the anchor's post-barrier
                     # values, keeping the pin live in the graph
                     gathered[i - prefetch - 1] = list(pinned[len(bufs):])
-                if cgathers[i] is not None:
+                if bucket_comps[i] is not None:
                     # quantized bucket: same pinned slot in the gather
                     # order, compressed gradient leg in the transpose
-                    full = cgathers[i](bufs[0], comp_[i])
+                    full = gathers[i](bufs[0], comp_[i])
                     gathered.append([full[:meta.buckets[i].orig_lens[0]]])
                 else:
                     gathered.append(gather_bucket(i, bufs))
@@ -836,20 +750,6 @@ def make_fsdp_train_step(
             gshards, comp = gcarry
         else:
             gshards = gcarry
-        if obs is not None:
-            # the per-bucket reduce-scatters run inside the transpose:
-            # their shared begin edge is the backward starting (the loss
-            # value exists), the per-bucket end edge is that bucket's
-            # gradient shards existing.
-            for i, gb in enumerate(gshards):
-                if not gb:
-                    continue
-                jax.debug.callback(
-                    obs.make_callback("scatter", "begin", i,
-                                      _wire_nbytes(i)), me, loss)
-                jax.debug.callback(
-                    obs.make_callback("scatter", "end", i, _wire_nbytes(i)),
-                    me, gb[0].reshape(-1)[0])
         if not global_loss:
             # transpose delivered the SUM over devices; reference
             # allreduce_grad semantics are the mean.  (With global_loss
@@ -900,17 +800,7 @@ def make_fsdp_train_step(
                            in_specs=in_specs, out_specs=out_specs,
                            check_vma=check_vma)
     donate_argnums = ((0, 1) if with_model_state else (0,)) if donate else ()
-    jitted = jax.jit(mapped, donate_argnums=donate_argnums)
-    if obs is None or obs.registry is None:
-        return jitted
-
-    def step_with_metrics(*args):
-        t0 = time.perf_counter()
-        out = jitted(*args)
-        obs.record_dispatch(time.perf_counter() - t0)
-        return out
-
-    return step_with_metrics
+    return jax.jit(mapped, donate_argnums=donate_argnums)
 
 
 __all__ = ["BucketLayout", "FsdpMeta", "FsdpState", "fsdp_init",

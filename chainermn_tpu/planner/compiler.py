@@ -90,9 +90,8 @@ def _all_reduce_wire(st: Stage) -> Optional[str]:
 def _stage_scope(i: int, st: Stage):
     """The named scope one emitted stage runs under:
     ``chainermn.plan.<i>.<op>`` (``all-reduce`` reads ``all_reduce``).  It
-    names the stage's instructions in the device trace — what the
-    ``plan_stage`` begin/end callbacks time from the host, read on the
-    device's own clock and at no cost to the program
+    names the stage's instructions in the device trace, so a stage's
+    time is read on the device's own clock and at no cost to the program
     (docs/observability.md)."""
     return jax.named_scope(
         f"chainermn.plan.{i}.{re.sub('[^0-9A-Za-z]', '_', st.op)}")
@@ -230,16 +229,13 @@ def init_plan_compression_states(plan: Plan, topology: PlanTopology,
     return states
 
 
-def _compressed_psum(st: Stage, idx: int, axes, world: int, buf, state,
-                     obs):
+def _compressed_psum(st: Stage, idx: int, axes, world: int, buf, state):
     """Lower one quantized all-reduce stage: EF-encode to wire codes,
     psum the codes (and piggybacked saturation flags) IN wire
     arithmetic over the scope axes, decode + delayed-scale update.
     Returns ``(summed_f32_buffer, new_state)`` — sum semantics, same as
     the psum it replaces, so the fused 1/world mean at unpack is
     untouched."""
-    from chainermn_tpu.compression import quantize as _cq
-
     comp = _quantizer_for(st)
     m = int(buf.shape[0])
     if int(state.ef.shape[0]) != comp._padded(m):
@@ -251,82 +247,18 @@ def _compressed_psum(st: Stage, idx: int, axes, world: int, buf, state,
             "packed_length) / comm.init_compression_state(grads)")
     orig_dtype = buf.dtype
     rank = lax.axis_index(_axis_arg(axes))
-    v = buf.astype(jnp.float32)
-    if obs is not None:
-        bpp = _cq.wire_bits_per_param(comp, m, world)
-        saved = (m * 4 - (comp._padded(m) + comp.n_chunks(m))
-                 * jnp.dtype(comp.wire).itemsize)
-        seam = f"plan:{st.scope}"
-        jax.debug.callback(
-            obs.make_callback("compress", "begin", seam, idx,
-                              comp.name, bpp, saved),
-            rank, 0.0, v[0])
-    codes, state = comp.compress(v, state, rank=rank, world_size=world)
-    if obs is not None:
-        rnorm = jnp.sqrt(jnp.sum(jnp.square(state.ef)))
-        jax.debug.callback(
-            obs.make_callback("compress", "end", seam, idx,
-                              comp.name, bpp, saved),
-            rank, rnorm, codes[0])
+    with jax.named_scope("chainermn.compress"):
+        codes, state = comp.compress(buf.astype(jnp.float32), state,
+                                     rank=rank, world_size=world)
     summed = lax.psum(codes, _axis_arg(axes))
-    if obs is not None:
-        jax.debug.callback(
-            obs.make_callback("decompress", "begin", seam, idx,
-                              comp.name, bpp, saved),
-            rank, 0.0, summed[0])
-    out, state = comp.decompress(summed, state, world_size=world,
-                                 axes=_axis_arg(axes))
-    if obs is not None:
-        mp = comp._padded(m)
-        sat = jnp.sum(summed[mp:].astype(jnp.float32))
-        jax.debug.callback(
-            obs.make_sat_callback(seam, idx, comp.name), rank, sat, out[0])
-        jax.debug.callback(
-            obs.make_callback("decompress", "end", seam, idx,
-                              comp.name, bpp, saved),
-            rank, 0.0, out[0])
+    with jax.named_scope("chainermn.decompress"):
+        out, state = comp.decompress(summed, state, world_size=world,
+                                     axes=_axis_arg(axes))
     return out[:m].astype(orig_dtype), state
 
 
-def _stage_hook(pobs, plan: Plan, topology: PlanTopology, i: int,
-                st: Stage, buf, edge: str,
-                wire_bytes: Optional[float] = None,
-                group: Optional[int] = None):
-    """Insert one per-stage span edge (``plan_stage_begin``/``_end``)
-    as a device-side debug callback, data-dependent on one element of
-    ``buf`` so it fires when the device reaches this point, gated inside
-    :class:`~chainermn_tpu.observability.spans.PlanObs` to one
-    representative device per controller.  ``link`` prices the hop the
-    same way :func:`plan_dcn_bytes` does: ``intra`` rides ICI, ``inter``
-    and ``all`` cross the DCN boundary.  ``wire_bytes`` overrides the
-    payload size (the leaf-packing path prices the whole tree, not the
-    representative leaf the callback rides on).  ``group`` tags the
-    event with the concurrent stripe index of a striped plan — stage 0
-    of group 0 and stage 0 of group 1 are different spans."""
-    if pobs is None:
-        return
-    ridx = lax.axis_index(_axis_arg(topology.scope_axes("all")))
-    if wire_bytes is None:
-        wire_bytes = _stage_wire_elem_bytes(
-            plan, st, float(buf.shape[0]), jnp.dtype(buf.dtype).itemsize)
-    link = "ici" if st.scope == "intra" else "dcn"
-    cb = pobs.make_callback(edge, plan.name, i, st.op, st.scope, link,
-                            int(round(wire_bytes)), group=group)
-    # Device-side gate: only one shard per controller (global index a
-    # multiple of the per-controller device count) pays the host
-    # round-trip — the SAME predicate on every controller, so the SPMD
-    # programs stay identical; the host-side rep_rank check remains the
-    # backstop.
-    stride = max(int(getattr(pobs, "rep_stride", 1)), 1)
-    jax.lax.cond(
-        ridx % stride == 0,
-        lambda r, d: jax.debug.callback(cb, r, d),
-        lambda r, d: None,
-        ridx, buf.reshape(-1)[0])
-
-
 def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
-                     states: Optional[Dict] = None, obs=None, pobs=None,
+                     states: Optional[Dict] = None,
                      group: Optional[int] = None):
     """Apply one stage chain to one flat buffer.  ``group`` selects a
     concurrent group's chain (striped plans — ``buf`` is that group's
@@ -334,10 +266,7 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
     a plain plan's ``stages`` with bare stage-index keys.  ``states``
     maps hop key -> per-hop CompressionState for quantizing stages;
     returns ``(buf, new_states)`` (``new_states`` empty when nothing is
-    stateful).  ``pobs`` (a :class:`spans.PlanObs`, or ``None`` when
-    observability is off) brackets every emitted stage with
-    ``plan_stage_begin``/``_end`` flight events — the attribution
-    subsystem's ICI-vs-DCN ground truth."""
+    stateful)."""
     from chainermn_tpu.communicators import _packing
 
     states = dict(states or {})
@@ -349,7 +278,6 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
         axes = topology.scope_axes(st.scope)
         if not axes:
             continue
-        _stage_hook(pobs, plan, topology, i, st, buf, "begin", group=group)
         with _stage_scope(i, st):
             quant = _quantizer_for(st)
             if quant is not None:
@@ -362,7 +290,7 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
                     state = quant.init_state(
                         int(buf.shape[0]), world, hop=key)
                 buf, new_states[key] = _compressed_psum(
-                    st, key, axes, world, buf, state, obs)
+                    st, key, axes, world, buf, state)
             elif st.op == "all-reduce":
                 buf = _with_wire(buf, _all_reduce_wire(st),
                                  lambda b: lax.psum(b, _axis_arg(axes)))
@@ -423,7 +351,6 @@ def _run_stages_flat(plan: Plan, topology: PlanTopology, buf,
                     "buffers), not the gradient-mean executor")
             else:  # pragma: no cover — ir validation rejects unknown ops
                 raise PlanError(f"unknown stage op {st.op!r}")
-        _stage_hook(pobs, plan, topology, i, st, buf, "end", group=group)
     return buf, new_states
 
 
@@ -462,37 +389,19 @@ def _run_stages_leaf(plan: Plan, topology: PlanTopology, leaf):
 
 
 def _run_stages_leaves(plan: Plan, topology: PlanTopology, leaves: List,
-                       pobs, group: Optional[int] = None) -> List:
+                       group: Optional[int] = None) -> List:
     """Apply one chain of leaf-mode stages (all-reduce/multicast/p2p) to
     a LIST of leaves, each where it lies.  Runs stage-outer / leaf-inner
     — per leaf the chain is identical to :func:`_run_stages_leaf` (leaves
-    are independent), but the loop order lets one ``plan_stage``
-    begin/end pair (``pobs``; ``None`` when observability is off)
-    bracket each stage for the WHOLE tree.  The callback rides the
-    largest leaf (the stage's dominant cost); ``wire_bytes`` prices
-    every leaf on that stage's wire.  ``group`` as in
-    :func:`_run_stages_flat`."""
+    are independent), and the order of the all-reduces in the program is
+    the one the asynchronous exchange was measured on (PERF.md, PR 29).
+    ``group`` as in :func:`_run_stages_flat`."""
     stages = plan.stages if group is None else plan.groups[group].stages
-
-    def hook(i, st, edge):
-        sized = [l for l in leaves if getattr(l, "size", 0)]
-        if pobs is None or not sized:
-            return
-        wire_bytes = sum(
-            _stage_wire_elem_bytes(plan, st, float(l.size),
-                                   jnp.dtype(l.dtype).itemsize)
-            for l in sized)
-        _stage_hook(pobs, plan, topology, i, st,
-                    max(sized, key=lambda l: l.size), edge,
-                    wire_bytes=wire_bytes, group=group)
-
     for i, st in enumerate(stages):
         if not topology.scope_axes(st.scope):
             continue
-        hook(i, st, "begin")
         with _stage_scope(i, st):
             leaves = [_leaf_stage_op(plan, topology, st, l) for l in leaves]
-        hook(i, st, "end")
     return leaves
 
 
@@ -517,7 +426,7 @@ def plan_needs_buffer(plan: Plan, topology: PlanTopology) -> bool:
                if topology.scope_axes(st.scope))
 
 
-def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs,
+def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads,
                       like=None):
     """The lowering of a flat plan that needs no buffer: the same cast,
     reduce, cast back, scale as pack -> stages -> unpack, leaf by leaf.
@@ -536,7 +445,7 @@ def _mean_over_leaves(plan: Plan, topology: PlanTopology, grads, pobs,
             leaves = [l if l.dtype == wire else l.astype(wire)
                       for l in leaves]
     leaves = _run_stages_leaves(
-        plan, topology, leaves, pobs,
+        plan, topology, leaves,
         group=None if plan.groups is None else 0)
     scale = 1.0 / topology.size
     with jax.named_scope("chainermn.unpack"):
@@ -580,8 +489,6 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
 
     topology = comm.plan_topology()
     n = topology.size
-    from chainermn_tpu.observability import spans as _spans
-    pobs = _spans.get_plan_obs(comm)
     if plan.packing == "leaf":
         if states is not None:
             raise PlanError(
@@ -589,11 +496,11 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
                 "compression state")
         leaves, treedef = jax.tree_util.tree_flatten(
             _packing.cast_like(grads, like))
-        leaves = _run_stages_leaves(plan, topology, leaves, pobs)
+        leaves = _run_stages_leaves(plan, topology, leaves)
         return jax.tree_util.tree_unflatten(
             treedef, [l / n for l in leaves])
     if not plan_needs_buffer(plan, topology):
-        result = _mean_over_leaves(plan, topology, grads, pobs, like)
+        result = _mean_over_leaves(plan, topology, grads, like)
         return (result, {}) if states is not None else result
     grads = _packing.cast_like(grads, like)
     # Quantizing plans exchange ONE float32 buffer (the quantizer's
@@ -605,10 +512,6 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
     if has_quant and comm_dtype is None:
         comm_dtype = jnp.float32
     buffers, meta = _packing.pack(grads, comm_dtype=comm_dtype)
-    obs = None
-    if has_quant:
-        from chainermn_tpu.compression import observe as _cobs
-        obs = _cobs.get_compression_obs()
     new_states: Dict = {}
     out_buffers = []
     for b in buffers:
@@ -624,8 +527,7 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
             lens = plan_group_lengths(plan, int(b.shape[0]))
             if len(lens) == 1:
                 b, st_out = _run_stages_flat(
-                    plan, topology, b, states=states, obs=obs,
-                    pobs=pobs, group=0)
+                    plan, topology, b, states=states, group=0)
                 new_states.update(st_out)
             else:
                 parts = []
@@ -639,14 +541,12 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
                         parts.append(seg)
                         continue
                     seg, st_out = _run_stages_flat(
-                        plan, topology, seg, states=states, obs=obs,
-                        pobs=pobs, group=g)
+                        plan, topology, seg, states=states, group=g)
                     new_states.update(st_out)
                     parts.append(seg)
                 b = jnp.concatenate(parts)
         else:
-            b, st_out = _run_stages_flat(plan, topology, b, states=states,
-                                         obs=obs, pobs=pobs)
+            b, st_out = _run_stages_flat(plan, topology, b, states=states)
             new_states.update(st_out)
         out_buffers.append(b)
     result = _packing.unpack(out_buffers, meta, scale=1.0 / n)
@@ -655,22 +555,7 @@ def execute_plan(plan: Plan, comm, grads, *, states: Optional[Dict] = None,
     return result
 
 
-def _exchange_hook(pobs, plan: Plan, topology: PlanTopology, i: int,
-                   st: Stage, buf, edge: str, group: Optional[int] = None):
-    """Per-stage span edge for an exchange stage: the payload is the
-    WHOLE block buffer (every element is shipped or kept in place), so
-    the wire bytes price ``buf.size`` elements at the stage's wire
-    width — not the leading dim the flat-gradient hook assumes."""
-    if pobs is None:
-        return
-    wb = _stage_wire_elem_bytes(plan, st, float(buf.size),
-                                jnp.dtype(buf.dtype).itemsize)
-    _stage_hook(pobs, plan, topology, i, st, buf.reshape(-1), edge,
-                wire_bytes=wb, group=group)
-
-
-def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
-                        pobs=None, group: Optional[int] = None):
+def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf):
     """Lower one exchange chain over one ``[P, ...]`` block buffer.
 
     Two canonical decompositions (the zoo ``plans.alltoall_plans``
@@ -706,15 +591,11 @@ def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
                 "intra+inter chain")
         i, st = emitted[0]
         axes = topology.scope_axes(st.scope)
-        _exchange_hook(pobs, plan, topology, i, st, buf, "begin",
-                       group=group)
         with _stage_scope(i, st):
             buf = _with_wire(
                 buf, st.wire_dtype,
                 lambda b: lax.all_to_all(b, _axis_arg(axes), 0, 0,
                                          tiled=True))
-        _exchange_hook(pobs, plan, topology, i, st, buf, "end",
-                       group=group)
         return buf
     if scopes != ("intra", "inter"):
         raise PlanError(
@@ -730,34 +611,25 @@ def _run_alltoall_chain(plan: Plan, topology: PlanTopology, stages, buf,
     # splits by destination intra coordinate
     x = buf.reshape((isz, jsz) + rest)
     x = jnp.moveaxis(x, 1, 0).reshape((jsz * isz,) + rest)
-    _exchange_hook(pobs, plan, topology, ii, intra_st, x, "begin",
-                   group=group)
     with _stage_scope(ii, intra_st):
         x = _with_wire(
             x, intra_st.wire_dtype,
             lambda b: lax.all_to_all(b, intra_axis, 0, 0, tiled=True))
-    _exchange_hook(pobs, plan, topology, ii, intra_st, x, "end",
-                   group=group)
     # x[b'*I + i] = block from intra peer b' destined (i, self_j);
     # re-major by destination host for the DCN hop
     x = x.reshape((jsz, isz) + rest)
     x = jnp.moveaxis(x, 1, 0).reshape((isz * jsz,) + rest)
-    _exchange_hook(pobs, plan, topology, ji, inter_st, x, "begin",
-                   group=group)
     with _stage_scope(ji, inter_st):
         x = _with_wire(
             x, inter_st.wire_dtype,
             lambda b: lax.all_to_all(b, _axis_arg(inter_axes), 0, 0,
                                      tiled=True))
-    _exchange_hook(pobs, plan, topology, ji, inter_st, x, "end",
-                   group=group)
     # x[a'*J + b'] = block from source (a', b') — source global-rank
     # order, exactly the flat exchange's output layout
     return x
 
 
-def execute_alltoall(plan: Plan, topology: PlanTopology, buf, *,
-                     pobs=None):
+def execute_alltoall(plan: Plan, topology: PlanTopology, buf):
     """Run ``plan`` as a block exchange over ``buf`` — the MoE
     dispatch/combine seam (``parallel/expert.moe_apply(plan=...)``).
 
@@ -767,9 +639,9 @@ def execute_alltoall(plan: Plan, topology: PlanTopology, buf, *,
     exchanged buffer with blocks indexed by SOURCE global rank — exactly
     ``lax.all_to_all(..., split_axis=0, concat_axis=0, tiled=True)``
     semantics over the combined axes, whatever decomposition the plan
-    picked.  ``pobs`` (``spans.get_plan_obs()``) brackets every emitted
-    hop with ``plan_stage`` begin/end edges, so the ICI and DCN legs of
-    one dispatch are separate attribution spans.
+    picked.  Every emitted hop runs under its own
+    ``chainermn.plan.<i>.<op>`` scope, so the ICI and DCN legs of one
+    dispatch are separate in the device trace.
 
     A striped plan (``plan.groups``) splits the buffer's SECOND dim (the
     within-block payload) at the group ratio boundaries and runs each
@@ -780,16 +652,14 @@ def execute_alltoall(plan: Plan, topology: PlanTopology, buf, *,
         raise PlanError(
             f"plan {plan.name!r}: all-to-all requires flat packing")
     if plan.groups is None:
-        return _run_alltoall_chain(plan, topology, plan.stages, buf,
-                                   pobs=pobs)
+        return _run_alltoall_chain(plan, topology, plan.stages, buf)
     if buf.ndim < 2:
         raise PlanError(
             f"plan {plan.name!r}: a striped exchange splits the "
             "within-block payload — the buffer needs a second dim")
     lens = plan_group_lengths(plan, int(buf.shape[1]))
     if len(lens) == 1:
-        return _run_alltoall_chain(plan, topology, plan.groups[0].stages,
-                                   buf, pobs=pobs, group=0)
+        return _run_alltoall_chain(plan, topology, plan.groups[0].stages, buf)
     parts = []
     off = 0
     for g, ln in enumerate(lens):
@@ -797,8 +667,7 @@ def execute_alltoall(plan: Plan, topology: PlanTopology, buf, *,
         off += ln
         if ln:
             seg = _run_alltoall_chain(plan, topology,
-                                      plan.groups[g].stages, seg,
-                                      pobs=pobs, group=g)
+                                      plan.groups[g].stages, seg)
         parts.append(seg)
     return jnp.concatenate(parts, axis=1)
 
@@ -983,7 +852,7 @@ def plan_wire_bytes(plan: Plan, topology: PlanTopology, nbytes: int,
 
 #: scope -> physical link class its traffic rides: the intra (last) axis
 #: is the ICI domain, inter and flat-over-all traffic crosses the DCN
-#: boundary (the same classification _stage_hook tags spans with)
+#: boundary
 LINK_CLASS = {"intra": "ici", "inter": "dcn", "all": "dcn"}
 
 

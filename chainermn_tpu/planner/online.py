@@ -247,8 +247,9 @@ class OnlineTuner:
     """The attribution-closed control loop over one communicator's plan
     table.
 
-    Drive it from ``MetricsReport`` (the default wiring —
-    ``MetricsReport(online_tune=True)``): :meth:`ingest` absorbs each
+    No training program drives it since the traced step lost its
+    ``plan_stage`` emitters (ROADMAP Design 6, stage (b)); a caller with
+    recorded events does: :meth:`ingest` absorbs each
     newly-completed step's flight events, :meth:`on_regression` arms a
     re-tune from the attribution watch's flagged buckets, and
     :meth:`maybe_swap` — COLLECTIVE, called at the same trigger on every

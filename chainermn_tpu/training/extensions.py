@@ -115,10 +115,6 @@ class MetricsReport:
                  attribution: bool = True,
                  attribution_factor: float = 2.0,
                  profile_dir: Optional[str] = None,
-                 online_tune: bool = False,
-                 online_tune_threshold: float = 1.05,
-                 online_tune_link_gbps: Optional[dict] = None,
-                 fsdp_prefetch: Optional[tuple] = None,
                  stream_telemetry: bool = False):
         if straggler_every < 1:
             raise ValueError(f"straggler_every must be >= 1, got "
@@ -142,21 +138,6 @@ class MetricsReport:
         self._attribution_factor = attribution_factor
         self._profile_dir = profile_dir
         self._attr = None
-        # online_tune=True closes the attribution loop: plan-stage spans
-        # feed an OnlineTuner (planner/online.py) and a flagged
-        # ici/dcn_comm regression re-tunes the communicator's PlanTable
-        # against the observed link rates, hot-swapping it at the next
-        # emit boundary (rank-0 decision broadcast over the control
-        # plane, so all controllers flip on the same step).
-        # online_tune_link_gbps prices link classes the window has not
-        # observed yet (the static tuning-run figures);
-        # fsdp_prefetch=(current_depth, num_buckets) additionally emits
-        # advisory prefetch-depth recommendations from stall evidence.
-        self._want_online_tune = online_tune
-        self._online_tune_threshold = online_tune_threshold
-        self._online_tune_link_gbps = online_tune_link_gbps
-        self._fsdp_prefetch = fsdp_prefetch
-        self._tuner = None
         # stream_telemetry=True ships each rank's compact per-window
         # summary (occupancy, dropped events, step times, serving
         # latency histograms) to rank 0 over the control plane at every
@@ -199,12 +180,6 @@ class MetricsReport:
                 registry=reg, flight=self._fr,
                 factor=self._attribution_factor,
                 profile_dir=self._profile_dir)
-        if self._want_online_tune and self._attr is not None:
-            from chainermn_tpu.planner.online import OnlineTuner
-            self._tuner = OnlineTuner(
-                comm=comm, registry=reg, flight=self._fr,
-                threshold=self._online_tune_threshold,
-                fallback_gbps=self._online_tune_link_gbps)
         if self._want_stream:
             from chainermn_tpu.observability.streaming import \
                 TelemetryAggregator
@@ -234,15 +209,10 @@ class MetricsReport:
         window = [e for e in evs if e.get("seq", 0) <= last_seq]
         from chainermn_tpu.observability import attribution as _attribution
         from chainermn_tpu.observability import spans as _spans
-        if self._tuner is not None:
-            self._tuner.ingest(window)
         for tree in _spans.build_step_trees(
                 window, rank=getattr(self._comm, "rank", 0)):
             self._last_attr = _attribution.attribute_step(tree)
-            flagged = self._attr.observe(self._last_attr)
-            if self._tuner is not None:
-                self._tuner.observe_attribution(self._last_attr)
-                self._tuner.on_regression(flagged)
+            self._attr.observe(self._last_attr)
         self._attr_seq = last_seq
 
     def _emit_record(self, trainer) -> dict:
@@ -298,18 +268,6 @@ class MetricsReport:
             # COLLECTIVE over the control plane — every rank reaches this
             # at the same trigger; do not gate it on the writer rank.
             straggler = self._tele.straggler.report()
-        swap = None
-        if self._tuner is not None:
-            # COLLECTIVE (rank-0 decision broadcast): every rank calls
-            # maybe_swap at this trigger so all controllers hot-swap the
-            # plan table on the SAME step boundary.
-            swap = self._tuner.maybe_swap(trainer.updater.iteration)
-            if swap is not None:
-                # drop the jitted step so the next dispatch retraces and
-                # re-selects plans against the swapped table
-                step_fn = getattr(trainer.updater, "step_fn", None)
-                if hasattr(step_fn, "clear_cache"):
-                    step_fn.clear_cache()
         fleet = None
         if self._stream is not None:
             # COLLECTIVE (control-plane gather to rank 0): every rank
@@ -331,29 +289,6 @@ class MetricsReport:
                                           kind="step_attribution",
                                           ts=time.time()))
             self._last_attr = None
-        if self._tuner is not None:
-            if swap is not None:
-                # JSONL copy of the swap (minus the full table/comparison
-                # payloads — the flight event and sidecar pin carry the
-                # hash); obs_report --attribution renders it
-                slim = {k: v for k, v in swap.items()
-                        if k not in ("table", "comparison")}
-                append_jsonl(self._path, dict(
-                    slim, kind="plan_table_swap",
-                    iteration=trainer.updater.iteration, ts=time.time()))
-            append_jsonl(self._path, dict(
-                self._tuner.state(),
-                iteration=trainer.updater.iteration, ts=time.time()))
-            if self._fsdp_prefetch is not None:
-                cur, nbuckets = self._fsdp_prefetch
-                rec = self._tuner.recommend_prefetch(int(cur),
-                                                     int(nbuckets))
-                if rec != int(cur):
-                    append_jsonl(self._path, {
-                        "kind": "fsdp_prefetch_recommendation",
-                        "current": int(cur), "recommended": rec,
-                        "iteration": trainer.updater.iteration,
-                        "ts": time.time()})
         if self._prometheus:
             write_prometheus(self._prometheus, self._reg.snapshot())
 

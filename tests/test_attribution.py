@@ -36,7 +36,6 @@ from chainermn_tpu.observability import (
     span_summary,
     to_trace_events,
 )
-from chainermn_tpu.observability.spans import get_plan_obs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(REPO, "tests", "data")
@@ -329,30 +328,3 @@ class TestRecorderSurfaces:
         fr.record("x")
         ev = fr.snapshot()[0]
         assert "mono" in ev and ev["mono"] > 0
-
-    def test_plan_obs_disabled_returns_none(self):
-        assert not obs.enabled()
-        assert get_plan_obs() is None
-
-    def test_plan_obs_pairs_edges_into_metrics(self):
-        reg = MetricsRegistry()
-        fr = FlightRecorder()
-        from chainermn_tpu.observability.spans import PlanObs
-        po = PlanObs(fr, reg, rep_rank=4, rep_stride=4)
-        args = ("hier", 1, "all-reduce", "inter", "dcn", 4096)
-        po.edge("begin", *args)
-        po.edge("end", *args)
-        assert reg.get("plan_stage_seconds").count(
-            plan="hier", stage="1", op="all-reduce", scope="inter",
-            link="dcn", group="-") == 1
-        assert reg.get("plan_stage_bytes").value(
-            plan="hier", stage="1", op="all-reduce", scope="inter",
-            link="dcn", group="-") == 4096
-        kinds = [e["kind"] for e in fr.snapshot()]
-        assert kinds == ["plan_stage_begin", "plan_stage_end"]
-        # the device-side gate and the host backstop pick the same shard
-        cb = po.make_callback("begin", *args)
-        cb(5, 0.0)     # not the representative -> ignored
-        assert len(fr.snapshot()) == 2
-        cb(4, 0.0)
-        assert len(fr.snapshot()) == 3

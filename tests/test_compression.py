@@ -673,31 +673,6 @@ class TestObservability:
         obs.disable()
         get_registry().reset()
 
-    def test_compression_metric_family_published(self, comm):
-        from chainermn_tpu import observability as obs
-        from chainermn_tpu.observability import get_registry
-
-        obs.enable()
-        params, loss_fn, data = _mlp_problem(comm)
-        state, meta = fsdp_init(comm, params, optax.adam(0.01),
-                                num_buckets=2, bucket_compressors="int8")
-        step = make_fsdp_train_step(comm, loss_fn, optax.adam(0.01), meta,
-                                    donate=False)
-        batch = put_global_batch(comm, data)
-        state, loss = step(state, batch)
-        jax.block_until_ready(loss)
-        jax.effects_barrier()
-        reg = get_registry()
-        for b in ("0", "1"):
-            bits = reg.gauge("compression_bits_per_param").value(
-                seam="fsdp", bucket=b, compressor="int8")
-            assert 8.0 <= bits < 16.0, bits  # 8-bit wire + scale/pad
-            assert reg.counter("compression_wire_bytes_saved").value(
-                seam="fsdp", bucket=b, compressor="int8") > 0
-            rn = reg.gauge("compression_residual_norm").value(
-                seam="fsdp", bucket=b, compressor="int8")
-            assert np.isfinite(rn) and rn >= 0.0
-
     def test_instrumented_proxy_passes_codec_through(self, comm):
         """Regression: the observability proxy once pinned the old
         ``allreduce_grad(grads)`` signature, so ``--compression`` +

@@ -299,7 +299,7 @@ class TestCheckpoint:
             ckpt.resume(jax.tree.map(jnp.zeros_like, {"fsdp": state1}))
 
 
-# ---- observability: per-bucket spans + fsdp_overlap metrics -----------------
+# ---- observability: the step carries no host callback ----------------------
 
 class TestObservability:
     @pytest.fixture(autouse=True)
@@ -315,76 +315,6 @@ class TestObservability:
         reset_flight_recorder()
         obs.disable()
         get_registry().reset()
-
-    def test_per_bucket_flight_spans_and_lane(self, comm, tmp_path):
-        """With the flight recorder on, one step emits begin/end events
-        for every bucket's gather and scatter, and the obs_report lane
-        renders one bar per (leg, bucket)."""
-        from chainermn_tpu.observability import (
-            get_flight_recorder, install_flight_recorder)
-
-        install_flight_recorder()
-        params, loss_fn, data = _mlp_problem(comm)
-        state, meta = fsdp_init(comm, params, optax.adam(0.01),
-                                num_buckets=2)
-        step = make_fsdp_train_step(comm, loss_fn, optax.adam(0.01), meta,
-                                    donate=False)
-        batch = put_global_batch(comm, data)
-        state, loss = step(state, batch)
-        jax.block_until_ready(loss)
-        jax.effects_barrier()
-
-        events = get_flight_recorder().snapshot()
-        kinds = [e["kind"] for e in events if e["kind"].startswith("fsdp_")]
-        for b in range(2):
-            for want in ("fsdp_gather_begin", "fsdp_gather_end",
-                         "fsdp_scatter_begin", "fsdp_scatter_end"):
-                assert any(e["kind"] == want and e.get("bucket") == b
-                           for e in events), (want, b, kinds)
-
-        # the report tool renders a lane per (leg, bucket)
-        get_flight_recorder().dump(str(tmp_path), rank=0, reason="test")
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools"))
-        try:
-            import obs_report
-        finally:
-            sys.path.pop(0)
-        dumps = obs_report.load_flight_dumps([str(tmp_path)])
-        lane = obs_report.flight_fsdp_lane_section(dumps)
-        assert "fsdp per-bucket collectives" in lane
-        for label in ("gather b0", "gather b1", "scatter b0", "scatter b1"):
-            assert label in lane, lane
-
-    def test_fsdp_overlap_metrics_family(self, comm):
-        """With metrics enabled at build time the step publishes the
-        fsdp_overlap family: bucket/prefetch gauges, per-leg byte
-        counters, and per-bucket latency observations."""
-        from chainermn_tpu import observability as obs
-        from chainermn_tpu.observability import get_registry
-
-        obs.enable()
-        params, loss_fn, data = _mlp_problem(comm)
-        state, meta = fsdp_init(comm, params, optax.adam(0.01),
-                                num_buckets=2)
-        step = make_fsdp_train_step(comm, loss_fn, optax.adam(0.01), meta,
-                                    donate=False, prefetch=1)
-        batch = put_global_batch(comm, data)
-        state, loss = step(state, batch)
-        jax.block_until_ready(loss)
-        jax.effects_barrier()
-
-        reg = get_registry()
-        assert reg.gauge("fsdp_overlap_buckets").value() == 2
-        assert reg.gauge("fsdp_overlap_prefetch").value() == 1
-        for leg in ("gather", "scatter"):
-            for b in ("0", "1"):
-                assert reg.counter("fsdp_overlap_bytes").value(
-                    leg=leg, bucket=b) > 0, (leg, b)
-        assert reg.histogram("fsdp_overlap_seconds").count(
-            leg="gather", bucket="0") >= 1
-        assert reg.histogram("fsdp_overlap_dispatch_seconds").count() >= 1
 
     def test_disabled_observability_keeps_program_clean(self, comm):
         """Zero-cost-when-disabled: with recorder and registry off, the

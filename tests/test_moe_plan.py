@@ -62,7 +62,7 @@ def _mesh_for(topo):
     return Mesh(devs, names), names
 
 
-def _exchange_pair(plan, topo, n=4, d=3, pobs=None):
+def _exchange_pair(plan, topo, n=4, d=3):
     """Per-device [P, n, d] buffers through ``execute_alltoall`` AND raw
     tiled ``lax.all_to_all`` in one SPMD program; returns both stacked
     over devices as numpy."""
@@ -74,7 +74,7 @@ def _exchange_pair(plan, topo, n=4, d=3, pobs=None):
         me = lax.axis_index(axis_arg)
         key = jax.random.fold_in(jax.random.key(7), me)
         buf = jax.random.uniform(key, (p_tot, n, d), jnp.float32)
-        return (execute_alltoall(plan, topo, buf, pobs=pobs),
+        return (execute_alltoall(plan, topo, buf),
                 lax.all_to_all(buf, axis_arg, 0, 0, tiled=True))
 
     out_spec = P(names if len(names) > 1 else names[0])
@@ -330,90 +330,6 @@ class TestMoePlanSeam:
         jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("inter", "intra"),
                           out_specs=P("inter", "intra"),
                           check_vma=False))(jnp.zeros((2, 4)))
-
-
-# ---------------------------------------------------------------------------
-# Observability: per-hop spans and the attribution buckets
-# ---------------------------------------------------------------------------
-
-class TestMoeObservability:
-    @pytest.fixture
-    def enabled_obs(self):
-        from chainermn_tpu import observability as obs
-        from chainermn_tpu.observability import reset_flight_recorder
-        reset_flight_recorder()
-        obs.enable()
-        obs.get_registry().reset()
-        yield obs
-        obs.get_registry().reset()
-        obs.disable()
-        reset_flight_recorder()
-
-    def test_plan_lowered_moe_emits_ici_and_dcn_spans(self, devices,
-                                                      enabled_obs):
-        from chainermn_tpu.observability import (attribute_step,
-                                                 build_step_trees,
-                                                 get_flight_recorder)
-        from chainermn_tpu.observability.spans import get_plan_obs
-
-        pobs = get_plan_obs()
-        assert pobs is not None
-        plan = _zoo(TOPO_2D)["alltoall_hier_bfloat16_dcn"]
-        mesh, _ = _mesh_for(TOPO_2D)
-
-        def body(z):
-            me = lax.axis_index(("inter", "intra"))
-            key = jax.random.fold_in(jax.random.key(3), me)
-            x = jax.random.uniform(key, (16, 4), jnp.float32)
-            g = jax.random.normal(jax.random.fold_in(key, 1), (16, 8))
-            return moe_apply(lambda t: t * 2.0, g, x, ("inter", "intra"),
-                             top_k=2, num_experts=8, plan=plan,
-                             plan_obs=pobs)
-
-        out = jax.jit(jax.shard_map(
-            body, mesh=mesh, in_specs=P("inter", "intra"),
-            out_specs=P(("inter", "intra")),
-            check_vma=False))(jnp.zeros((2, 4)))
-        out.block_until_ready()
-
-        evs = get_flight_recorder().snapshot()
-        begins = [e for e in evs if e["kind"] == "plan_stage_begin"]
-        ends = [e for e in evs if e["kind"] == "plan_stage_end"]
-        # two exchanges (dispatch + combine) x two hops each
-        assert len(begins) == len(ends) == 4
-        assert all(e["op"] == "all-to-all" for e in begins)
-        assert {e["link"] for e in begins} == {"ici", "dcn"}
-        # the hop payload is the whole [P, C, D] block buffer at the
-        # stage wire (capacity C = 2*N*k/E = 8 here)
-        by_link = {e["link"]: e["nbytes"] for e in begins}
-        assert by_link["ici"] == 8 * 8 * 4 * 4      # f32 intra hop
-        assert by_link["dcn"] == 8 * 8 * 4 * 2      # bf16 inter hop
-
-        # obs_report --attribution's bucketer: the spans land in
-        # ici_comm / dcn_comm, never compute
-        ts = [e["ts"] for e in evs]
-        evs.append({"kind": "step", "ts": max(ts) + 1e-4, "seq": 10 ** 6,
-                    "dur_s": (max(ts) - min(ts)) + 2e-4, "iteration": 1})
-        step = build_step_trees(evs)[0]
-        a = attribute_step(step)
-        assert a["buckets"]["ici_comm"] > 0
-        assert a["buckets"]["dcn_comm"] > 0
-        assert a["sum_frac"] == pytest.approx(1.0)
-
-    def test_metrics_series_split_by_link(self, devices, enabled_obs):
-        from chainermn_tpu.observability import get_registry
-        from chainermn_tpu.observability.spans import get_plan_obs
-
-        pobs = get_plan_obs()
-        plan = _zoo(TOPO_2D)["alltoall_hierarchical"]
-        a, b = _exchange_pair(plan, TOPO_2D, pobs=pobs)
-        assert np.array_equal(a, b)
-        reg = get_registry()
-        for stage, scope, link in ((0, "intra", "ici"),
-                                   (1, "inter", "dcn")):
-            assert reg.get("plan_stage_seconds").count(
-                plan=plan.name, stage=str(stage), op="all-to-all",
-                scope=scope, link=link, group="-") == 1
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,9 @@ import pytest
 import chainermn_tpu
 from chainermn_tpu.models import TransformerLM
 from chainermn_tpu.optimizers import init_opt_state, make_train_step
+from chainermn_tpu.parallel.fsdp import fsdp_init, make_fsdp_train_step
+from chainermn_tpu.planner import execute_plan
+from chainermn_tpu.planner.plans import compressed_two_dimensional
 from chainermn_tpu.training.trainer import put_global_batch
 
 OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
@@ -120,3 +123,77 @@ def test_no_argument_or_variable_turns_the_scopes_off():
     assert all(re.match(r"(with|return) jax\.named_scope\($|"
                         r'with jax\.named_scope\("chainermn\.\w+"\):$', line)
                for line in opened), opened
+
+
+def _fsdp_text(**init_args):
+    comm = chainermn_tpu.create_communicator("naive", intra_size=4)
+    params = {"a": jnp.ones((16, 16)), "b": jnp.ones((16,)),
+              "c": jnp.ones((16, 8))}
+    state, meta = fsdp_init(comm, params, optax.adam(0.01), num_buckets=2,
+                            **init_args)
+    step = make_fsdp_train_step(
+        comm,
+        lambda p, b: jnp.mean(
+            (jnp.tanh(b[0] @ p["a"] + p["b"]) @ p["c"]) ** 2),
+        optax.adam(0.01), meta, donate=False)
+    batch = put_global_batch(comm, (jnp.ones((comm.size * 2, 16)),))
+    return step.lower(state, batch).compile().as_text()
+
+
+def _int8_allreduce_grad_text():
+    comm = chainermn_tpu.create_communicator("naive", intra_size=4)
+    grads = {"w": jnp.ones((comm.size, 3, 5)), "b": jnp.ones((comm.size, 7))}
+    state = jax.tree.map(
+        lambda a: jnp.broadcast_to(a, (comm.size,) + a.shape),
+        comm.init_compression_state(grads, "int8"))
+    return comm.compiled_hlo(
+        lambda g, s: comm.allreduce_grad(g, compressor="int8", state=s),
+        grads, state)
+
+
+def _per_hop_int8_text():
+    comm = chainermn_tpu.create_communicator("naive", intra_size=4)
+    plan = compressed_two_dimensional({"name": "int8"})
+    return comm.compiled_hlo(lambda g: execute_plan(plan, comm, g),
+                             jnp.ones((comm.size, 2048)))
+
+
+# a bucket's collective, by the instruction it must name
+_FSDP_LEGS = [leg for i in (0, 1) for leg in (
+    ("all-gather", r"/jvp\(chainermn\.fsdp\.gather\.%d\)/" % i),
+    ("reduce-scatter",
+     r"/transpose\(jvp\(chainermn\.fsdp\.scatter\.%d\)\)/" % i))]
+# program -> (its compiled text, [(instruction or None, op_name pattern)])
+SCOPED = {
+    "fsdp": (_fsdp_text, _FSDP_LEGS),
+    "fsdp_int8": (
+        lambda: _fsdp_text(bucket_compressors="int8"),
+        _FSDP_LEGS + [(None, r"jvp\(chainermn\.compress\)\)/"),
+                      (None, r"jvp\(chainermn\.decompress\)\)/")]),
+    "int8_allreduce_grad": (
+        _int8_allreduce_grad_text,
+        [(None, r"/chainermn\.allreduce_grad/chainermn\.compress/"),
+         (None, r"/chainermn\.allreduce_grad/chainermn\.decompress/")]),
+    # inside the plan stage's own scope
+    "per_hop_int8_plan": (
+        _per_hop_int8_text,
+        [(None, r"/chainermn\.plan\.1\.all_reduce/chainermn\.compress/"),
+         (None, r"/chainermn\.plan\.1\.all_reduce/chainermn\.decompress/"),
+         ("all-reduce", r"/chainermn\.plan\.1\.all_reduce/psum")]),
+}
+
+
+@pytest.mark.parametrize("program", sorted(SCOPED))
+def test_fsdp_buckets_and_the_quantizer_are_named(program):
+    """What the device trace reads where a host-clock emitter used to sit:
+    a bucket's all-gather and (in the transpose) its reduce-scatter, and
+    the quantizer's two halves around the collective that carries the
+    codes."""
+    build, wanted = SCOPED[program]
+    text = build()
+    for kind, pattern in wanted:
+        lines = [line for line in text.splitlines() if kind is None
+                 or re.search(r"= .*\b%s(-start)?\(" % kind, line)]
+        names = [m.group(1) for m in map(OP_NAME.search, lines) if m]
+        assert any(re.search(pattern, name) for name in names), (
+            kind, pattern, sorted(set(names))[:40])

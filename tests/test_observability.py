@@ -12,9 +12,12 @@ import json
 import os
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import chainermn_tpu
 from chainermn_tpu import observability as obs
@@ -35,6 +38,12 @@ from chainermn_tpu.observability import (
 )
 from chainermn_tpu.observability.registry import StreamingHistogram
 from chainermn_tpu.observability.straggler import StragglerDetector, StepTelemetry
+from chainermn_tpu.optimizers import init_opt_state, make_train_step
+from chainermn_tpu.parallel.expert import ExpertParallelMLP
+from chainermn_tpu.parallel.fsdp import fsdp_init, make_fsdp_train_step
+from chainermn_tpu.planner import alltoall_plans, execute_plan, striped_plan
+from chainermn_tpu.planner.plans import compressed_two_dimensional
+from chainermn_tpu.training.trainer import put_global_batch
 
 
 @pytest.fixture
@@ -452,6 +461,136 @@ def test_disabled_hot_path_makes_zero_observability_calls(
     assert trainer.updater.iteration == 4
     assert trainer.updater.telemetry is None
     assert not os.path.exists(os.path.join(str(tmp_path), "metrics.jsonl"))
+
+
+# ---- the switches do not reach the program -----------------------------------
+
+def _spmd_text(comm, body, *stacked):
+    """Lowered text of the program ``comm.run_spmd(body, *stacked)`` runs."""
+    return comm._spmd_program(body).lower(tuple(stacked)).as_text()
+
+
+def _naive_2x4():
+    return chainermn_tpu.create_communicator("naive", intra_size=4)
+
+
+def _exchange(flavor):
+    def build():
+        comm = chainermn_tpu.create_communicator(
+            flavor, intra_size=8 if flavor == "single_node" else 4)
+        grads = {"w": jnp.ones((comm.size, 3, 5)),
+                 "b": jnp.ones((comm.size, 7))}
+        return _spmd_text(comm, lambda g: comm.allreduce_grad(g), grads)
+    return build
+
+
+def _cells_stack():
+    """What every benchmark cell trains through: the ``xla`` flavor on a
+    bfloat16 wire under the double-buffering optimizer."""
+    comm = chainermn_tpu.create_communicator(
+        "xla", allreduce_grad_dtype="bfloat16")
+    params = comm.bcast_data({"w": jnp.ones((4, 3)), "b": jnp.zeros((3,))})
+    optimizer = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(0.1, momentum=0.9), comm, double_buffering=True)
+    state = init_opt_state(comm, optimizer, params)
+    step = make_train_step(
+        comm, lambda p, b: jnp.mean((b[0] @ p["w"] + p["b"]) ** 2),
+        optimizer, donate=False)
+    batch = put_global_batch(comm, (jnp.ones((comm.size * 2, 4)),))
+    return step.lower(params, state, batch).as_text()
+
+
+def _plan_exchange(plan):
+    def build():
+        comm = _naive_2x4()
+        return _spmd_text(comm, lambda g: execute_plan(plan, comm, g),
+                          jnp.ones((comm.size, 2048)))
+    return build
+
+
+def _int8_allreduce_grad():
+    comm = _naive_2x4()
+    grads = {"w": jnp.ones((comm.size, 3, 5)), "b": jnp.ones((comm.size, 7))}
+    state = jax.tree.map(
+        lambda a: jnp.broadcast_to(a, (comm.size,) + a.shape),
+        comm.init_compression_state(grads, "int8"))
+    return _spmd_text(
+        comm,
+        lambda g, s: comm.allreduce_grad(g, compressor="int8", state=s),
+        grads, state)
+
+
+def _fsdp_step(**init_args):
+    def build():
+        comm = _naive_2x4()
+        params = {"a": jnp.ones((16, 16)), "b": jnp.ones((16,)),
+                  "c": jnp.ones((16, 8))}
+        state, meta = fsdp_init(comm, params, optax.adam(0.01),
+                                num_buckets=2, **init_args)
+        step = make_fsdp_train_step(
+            comm,
+            lambda p, b: jnp.mean(
+                (jnp.tanh(b[0] @ p["a"] + p["b"]) @ p["c"]) ** 2),
+            optax.adam(0.01), meta, donate=False)
+        batch = put_global_batch(comm, (jnp.ones((comm.size * 2, 16)),))
+        return step.lower(state, batch).as_text()
+    return build
+
+
+def _moe_exchange():
+    comm = chainermn_tpu.create_communicator("hierarchical", intra_size=4)
+    plan = {p.name: p for p in alltoall_plans(comm.plan_topology())}[
+        "alltoall_hier_bfloat16_dcn"]
+    axes = tuple(name for name, _ in comm.plan_topology().axes)
+    model = ExpertParallelMLP(hidden=16, axis_name=axes, top_k=2,
+                              num_experts=8, plan=plan)
+    x = jnp.ones((16, 8))
+
+    def body(z):
+        return model.apply(model.init(jax.random.key(0), x), x) + z
+
+    return jax.jit(jax.shard_map(
+        body, mesh=comm.mesh, in_specs=P(), out_specs=P(axes),
+        check_vma=False)).lower(jnp.zeros(())).as_text()
+
+
+PROGRAMS = {
+    **{flavor: _exchange(flavor) for flavor in (
+        "naive", "flat", "hierarchical", "two_dimensional", "single_node")},
+    "xla_bf16_double_buffered": _cells_stack,
+    "striped_plan": _plan_exchange(striped_plan(0.5)),
+    "per_hop_int8_plan": _plan_exchange(
+        compressed_two_dimensional({"name": "int8"})),
+    "int8_allreduce_grad": _int8_allreduce_grad,
+    "fsdp": _fsdp_step(),
+    "fsdp_int8": _fsdp_step(bucket_compressors="int8"),
+    "moe_apply_plan": _moe_exchange,
+}
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_the_switches_do_not_reach_the_program(program):
+    """One program whatever the switches: traced with the metrics switch
+    on and a flight recorder installed (what ``start_watchdog(force=True)``
+    and ``install_crash_dumps(force=True)`` do), every exchange, FSDP step
+    and planned MoE layer lowers to the text it lowers to with both off,
+    and that text calls nothing on the host."""
+    from chainermn_tpu.observability import (install_flight_recorder,
+                                             reset_flight_recorder)
+
+    assert not obs.enabled()
+    reset_flight_recorder()
+    off = PROGRAMS[program]()
+    obs.enable()
+    install_flight_recorder()
+    try:
+        on = PROGRAMS[program]()
+    finally:
+        obs.get_registry().reset()
+        obs.disable()
+        reset_flight_recorder()
+    assert "callback" not in off
+    assert on == off
 
 
 def test_metrics_report_end_to_end(comm, tmp_path, enabled_obs):

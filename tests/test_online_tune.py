@@ -516,70 +516,6 @@ class TestPrefetchRecommendation:
 
 
 # ---------------------------------------------------------------------------
-# MetricsReport wiring
-# ---------------------------------------------------------------------------
-
-class TestMetricsReportWiring:
-    @pytest.fixture
-    def enabled_obs(self):
-        from chainermn_tpu import observability as obs
-        obs.enable()
-        obs.get_registry().reset()
-        yield obs
-        obs.get_registry().reset()
-        obs.disable()
-
-    def _run_trainer(self, tmp_path, report, n_iters=4):
-        from chainermn_tpu.datasets import TupleDataset
-        from chainermn_tpu.iterators import SerialIterator
-        from chainermn_tpu.training import StandardUpdater, Trainer
-
-        comm = chainermn_tpu.create_communicator("naive", intra_size=4)
-        x = np.arange(32 * 4, dtype=np.float32).reshape(32, 4)
-        it = SerialIterator(TupleDataset(x, np.zeros(32, np.int32)),
-                            batch_size=16, shuffle=False)
-
-        def step(params, opt_state, batch):
-            return params, opt_state, jnp.sum(batch[0])
-
-        updater = StandardUpdater(it, step, {"w": jnp.zeros(2)}, None, comm)
-        trainer = Trainer(updater, (n_iters, "iteration"),
-                          out=str(tmp_path))
-        trainer.extend(report)
-        trainer.run()
-        return trainer
-
-    def test_online_tune_emits_state_records(self, tmp_path, enabled_obs):
-        from chainermn_tpu.observability import read_jsonl
-        from chainermn_tpu.training import extensions
-
-        report = extensions.MetricsReport(
-            trigger=(2, "iteration"), online_tune=True,
-            fsdp_prefetch=(1, 4))
-        self._run_trainer(tmp_path, report)
-        assert report._tuner is not None
-        recs = read_jsonl(os.path.join(str(tmp_path), "metrics.jsonl"))
-        states = [r for r in recs if r["kind"] == "plan_table_state"]
-        # one snapshot per emit trigger, stamped with the iteration
-        assert [s["iteration"] for s in states] == [2, 4]
-        for s in states:
-            assert s["table_hash"] and s["last_swap_step"] is None
-        # no regression, no swap records
-        assert not [r for r in recs if r["kind"] == "plan_table_swap"]
-
-    def test_online_tune_off_by_default(self, tmp_path, enabled_obs):
-        from chainermn_tpu.observability import read_jsonl
-        from chainermn_tpu.training import extensions
-
-        report = extensions.MetricsReport(trigger=(2, "iteration"))
-        self._run_trainer(tmp_path, report)
-        assert report._tuner is None
-        recs = read_jsonl(os.path.join(str(tmp_path), "metrics.jsonl"))
-        assert not [r for r in recs
-                    if r["kind"].startswith("plan_table")]
-
-
-# ---------------------------------------------------------------------------
 # offline replay + perf gate over the committed dump (satellites)
 # ---------------------------------------------------------------------------
 

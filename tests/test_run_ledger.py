@@ -253,35 +253,6 @@ def test_committed_tracing_overhead_r17_has_noise_guard():
 
 
 # ---------------------------------------------------------------------------
-# the noise guard itself
-# ---------------------------------------------------------------------------
-
-def test_overhead_stats_noise_guard():
-    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
-    try:
-        from bench_allreduce import overhead_stats
-    finally:
-        sys.path.pop(0)
-    # negative center: clamped to 0, flagged, raw preserved
-    s = overhead_stats([1.00, 1.02, 0.99], [0.97, 1.03, 1.00])
-    assert s["noise_dominated"] is True
-    assert s["tracing_overhead_pct"] == 0.0
-    assert s["raw_overhead_pct"] < 0
-    assert len(s["per_repeat_pct"]) == 3 and s["spread_pct"] > 0
-    # clean positive overhead with tight spread: published as-is
-    s = overhead_stats([1.0, 1.0, 1.0], [1.05, 1.051, 1.049])
-    assert s["noise_dominated"] is False
-    assert s["tracing_overhead_pct"] == pytest.approx(4.9)
-    # positive center swallowed by spread: flagged but not zeroed
-    s = overhead_stats([1.0, 1.0], [1.005, 1.06])
-    assert s["noise_dominated"] is True
-    assert s["tracing_overhead_pct"] == pytest.approx(0.5)
-    # streaming-collect amortization lands on the on arm
-    s = overhead_stats([1.0], [1.0], collect_s_per_iter=0.02)
-    assert s["tracing_overhead_pct"] == pytest.approx(2.0)
-
-
-# ---------------------------------------------------------------------------
 # artifact-drift lint rule
 # ---------------------------------------------------------------------------
 

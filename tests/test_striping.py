@@ -515,29 +515,6 @@ class TestStripedLint:
 # ---------------------------------------------------------------------------
 
 class TestStripedObservability:
-    def test_plan_obs_group_labels_and_pairing(self):
-        from chainermn_tpu.observability import (FlightRecorder,
-                                                 MetricsRegistry)
-        from chainermn_tpu.observability.spans import PlanObs
-        reg = MetricsRegistry()
-        fr = FlightRecorder()
-        po = PlanObs(fr, reg, rep_rank=0, rep_stride=1)
-        args = ("striped_r50", 0, "reduce-scatter", "intra", "ici", 1024)
-        # interleaved begin/ends across stripes sharing a stage index
-        po.edge("begin", *args, group=0)
-        po.edge("begin", *args, group=1)
-        po.edge("end", *args, group=1)
-        po.edge("end", *args, group=0)
-        for g in ("0", "1"):
-            assert reg.get("plan_stage_seconds").count(
-                plan="striped_r50", stage="0", op="reduce-scatter",
-                scope="intra", link="ici", group=g) == 1
-        groups = [e.get("group") for e in fr.snapshot()]
-        assert groups == [0, 1, 1, 0]
-        # plain plans keep the back-compat event shape (no group field)
-        po.edge("begin", *args)
-        assert "group" not in fr.snapshot()[-1]
-
     def test_span_names_carry_group_tag(self):
         from chainermn_tpu.observability import build_step_trees
         evs = []

@@ -165,48 +165,6 @@ run 0 "$OUT/REMAT_TUNE_$ROUND.json" \
         $PY_TPU benchmarks/run_configs.py --tune-remat \
         --out '$OUT/REMAT_TUNE_$ROUND.json' > /dev/null"
 
-# ---- step-time attribution: traced 2-process run + overhead A/B ------
-# Hardware-free (2 controllers x 4-way CPU meshes) so the whole span
-# pipeline — flight recorder -> plan_stage hooks -> clock handshake ->
-# cross-rank merge -> bucket decomposition -> Perfetto export — is
-# asserted on every host: the smoke FAILS unless per-rank buckets sum
-# to the measured step time within 5%, the critical path names a
-# concrete (rank, span) pair, and the trace JSON round-trips
-# (docs/observability.md "Attribution & tracing").  The overhead A/B
-# feeds the perf gate's tracing_overhead_pct budget (direction: lower),
-# so both land before the PERF_GATE leg.  On a slice, re-run the smoke
-# WITHOUT the platform override for real ICI/DCN bucket splits.
-run 0 "$OUT/ATTRIBUTION_$ROUND.json" \
-    "step-time attribution smoke: 2-process traced MNIST-shaped training; buckets must sum to step time within 5% and the critical path must name a (rank, span) pair" -- \
-    bash -c "env JAX_PLATFORMS=cpu \
-        $PY_TPU tools/attribution_smoke.py --out '$OUT/ATTRIBUTION_$ROUND.json' \
-        --dump-dir '$OUT/attr_flight_$ROUND' > /dev/null"
-
-run 0 "$OUT/TRACING_OVERHEAD_$ROUND.json" \
-    "span-tracing overhead A/B: hierarchical allreduce_grad with the flight recorder off vs on (the on-arm also runs the streaming telemetry aggregator); perf gate holds tracing_overhead_pct under 3%" -- \
-    bash -c "env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        $PY_TPU benchmarks/bench_allreduce.py \
-        --traced '$OUT/TRACING_OVERHEAD_$ROUND.json' \
-        --iters 10 --repeats 3 --communicators hierarchical > /dev/null"
-
-# ---- link contention: 2-process FSDP + MoE overlap observatory --------
-# Hardware-free (2 controllers x 4-way CPU meshes): bucketed-FSDP
-# training plus the hierarchical all-to-all dispatch schedule on the
-# same world, then the full observatory cut — per-link occupancy
-# timelines, the fsdp x moe overlap matrix, effective-vs-modeled GB/s
-# under contention, the occupancy-vs-attribution-bucket reconciliation,
-# the `overlapping-collectives` lint firing on the same events, and the
-# streaming fleet-telemetry gather over the live control plane
-# (docs/observability.md "Contention & fleet telemetry").  Render with
-# `obs_report --flight --contention <dump dir>`.  On a slice, re-run
-# WITHOUT the platform override: real concurrent issue streams replace
-# the modeled-overlap shift.
-run 0 "$OUT/CONTENTION_$ROUND.json" \
-    "link-contention smoke: 2-process FSDP gathers + MoE all-to-all; overlap matrix must name fsdp x moe on ici, occupancy must reconcile with the attribution buckets, and the overlapping-collectives lint must fire" -- \
-    bash -c "env JAX_PLATFORMS=cpu \
-        $PY_TPU tools/contention_smoke.py --out '$OUT/CONTENTION_$ROUND.json' \
-        --dump-dir '$OUT/cont_flight_$ROUND' > /dev/null"
-
 run 1 "$OUT/PERF_GATE_$ROUND.json" \
     "perf gate: fresh bench artifacts vs checked-in budgets (tools/perf_budgets.json; >3% regression on any tracked throughput FAILS this leg)" -- \
     $PY_TPU tools/perf_gate.py --budgets tools/perf_budgets.json \
@@ -313,9 +271,7 @@ run 0 "$OUT/PLANNER_GATE_ALLTOALL_$ROUND.json" \
 # through plan_modeled_time_s at the observed rates, and must decide to
 # hot-swap with best_speedup >= 1.05 over the previously active plan.
 # Deterministic and device-free (no mesh, no 2-process spawn); the
-# artifact's retune.best_speedup feeds the retune_speedup budget.  The
-# live loop (MetricsReport online_tune=True) is exercised by
-# tests/test_online_tune.py's 2-process swap test.
+# artifact's retune.best_speedup feeds the retune_speedup budget.
 run 0 "$OUT/ONLINE_TUNE_$ROUND.json" \
     "online-tune gate: replay committed degraded-DCN span dump through the OnlineTuner, require a profitable (>=1.05x) plan-table retune decision" -- \
     bash -c "$PY_TPU benchmarks/bench_allreduce.py \

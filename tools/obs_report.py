@@ -462,8 +462,8 @@ def attribution_section(records: List[dict]) -> str:
 
 def _render_contention_doc(doc: dict) -> str:
     """Tables for one ``contention/v1`` document (the post-hoc,
-    clock-corrected observatory cut — contention_smoke.py /
-    ``--flight`` rebuild it from flight dumps)."""
+    clock-corrected observatory cut — ``--flight`` rebuilds it from
+    flight dumps)."""
     parts = []
     head = (f"contention report ({doc.get('n_ranks', '?')} rank(s), "
             f"{doc.get('n_steps', '?')} step(s), links: "
@@ -584,8 +584,7 @@ def contention_section(records: List[dict]) -> str:
         parts.append(_render_contention_doc(cont[-1]))
     if not parts:
         return ("contention: no fleet_telemetry or contention_report "
-                "records (enable MetricsReport(stream_telemetry=True), "
-                "or run tools/contention_smoke.py)")
+                "records (enable MetricsReport(stream_telemetry=True))")
     return "\n\n".join(parts)
 
 
